@@ -78,20 +78,15 @@ def main():
 
     import jax
 
-    jax.config.update(
-        "jax_compilation_cache_dir",
-        os.path.join(os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__))), ".jax_cache"),
-    )
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-
     from speakingstyle_tpu.configs.config import Config
     from speakingstyle_tpu.data.mel_dataset import scan_wavs
+    from speakingstyle_tpu.obs.jaxmon import enable_compilation_cache
     from speakingstyle_tpu.training.vocoder_trainer import (
         VocoderHParams,
         train_vocoder,
     )
 
+    enable_compilation_cache()
     out = os.path.abspath(args.out)
     os.makedirs(out, exist_ok=True)
     ckpt_dir = os.path.join(out, "ckpt")
@@ -108,7 +103,7 @@ def main():
     with open(log_path, "w") as logf, contextlib.redirect_stdout(
         _Tee(sys.stdout, logf)
     ):
-        print(f"device: {dev.platform}/{getattr(dev, 'device_kind', '?')}, "
+        print(f"device: {dev.platform}/{dev.device_kind}, "
               f"{len(wavs)} wavs, batch {args.batch}, "
               f"segment {hp.segment_size}", flush=True)
         print(f"leg 1: steps 0 -> {args.resume_at} (checkpoint at the end)",

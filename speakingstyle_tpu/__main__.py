@@ -31,6 +31,11 @@ def main(argv=None):
         modules[name] = mod
         mod.build_parser(sub.add_parser(name, help=mod.__doc__.splitlines()[0]))
     args = parser.parse_args(argv)
+    # before any subcommand can compile: the persistent compile cache goes
+    # where JAX_COMPILATION_CACHE_DIR points, else <checkout>/.jax_cache
+    from speakingstyle_tpu.obs.jaxmon import enable_compilation_cache
+
+    enable_compilation_cache()
     return modules[args.command].main(args)
 
 

@@ -1379,11 +1379,11 @@ _SYNC_CALL_NAMES = {
 }
 
 
-def _jl010_jitted_names(mod: ModuleInfo, fn: ast.FunctionDef) -> Set[str]:
-    """Names in/visible-to ``fn`` bound to jit-compiled callables: passed
-    to a jax transform anywhere in the file, assigned from ``jax.jit(...)``,
-    assigned from an AOT ``.lower(...).compile()`` chain, or locally
-    ``@jax.jit``-decorated."""
+def _jl010_module_jitted(mod: ModuleInfo) -> Set[str]:
+    """Names bound to jit-compiled callables anywhere in the file: passed
+    to a jax transform, assigned from ``jax.jit(...)``, or assigned from an
+    AOT ``.lower(...).compile()`` chain. One module walk, shared by every
+    function JL010 looks at."""
     jitted = set(mod._jitted_names)
     for node in mod.walk():
         if isinstance(node, ast.Assign) and isinstance(node.value, ast.Call):
@@ -1392,6 +1392,14 @@ def _jl010_jitted_names(mod: ModuleInfo, fn: ast.FunctionDef) -> Set[str]:
                 for t in node.targets:
                     if isinstance(t, ast.Name):
                         jitted.add(t.id)
+    return jitted
+
+
+def _jl010_jitted_names(mod: ModuleInfo, fn: ast.FunctionDef,
+                        module_jitted: Set[str]) -> Set[str]:
+    """Names in/visible-to ``fn`` bound to jit-compiled callables: the
+    file-wide set plus functions locally ``@jax.jit``-decorated."""
+    jitted = set(module_jitted)
     for sub in mod.walk(fn):
         if isinstance(sub, (ast.FunctionDef, ast.AsyncFunctionDef)) and \
                 sub in mod._traced:
@@ -1427,8 +1435,9 @@ def rule_jl010(mod: ModuleInfo) -> Iterator[Finding]:
     wrong (often 100x). Read a result back or ``block_until_ready``
     inside the region, or time at a boundary that already syncs.
     """
+    module_jitted = _jl010_module_jitted(mod)
     for fn in mod.functions:
-        jitted = _jl010_jitted_names(mod, fn)
+        jitted = _jl010_jitted_names(mod, fn, module_jitted)
         if not jitted:
             continue
         stamp_lines: Dict[str, List[int]] = {}   # name -> clock-assign lines

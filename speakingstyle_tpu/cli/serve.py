@@ -171,6 +171,27 @@ def load_engine(cfg, restore_step: int, vocoder_ckpt=None, griffin_lim=False,
     )
 
 
+def require_chips_for_cluster(replicas: int) -> None:
+    """``--cluster`` spawns each replica as a process that needs its own
+    chip, from a parent that restores the checkpoint and runs the style
+    service — and so already holds every chip of this host. A chip belongs
+    to one process, so on a TPU backend the spawn can only fail or hang:
+    refuse at start-up instead. (CPU replicas share the host freely; TPU
+    replicas belong on one host each.)"""
+    import jax
+
+    if jax.default_backend() != "tpu":
+        return
+    held = jax.local_device_count()
+    raise SystemExit(
+        f"serve --cluster: this process holds all {held} TPU chip(s) of "
+        f"this host, so 0 are free for the {replicas} replica process(es) "
+        "it would spawn (a chip belongs to one process). On TPU, --cluster "
+        "needs one host per replica; on one host use --replicas N without "
+        "--cluster (in-process engines)."
+    )
+
+
 def main(args):
     from speakingstyle_tpu.serving.server import (
         SynthesisServer,
@@ -206,10 +227,6 @@ def main(args):
                 cfg.serve.style, ref_dir=args.ref_dir
             )
         ))
-    # persistent compile-cache wiring moved into each engine's
-    # ProgramRegistry (parallel/registry.py), constructed before the
-    # lattice precompile — a warm restart then serves its AOT programs
-    # out of the persistent cache instead of XLA
     replicas = (
         args.replicas if args.replicas is not None
         else cfg.serve.fleet.replicas
@@ -237,6 +254,9 @@ def main(args):
         from speakingstyle_tpu.serving.fleet import FleetRouter
         from speakingstyle_tpu.serving.style import StyleService
 
+        cluster_mode = args.cluster or cfg.serve.cluster.enabled
+        if cluster_mode:
+            require_chips_for_cluster(replicas)
         registry = MetricsRegistry()
         variables, vocoder, lattice, model, info = load_engine_parts(
             cfg, args.restore_step,
@@ -259,7 +279,6 @@ def main(args):
                 fault_plan=fault_plan,
             )
 
-        cluster_mode = args.cluster or cfg.serve.cluster.enabled
         if cluster_mode:
             # distributed control plane: replicas are separate processes
             # spawned as `speakingstyle-tpu replica`, each restoring the

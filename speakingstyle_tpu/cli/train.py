@@ -78,8 +78,6 @@ def main(args):
     from speakingstyle_tpu.training.trainer import run_training
 
     cfg = config_from_args(args)
-    # persistent compile-cache wiring moved into the ProgramRegistry that
-    # run_training constructs before its first compile (parallel/registry.py)
     par = cfg.train.parallel
     flags_given = args.data_parallel is not None or args.model_parallel is not None
     if not par.is_single() and not flags_given:

@@ -199,10 +199,12 @@ class ModelConfig:
     # ops/pallas_attention.py: one VMEM pass per (batch, head), f32
     # softmax in-register; measured ~1.7x faster fwd+bwd at paper shapes)
     # or "einsum" (XLA, materializes [B, H, L, L] scores in HBM — the
-    # literal transcription of the reference math). "fused" engages only
-    # on TPU hardware with L <= 1024 / head_dim <= 128 and falls back to
-    # einsum elsewhere (CPU tests and parity runs always exercise einsum
-    # numerics). Parameter-free, so switchable on a restored checkpoint.
+    # literal transcription of the reference math). "fused" compiles the
+    # kernel on a TPU backend and takes the einsum path on any other (CPU
+    # tests and parity runs always exercise einsum numerics) and for
+    # L > 1024; on a TPU a head dim the kernel cannot tile raises at
+    # trace time rather than falling back silently. Parameter-free, so
+    # switchable on a restored checkpoint.
     # Sharding: the kernel carries a custom_partitioning batch rule —
     # without it GSPMD ALL-GATHERS the operands of a custom call.
     # Validated: zero all-gathers + batch-sharded grads in the
@@ -454,20 +456,19 @@ class ObsConfig:
     # rotation: shift events.jsonl -> .1 past this size, keep N rotated files
     events_max_bytes: int = 8_000_000
     events_keep: int = 3
-    # persistent XLA compilation cache directory ("" = disabled): wired
-    # by the ProgramRegistry (parallel/registry.py) each consumer —
-    # trainer, serve replicas, style, bench — constructs, so every one
-    # of them gets the warm restart uniformly; the jaxmon bridge counts
-    # cache hits vs requests per-registry
-    # (jax_persistent_cache_{hits,requests}_total) so /metrics
-    # distinguishes a warm start from a cold one
+    # persistent XLA compilation cache directory, an explicit override
+    # honoured only when JAX_COMPILATION_CACHE_DIR is unset; "" = the
+    # fixed <checkout>/.jax_cache (obs/jaxmon.enable_compilation_cache
+    # owns the choice). The jaxmon bridge counts cache hits vs requests
+    # per-registry (jax_persistent_cache_{hits,requests}_total) so
+    # /metrics distinguishes a warm start from a cold one
     compilation_cache_dir: str = ""
     # build a ProgramCard for the jitted train step after its first
     # compile (obs/cost.py): emits a one-time `program_card` JSONL event
     # and feeds the achieved-FLOP/s histogram + device-memory watermark.
-    # Costs ONE extra compile of the step program at startup (a
-    # persistent-cache hit when compilation_cache_dir is set); disable on
-    # compile-budget-critical runs
+    # Built right after the step's first jit call, so its AOT compile
+    # is served from jax's in-memory executable cache (no second
+    # compile observed on the chip, PR 21)
     program_card: bool = True
 
     def __post_init__(self):

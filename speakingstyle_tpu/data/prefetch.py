@@ -104,23 +104,14 @@ class DevicePrefetcher:
                 # host materializes only its addressable shards. XLA then
                 # treats the result as one global array over the pod mesh.
                 # make_array_from_process_local_data slices the local data
-                # per the sharding itself; the callback spelling is the
-                # fallback for jax builds that predate it.
-                make = getattr(jax, "make_array_from_process_local_data", None)
-                if make is not None:
-                    # global_shape == local shape tells it each process
-                    # holds the FULL batch; it slices the addressable rows
-                    arrays = {
-                        k: make(self.sharding, v, global_shape=v.shape)
-                        for k, v in arrays.items()
-                    }
-                else:
-                    arrays = {
-                        k: jax.make_array_from_callback(
-                            v.shape, self.sharding, lambda idx, v=v: v[idx]
-                        )
-                        for k, v in arrays.items()
-                    }
+                # per the sharding itself; global_shape == local shape
+                # tells it each process holds the FULL batch
+                arrays = {
+                    k: jax.make_array_from_process_local_data(
+                        self.sharding, v, global_shape=v.shape
+                    )
+                    for k, v in arrays.items()
+                }
             else:
                 # single-process: one device_put against the batch
                 # NamedSharding (never a hard-pinned device — jaxlint

@@ -14,6 +14,7 @@ tests/test_training.py.
 """
 
 import json
+import math
 import os
 
 import numpy as np
@@ -93,6 +94,38 @@ def generate_corpus(
     with open(os.path.join(out_dir, "stats.json"), "w") as f:
         json.dump({"pitch": [lo, hi, 0.0, 1.0], "energy": [elo, ehi, 0.0, 1.0]}, f)
     return out_dir
+
+
+_DURATION_HEAD = (
+    "params", "variance_adaptor", "duration_predictor", "linear_layer",
+)
+
+
+def pin_durations(variables, frames_per_phoneme: int):
+    """Return ``variables`` with the duration predictor's output layer
+    replaced so free-running synthesis gives every phoneme exactly
+    ``frames_per_phoneme`` frames: kernel zeroed, bias ``log(k + 1)``, so
+    ``round(exp(log_d) - 1)`` (ops/length_regulator.predicted_durations) is
+    ``k`` whatever the rest of the weights hold and whatever the host's
+    numerics — the rounding margin survives bfloat16 up to ``k = 32``.
+
+    An untrained duration predictor rounds to 0-1 frames, so a seeded
+    random-weight model vocodes nothing; serve smokes and streaming tests
+    pin the utterance length with this instead of resting on what random
+    weights happen to predict. The input tree is not modified."""
+    k = int(frames_per_phoneme)
+    if not 1 <= k <= 32:
+        raise ValueError(f"frames_per_phoneme must be in 1..32, got {k}")
+
+    def replace(tree, path):
+        if path:
+            return {**tree, path[0]: replace(tree[path[0]], path[1:])}
+        return {
+            "kernel": np.zeros_like(tree["kernel"]),
+            "bias": np.full_like(tree["bias"], math.log(k + 1)),
+        }
+
+    return replace(variables, _DURATION_HEAD)
 
 
 if __name__ == "__main__":

@@ -84,6 +84,11 @@ class ProgramCard:
     alias_bytes: Optional[float] = None
     generated_code_bytes: Optional[float] = None
     peak_bytes: Optional[float] = None
+    # Pallas TPU kernels IN the program: Mosaic custom calls counted in
+    # the compiled text (0 on any other backend). cost_analysis cannot see
+    # into them, and a "fused" model that took its einsum path instead
+    # shows up here as 0 where a TPU program should have some.
+    mosaic_calls: Optional[int] = None
     errors: Tuple[str, ...] = ()
 
     @property
@@ -132,7 +137,26 @@ class ProgramCard:
             v = mem.get(src)
             fields[dst] = float(v) if isinstance(v, (int, float)) else None
         fields["peak_bytes"] = _peak_bytes(mem, fields)
-        return cls(name=name, errors=tuple(errors), **fields)
+        mosaic_calls = _count_mosaic_calls(compiled, errors)
+        return cls(name=name, mosaic_calls=mosaic_calls,
+                   errors=tuple(errors), **fields)
+
+
+MOSAIC_CALL_TARGET = "tpu_custom_call"
+
+
+def _count_mosaic_calls(compiled, errors: List[str]) -> Optional[int]:
+    """Occurrences of the Mosaic custom-call target in the compiled text;
+    reading the text never compiles. None when the backend has no text."""
+    try:
+        text = compiled.as_text()
+    except Exception as e:
+        errors.append(f"as_text: {type(e).__name__}: {e}")
+        return None
+    if not isinstance(text, str):
+        errors.append(f"as_text: unusable type {type(text).__name__}")
+        return None
+    return text.count(MOSAIC_CALL_TARGET)
 
 
 def _extract_cost(compiled, errors: List[str]) -> Dict:

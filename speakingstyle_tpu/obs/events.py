@@ -11,13 +11,17 @@ consumer can rely on:
     total_step + the build identity — git_sha, jax/jaxlib, backend,
     device_count; obs/buildinfo.py), ``program_card`` (one per run,
     after the first compile: the train step's ProgramCard fields —
-    flops, bytes_accessed, argument/output/temp/peak bytes;
-    obs/cost.py), ``train_step`` (step, per-loss fields,
+    flops, bytes_accessed, argument/output/temp/peak bytes,
+    ``mosaic_calls`` (Pallas kernels in the program); obs/cost.py), ``train_step`` (step, per-loss fields,
     ``lr``, ``step_time_s``, ``data_wait_s``, ``steps_per_sec``,
     ``mel_frames_per_sec``), ``val`` (step + per-loss fields),
     ``checkpoint_save`` (step), ``rollback`` (step, ``rollback_n``,
     ``restore_step``), ``fault_fire`` (kind, step), ``preempt_flush``
-    (signal, step), ``quarantine`` (sample ids), ``note`` (msg);
+    (signal, step), ``quarantine`` (sample ids), ``note`` (msg),
+    ``train_end`` (one per run that returns: step, ``cache_dir``,
+    ``compiles``, ``compile_seconds``, ``cache_hits``,
+    ``cache_requests`` — the jax.monitoring totals, so a log directory
+    says whether the run started warm);
   * serving (opt-in, ``serve.log_events``): ``serve_dispatch``
     (``req_ids``, bucket, rows, ``duration_s``) and ``http_request``
     (``req_id``, path, status, ``duration_s``) — ``req_id`` joins the

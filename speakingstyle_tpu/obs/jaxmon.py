@@ -5,7 +5,8 @@ module-level listeners is ever installed; everything downstream
 subscribes to them:
 
   * ``watch_compiles(registry)`` — every backend compile event
-    increments ``jax_backend_compiles_total`` in that registry (each
+    increments ``jax_backend_compiles_total`` (and adds its duration to
+    ``jax_backend_compile_seconds_total``) in that registry (each
     ``SynthesisEngine`` subscribes its own, so ``/metrics`` exports the
     backend's own compile count next to the engine's ``.compile()``
     bookkeeping — two independent witnesses for the zero-steady-state-
@@ -18,11 +19,13 @@ subscribes to them:
     used by the serve smoke test and ``bench.py --serve`` to assert the
     count is zero across a traffic window.
 
-``enable_compilation_cache(dir)`` wires jax's persistent compile cache
-(the ``train.obs.compilation_cache_dir`` knob, applied by each
-consumer's ``ProgramRegistry`` — ``parallel/registry.py`` — before its
-first compile) so repeated runs skip the AOT compiles the cache already
-holds.
+``enable_compilation_cache()`` is the one place that decides where jax's
+persistent compile cache lives: where ``JAX_COMPILATION_CACHE_DIR``
+points, else ``<checkout>/.jax_cache``. Every entry point calls it before
+its first compile (``__main__``, ``bench.py``, ``chip_smoke.py``'s
+children) and every ``ProgramRegistry`` (``parallel/registry.py``) calls
+it again with the ``train.obs.compilation_cache_dir`` override, so
+repeated runs skip the compiles the cache already holds.
 
 jax is imported lazily (on first install), so this module — like the
 rest of ``obs/`` — costs nothing to import in jax-free contexts
@@ -31,7 +34,7 @@ rest of ``obs/`` — costs nothing to import in jax-free contexts
 
 import os
 import threading
-from typing import List
+from typing import Dict, List
 
 from speakingstyle_tpu.obs.registry import MetricsRegistry
 
@@ -54,17 +57,24 @@ _registries: List[MetricsRegistry] = []
 _active_monitors: List["CompileMonitor"] = []
 
 
-def _listener(name: str, *args, **kwargs) -> None:
+_COMPILES_HELP = "XLA backend compiles observed on the jax.monitoring bus"
+_COMPILE_SECONDS_HELP = (
+    "seconds inside the backend-compile scope (a persistent-cache hit "
+    "counts its retrieval time): the set-up cost a warm cache collapses"
+)
+
+
+def _listener(name: str, duration_secs: float = 0.0, **kwargs) -> None:
     if _COMPILE_EVENT not in name:
         return
     with _lock:
         regs = list(_registries)
         mons = list(_active_monitors)
     for r in regs:
+        r.counter("jax_backend_compiles_total", help=_COMPILES_HELP).inc()
         r.counter(
-            "jax_backend_compiles_total",
-            help="XLA backend compiles observed on the jax.monitoring bus",
-        ).inc()
+            "jax_backend_compile_seconds_total", help=_COMPILE_SECONDS_HELP
+        ).inc(duration_secs)
     for m in mons:
         m._bump()
 
@@ -97,9 +107,9 @@ def watch_compiles(registry: MetricsRegistry) -> None:
     (idempotent)."""
     _ensure_installed()
     # touch the counters so /metrics exports 0 before the first compile
+    registry.counter("jax_backend_compiles_total", help=_COMPILES_HELP)
     registry.counter(
-        "jax_backend_compiles_total",
-        help="XLA backend compiles observed on the jax.monitoring bus",
+        "jax_backend_compile_seconds_total", help=_COMPILE_SECONDS_HELP
     )
     for cname, chelp in _CACHE_EVENT_COUNTERS.values():
         registry.counter(cname, help=chelp)
@@ -108,36 +118,57 @@ def watch_compiles(registry: MetricsRegistry) -> None:
             _registries.append(registry)
 
 
-def enable_compilation_cache(cache_dir: str) -> str:
-    """Point jax's persistent compilation cache at ``cache_dir`` (created
-    if missing) and drop the min-size/min-time thresholds so every
-    program — including the serving lattice's small buckets — is cached.
-    Returns the resolved directory. Safe to call after compiles have
-    already happened (a serve process restores its checkpoint — and
-    compiles — before the engine's ProgramRegistry exists): jax latches
-    its cache state on the first compile of the process, so a dir-less
-    latch must be reset or every later write is silently dropped while
-    the hit/request counters keep ticking."""
+def compile_totals(registry: MetricsRegistry) -> Dict[str, float]:
+    """What a watched registry has seen of compilation so far: the
+    set-up cost of a process and whether it started warm (the
+    ``train_end`` event and the chip smoke's legs report exactly this)."""
+    return {
+        "compiles": registry.value("jax_backend_compiles_total"),
+        "compile_seconds": registry.value("jax_backend_compile_seconds_total"),
+        "cache_hits": registry.value("jax_persistent_cache_hits_total"),
+        "cache_requests": registry.value(
+            "jax_persistent_cache_requests_total"),
+    }
+
+
+CACHE_DIR_ENV = "JAX_COMPILATION_CACHE_DIR"
+# fixed, resolved from this file's location: the directory is part of the
+# cache key, so a path that moved between runs would never hit
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".jax_cache",
+)
+
+
+def enable_compilation_cache(override: str = "") -> str:
+    """Place jax's persistent compilation cache and drop the min-size/
+    min-time thresholds so every program — including the serving lattice's
+    small buckets — is cached. Returns the directory in use.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set jax already honours it and
+    no directory is set in code. Otherwise the directory is ``override``
+    (``train.obs.compilation_cache_dir``) or, when that is empty,
+    ``DEFAULT_CACHE_DIR``. Safe to call repeatedly and after compiles have
+    happened: jax builds its cache object on the first compile and keeps
+    it, so a directory that changes afterwards gets the object rebuilt."""
     import jax
 
-    cache_dir = os.path.abspath(os.path.expanduser(cache_dir))
-    os.makedirs(cache_dir, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-    try:
-        from jax._src import compilation_cache as _cc
+    env_dir = os.environ.get(CACHE_DIR_ENV)
+    if env_dir:
+        return env_dir
+    cache_dir = (
+        os.path.abspath(os.path.expanduser(override)) if override
+        else DEFAULT_CACHE_DIR
+    )
+    if jax.config.jax_compilation_cache_dir != cache_dir:
+        from jax._src import compilation_cache
 
-        stale = _cc._cache_initialized and (
-            _cc._cache is None
-            or str(getattr(_cc._cache, "_path", "")) != cache_dir
-        )
-        if stale:
-            _cc.reset_cache()
-    except (ImportError, AttributeError):
-        # private API drift: the cache still works when enabled before
-        # the process's first compile, so don't take the process down
-        pass
+        os.makedirs(cache_dir, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
+        if compilation_cache._cache_initialized:
+            compilation_cache.reset_cache()
     return cache_dir
 
 

@@ -37,7 +37,6 @@ eliminated.
 """
 
 import functools
-import inspect
 from typing import Optional
 
 import jax
@@ -46,17 +45,14 @@ from jax.experimental import pallas as pl
 from jax.experimental.custom_partitioning import custom_partitioning
 from jax.sharding import NamedSharding, PartitionSpec
 
-try:  # pltpu imports fail on builds without the TPU plugin; fallback then
-    from jax.experimental.pallas import tpu as pltpu  # noqa: F401
-
-    _HAVE_PLTPU = True
-except ImportError:  # pragma: no cover
-    pltpu = None
-    _HAVE_PLTPU = False
+from speakingstyle_tpu.ops import on_tpu
 
 LANE = 128
 # VMEM budget guard: f32 scores are Tp*Tp*4 bytes (+ ~3 same-size f32
-# temporaries in bwd); 1024 keeps the worst case ~12 MB.
+# temporaries in bwd); 1024 keeps the worst case ~12 MB. Forward and
+# backward at Tp = 1024 compile and run on a v5e under Mosaic's default
+# scoped-VMEM limit (jax 0.9.0 / libtpu 0.0.34, chip_smoke.py), so no
+# vmem_limit_bytes is passed.
 MAX_T = 1024
 
 
@@ -203,23 +199,7 @@ def _batch_partitioned(fn, rule: str):
             out_sh = _batch_only(mesh, b, (result_infos,))[0]
         return mesh, fn, out_sh, arg_sh
 
-    def infer_sharding(mesh, arg_infos, result_infos):
-        b = _batch_axis(mesh, arg_infos)
-        if isinstance(result_infos, (list, tuple)):
-            return _batch_only(mesh, b, result_infos)
-        return _batch_only(mesh, b, (result_infos,))[0]
-
-    # ``sharding_rule`` (a Shardy einsum rule) exists from jax 0.4.(late)/0.5
-    # onward; older releases take the GSPMD ``infer_sharding_from_operands``
-    # callback instead — same batch-only policy either way.
-    if "sharding_rule" in inspect.signature(
-        custom_partitioning.def_partition
-    ).parameters:
-        cp.def_partition(partition=partition, sharding_rule=rule)
-    else:
-        cp.def_partition(
-            partition=partition, infer_sharding_from_operands=infer_sharding
-        )
+    cp.def_partition(partition=partition, sharding_rule=rule)
     return cp
 
 
@@ -287,27 +267,27 @@ def _reference_mha(q, k, v, pad_mask, sm_scale, softmax_dtype):
 FORCE_INTERPRET = False
 
 
-def _on_tpu() -> bool:
-    if not _HAVE_PLTPU:
-        return False
-    try:
-        dev = jax.devices()[0]
-    except Exception:  # pragma: no cover - backend init failure
-        return False
-    kind = (getattr(dev, "device_kind", "") or "").lower()
-    return "tpu" in dev.platform.lower() or "tpu" in kind
+def _padded_len(T: int) -> int:
+    return -(-T // LANE) * LANE
+
+
+def _sublane(dtype) -> int:
+    """D rides on sublanes in the kernel's [B, H, D, T] layout, so it must
+    be a multiple of the dtype's sublane tiling: 8 for 4-byte dtypes, 16
+    for bf16/f16, 32 for 1-byte dtypes (Mosaic packs 4/itemsize rows per
+    sublane — a D of 8/24/40 in bf16 would pass an %8 gate yet fail
+    lowering on real hardware)."""
+    return max(8, 32 // jnp.dtype(dtype).itemsize)
+
+
+def _head_dim_ok(D: int, dtype) -> bool:
+    return D % _sublane(dtype) == 0 and D <= LANE
 
 
 def supported(T: int, D: int, dtype=jnp.float32) -> bool:
-    """Shapes this kernel handles; callers fall back to einsum otherwise.
-
-    D rides on sublanes in the kernel's [B, H, D, T] layout, so it must be
-    a multiple of the dtype's sublane tiling: 8 for 4-byte dtypes, 16 for
-    bf16/f16, 32 for 1-byte dtypes (Mosaic packs 4/itemsize rows per
-    sublane — a D of 8/24/40 in bf16 would pass an %8 gate yet fail
-    lowering on real hardware)."""
-    sublane = max(8, 32 // jnp.dtype(dtype).itemsize)
-    return D % sublane == 0 and D <= LANE and -(-T // LANE) * LANE <= MAX_T
+    """Shapes this kernel handles: a head dim on the sublane tiling and a
+    lane-padded sequence whose [T, T] score tile fits VMEM."""
+    return _head_dim_ok(D, dtype) and _padded_len(T) <= MAX_T
 
 
 def fused_mha(
@@ -321,23 +301,35 @@ def fused_mha(
 ):
     """Fused self-attention. q/k/v: [B, L, H, D] (the layout the model's
     QKV projections produce); pad_mask: [B, L] True at padding. Returns
-    [B, L, H, D]. Falls back to the einsum reference off-TPU or for
-    unsupported shapes; ``interpret=True`` forces kernel emulation (CPU
-    parity tests)."""
+    [B, L, H, D].
+
+    ``interpret=None`` compiles the kernel on a TPU and takes the einsum
+    reference on any other backend; ``True`` emulates the kernel (CPU
+    parity tests); ``False`` compiles it unconditionally (raises off-TPU).
+    The einsum reference is taken quietly only off-TPU and for sequences
+    past ``MAX_T``; a compiled-kernel request whose head dim the kernel
+    cannot tile raises at trace time instead of silently materialising
+    [B, H, T, T] in HBM."""
     B, L, H, D = q.shape
     if sm_scale is None:
         sm_scale = 1.0 / (D ** 0.5)
-    # interpret=None: auto (real kernel on TPU, einsum fallback elsewhere,
-    # emulated kernel if FORCE_INTERPRET); interpret=True: force kernel
-    # emulation (CPU tests); interpret=False: force the compiled kernel
-    # (raises off-TPU).
     if interpret is None and FORCE_INTERPRET:
         interpret = True
-    use_kernel = _on_tpu() if interpret is None else True
-    if not use_kernel or not supported(L, D, q.dtype):
+    # will Mosaic compile the kernel (as opposed to emulating or skipping it)?
+    compiled = on_tpu() if interpret is None else not interpret
+    Tp = _padded_len(L)
+    if not (compiled or interpret) or Tp > MAX_T:
         return _reference_mha(q, k, v, pad_mask, sm_scale, softmax_dtype)
+    if not _head_dim_ok(D, q.dtype):
+        if not compiled:
+            return _reference_mha(q, k, v, pad_mask, sm_scale, softmax_dtype)
+        raise ValueError(
+            f"attention_kernel='fused' cannot tile head dim {D} in "
+            f"{jnp.dtype(q.dtype).name} (needs a multiple of "
+            f"{_sublane(q.dtype)} up to {LANE}); "
+            "set model.attention_kernel: einsum for this geometry"
+        )
 
-    Tp = -(-L // LANE) * LANE
     pad_t = Tp - L
     # [B, L, H, D] -> [B, H, D, Tp]: T on lanes, D on sublanes
     def to_t(x):
@@ -352,6 +344,6 @@ def fused_mha(
     bias = jnp.where(key_pad, neg, jnp.zeros((), jnp.float32))[:, None, :]
 
     outT = _fused(qT, kT, vT, bias, float(sm_scale), jnp.dtype(softmax_dtype),
-                  bool(interpret) if interpret is not None else False)
+                  not compiled)
     # [B, H, D, Tp] -> [B, L, H, D]
     return outT[..., :L].transpose(0, 3, 1, 2)

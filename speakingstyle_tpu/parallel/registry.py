@@ -24,12 +24,13 @@ Governance the registry provides uniformly:
     returns the SAME ``Compiled`` object without recompiling; the
     registry is the reason "did we already build this program?" has one
     answer instead of four dicts.
-  * **Persistent compile cache** — pass ``cache_dir`` (or let a consumer
-    thread ``train.obs.compilation_cache_dir`` through) and the
-    registry wires jax's persistent cache before its first compile, so
-    every consumer — serve replicas, style, bench, the trainer — gets
-    the ~1.6 s warm restart, not just whichever CLI remembered to call
-    ``enable_compilation_cache``. Hits/requests land per-registry as
+  * **Persistent compile cache** — the constructor calls
+    ``obs.jaxmon.enable_compilation_cache`` (the one owner of the cache
+    directory: ``JAX_COMPILATION_CACHE_DIR``, else ``cache_dir`` — the
+    ``train.obs.compilation_cache_dir`` override — else
+    ``<checkout>/.jax_cache``) before its first compile, so every
+    consumer — serve replicas, style, bench, the trainer — restarts
+    warm. Hits/requests land per-registry as
     ``jax_persistent_cache_{hits,requests}_total`` in the registry's
     metrics (the ``watch_compiles`` bus bridge).
   * **Cards with shardings** — every compile mints a ProgramCard
@@ -62,6 +63,7 @@ proves not just WHAT compiled but HOW SMALL.
 import contextlib
 import functools
 import threading
+import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
 from speakingstyle_tpu.obs.locks import make_lock
 
@@ -269,9 +271,7 @@ class ProgramRegistry:
         # metrics (jax_backend_compiles_total,
         # jax_persistent_cache_{hits,requests}_total)
         watch_compiles(self.metrics)
-        self.cache_dir = (
-            enable_compilation_cache(cache_dir) if cache_dir else None
-        )
+        self.cache_dir = enable_compilation_cache(cache_dir or "")
         self.prefix = prefix
         self._compiles = self.metrics.counter(
             counter_name,
@@ -373,6 +373,7 @@ class ProgramRegistry:
                 if out_shardings is not None:
                     kwargs["out_shardings"] = out_shardings
                 jitted = jax.jit(fn, **kwargs)
+            t0 = time.monotonic()
             with quiet_donation():
                 lowered = jitted.lower(*args)
                 exe = (
@@ -386,11 +387,12 @@ class ProgramRegistry:
             self._programs[key] = exe
             self._by_name[name] = exe
             self._record(exe, name, donate_argnums, in_shardings,
-                         out_shardings, labels, precision)
+                         out_shardings, labels, precision,
+                         compile_seconds=time.monotonic() - t0)
         return exe
 
     def _record(self, exe, name, donate, in_sh, out_sh, labels,
-                precision="f32") -> None:
+                precision="f32", compile_seconds=None) -> None:
         """Mint the ProgramCard, publish gauges, append the card row.
         Caller holds the lock. Card minting only reads compiler metadata
         — it can never itself compile."""
@@ -409,6 +411,9 @@ class ProgramRegistry:
         row["out_shardings"] = _sharding_str(out_sh)
         row["donate_argnums"] = list(donate)
         row["precision"] = precision
+        # lower + compile wall time (a persistent-cache hit shows as a
+        # small number): what a cold start of this program costs
+        row["compile_seconds"] = compile_seconds
         if labels:
             row.update({f"label_{k}": v for k, v in labels.items()})
         self._cards.append(row)

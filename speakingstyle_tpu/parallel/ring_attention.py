@@ -22,13 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-# jax.shard_map is top-level only from 0.5.x; older releases ship it under
-# jax.experimental (getattr with a default so the deprecation module
-# __getattr__ can't raise at import time).
-_shard_map = getattr(jax, "shard_map", None)
-if _shard_map is None:  # pragma: no cover - exercised on jax <= 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 
 def _block_attn(q, k, v, bias, scale):
     """One q-block × kv-block pass -> (unnormalized out, row max, row sumexp)."""
@@ -50,13 +43,7 @@ def ring_attention(q, k, v, bias=None, axis_name: str = "seq", scale: Optional[f
     """
     if scale is None:
         scale = q.shape[-1] ** -0.5
-    if hasattr(jax.lax, "axis_size"):
-        n = jax.lax.axis_size(axis_name)
-    else:  # jax <= 0.4.x: read the size off the axis environment frame
-        # (axis_frame returns the bare size int on 0.4.37, a frame object
-        # with .size on other 0.4.x point releases)
-        n = jax.core.axis_frame(axis_name)
-        n = getattr(n, "size", n)
+    n = jax.lax.axis_size(axis_name)
     perm = [(i, (i + 1) % n) for i in range(n)]
 
     o, m, l = _block_attn(q, k, v, bias, scale)
@@ -96,12 +83,12 @@ def ring_self_attention(
     fn = functools.partial(ring_attention, axis_name=axis_name)
 
     if bias is None:
-        sharded = _shard_map(
+        sharded = jax.shard_map(
             lambda q, k, v: fn(q, k, v, None),
             mesh=mesh, in_specs=in_specs[:3], out_specs=qkv_spec,
         )
         return sharded(q, k, v)
-    sharded = _shard_map(
+    sharded = jax.shard_map(
         lambda q, k, v, b: fn(q, k, v, b),
         mesh=mesh, in_specs=in_specs, out_specs=qkv_spec,
     )

@@ -37,12 +37,13 @@ def build_train_step_card(train_step, state, arrays, rng,
                           program_registry: Optional[ProgramRegistry] = None):
     """ProgramCard (obs/cost.py) for the jitted train step at the given
     batch geometry: XLA's own FLOP/bytes/memory accounting of the step
-    program. The AOT compile goes through the ProgramRegistry (the
-    tree's one compile entry point) and does not share jax's in-memory
-    jit cache, so this costs ONE extra compile of the step program — a
-    persistent-cache hit when ``train.obs.compilation_cache_dir`` is set
-    (the registry wires the cache itself).
-    Returns None (with a warning) rather than ever failing the run."""
+    program, and the count of Pallas kernels in it. The AOT compile goes
+    through the ProgramRegistry (the tree's one compile entry point);
+    called after the step's first jit call it is served from jax's
+    in-memory executable cache (first chip run, PR 21: one ~108 s
+    train-step compile per run, not two). The card is telemetry: a failed
+    card compile returns None (with a warning) rather than failing the
+    run."""
     registry = (
         program_registry if program_registry is not None
         else ProgramRegistry(counter_name="train_compiles_total",
@@ -334,10 +335,10 @@ def run_training(
         local_batch_size(cfg.train.optimizer.batch_size, mesh)
 
     registry = registry if registry is not None else obs.get_registry()
-    # one compile entry point for the run: wires the persistent compile
-    # cache (train.obs.compilation_cache_dir) BEFORE the first jit-on-call
-    # compile and counts/publishes per-program cards for anything compiled
-    # through it (the train-step ProgramCard below)
+    # one compile entry point for the run: places the persistent compile
+    # cache BEFORE the first jit-on-call compile and counts/publishes
+    # per-program cards for anything compiled through it (the train-step
+    # ProgramCard below)
     program_registry = ProgramRegistry(
         registry,
         cache_dir=cfg.train.obs.compilation_cache_dir or None,
@@ -376,10 +377,7 @@ def run_training(
     )
 
     if cfg.train.fast_prng:
-        try:
-            jax.config.update("jax_default_prng_impl", "rbg")
-        except Exception as e:  # pragma: no cover - only future jax renames
-            print(f"warning: fast_prng unavailable ({e}); using default PRNG")
+        jax.config.update("jax_default_prng_impl", "rbg")
 
     model = build_model(cfg)
     rng = jax.random.PRNGKey(cfg.train.seed)
@@ -732,6 +730,15 @@ def run_training(
                     logger.event(
                         "preempt_flush", signal=shutdown.signame, step=step
                     )
+            if logger:
+                # the run's set-up cost, readable without the process: how
+                # many programs it compiled, how long that took, and how
+                # many came out of the persistent cache (warm vs cold)
+                logger.event(
+                    "train_end", step=step,
+                    cache_dir=program_registry.cache_dir,
+                    **obs.jaxmon.compile_totals(registry),
+                )
     finally:
         if trace_active:
             jax.profiler.stop_trace()  # run ended inside the profile window

@@ -88,18 +88,16 @@ def _tiny_cfg(**fleet_kw):
 def tiny_parts():
     import jax
 
+    from speakingstyle_tpu.data.synthetic import pin_durations
     from speakingstyle_tpu.models.factory import build_model, init_variables
     from speakingstyle_tpu.models.hifigan import Generator
 
     cfg = _tiny_cfg()
     model = build_model(cfg, n_position=49)
     variables = init_variables(model, cfg, jax.random.PRNGKey(0))
-    # bias the duration predictor so random weights predict ~2 frames
-    # per phoneme — real multi-window streams flow end-to-end
-    bias = variables["params"]["variance_adaptor"]["duration_predictor"][
-        "linear_layer"]["bias"]
-    variables["params"]["variance_adaptor"]["duration_predictor"][
-        "linear_layer"]["bias"] = bias + 1.1
+    # exactly 2 frames per phoneme, whatever the random weights predict:
+    # real (nonzero) audio of a known length flows end-to-end
+    variables = pin_durations(variables, 2)
     gen = Generator(
         upsample_rates=(2, 2), upsample_kernel_sizes=(4, 4),
         upsample_initial_channel=16, resblock_kernel_sizes=(3,),

@@ -325,6 +325,10 @@ class _GoodCompiled:
     def memory_analysis(self):
         return self._Mem()
 
+    def as_text(self):
+        return ('%c = custom-call(%a), custom_call_target="tpu_custom_call"\n'
+                '%d = custom-call(%c), custom_call_target="tpu_custom_call"')
+
 
 class _RaisingCompiled:
     def cost_analysis(self):
@@ -363,6 +367,7 @@ def test_program_card_full_extraction():
     # peak estimate: args + out + temp + generated - alias
     assert card.peak_bytes == 100 + 50 + 200 + 10 - 25
     assert not card.partial and card.errors == ()
+    assert card.mosaic_calls == 2  # Pallas kernels counted from the text
     assert card.arithmetic_intensity == 2.0
     assert card.achieved_flops_per_sec(0.5) == 2e9
     d = card.as_dict()
@@ -377,6 +382,7 @@ def test_program_card_degrades_on_raising_backend():
     assert card.partial and card.flops is None and card.peak_bytes is None
     assert any("cost_analysis" in e for e in card.errors)
     assert any("memory_analysis" in e for e in card.errors)
+    assert card.mosaic_calls is None  # no text: unknown, never "zero"
     assert card.achieved_flops_per_sec(1.0) is None
     json.dumps(card.as_dict())
     # publishing a fully-degraded card is a no-op, not a crash
@@ -485,18 +491,88 @@ def test_persistent_cache_events_count_into_watched_registries():
     assert reg.value("jax_persistent_cache_hits_total") == 1
 
 
-def test_enable_compilation_cache_points_jax_at_dir(tmp_path):
+def _record_config_updates(monkeypatch):
+    """Patch jax.config.update (and the cache reset) with recorders, so a
+    placement test observes what the owner WOULD set without moving the
+    session's real cache."""
+    import jax
+    from jax._src import compilation_cache
+
+    calls = []
+    monkeypatch.setattr(
+        jax.config, "update", lambda name, value: calls.append((name, value))
+    )
+    monkeypatch.setattr(compilation_cache, "reset_cache", lambda: None)
+    return calls
+
+
+def test_compilation_cache_env_var_wins_and_no_dir_is_set_in_code(
+        tmp_path, monkeypatch):
     import jax
 
     from speakingstyle_tpu.obs import enable_compilation_cache
+    from speakingstyle_tpu.obs.jaxmon import CACHE_DIR_ENV
 
-    before = jax.config.jax_compilation_cache_dir
-    try:
-        resolved = enable_compilation_cache(str(tmp_path / "cache"))
-        assert os.path.isdir(resolved)
-        assert jax.config.jax_compilation_cache_dir == resolved
-    finally:
-        jax.config.update("jax_compilation_cache_dir", before)
+    # tests/conftest.py set the variable before jax was imported, so jax
+    # derived its own config value from the environment
+    env_dir = os.environ[CACHE_DIR_ENV]
+    assert jax.config.jax_compilation_cache_dir == env_dir
+    calls = _record_config_updates(monkeypatch)
+    assert enable_compilation_cache() == env_dir
+    # the explicit override loses to the variable too
+    assert enable_compilation_cache(str(tmp_path / "override")) == env_dir
+    assert not (tmp_path / "override").exists()
+    names = {name for name, _ in calls}
+    assert "jax_compilation_cache_dir" not in names
+    assert names == {
+        "jax_persistent_cache_min_compile_time_secs",
+        "jax_persistent_cache_min_entry_size_bytes",
+    }
+
+
+def test_compilation_cache_default_is_fixed_checkout_path(
+        tmp_path, monkeypatch):
+    from speakingstyle_tpu.obs import enable_compilation_cache
+    from speakingstyle_tpu.obs.jaxmon import CACHE_DIR_ENV, DEFAULT_CACHE_DIR
+
+    checkout = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert DEFAULT_CACHE_DIR == os.path.join(checkout, ".jax_cache")
+    monkeypatch.delenv(CACHE_DIR_ENV)
+    calls = _record_config_updates(monkeypatch)
+    resolved = []
+    for cwd in (tmp_path / "a", tmp_path / "b"):
+        cwd.mkdir()
+        monkeypatch.chdir(cwd)
+        resolved.append(enable_compilation_cache())
+    assert resolved == [DEFAULT_CACHE_DIR, DEFAULT_CACHE_DIR]
+    assert ("jax_compilation_cache_dir", DEFAULT_CACHE_DIR) in calls
+    # train.obs.compilation_cache_dir: honoured only now the variable is unset
+    override = str(tmp_path / "override")
+    assert enable_compilation_cache(override) == override
+    assert os.path.isdir(override)
+    assert calls[-1] == ("jax_compilation_cache_dir", override)
+
+
+def test_cache_dir_has_one_assignment_site():
+    """grep-style: the jax flag that places the persistent cache is named
+    in obs/jaxmon.py and nowhere else in the program (tests aside) — five
+    hard-coded copies of it used to disagree about where the cache lives."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    owner = os.path.join(root, "speakingstyle_tpu", "obs", "jaxmon.py")
+    offenders = []
+    for base, dirs, files in os.walk(root):
+        dirs[:] = [
+            d for d in dirs
+            if not d.startswith(".") and d not in ("tests", "chiprun_out")
+        ]
+        for name in files:
+            path = os.path.join(base, name)
+            if not name.endswith(".py") or path == owner:
+                continue
+            with open(path, encoding="utf-8") as f:
+                if "jax_compilation_cache_dir" in f.read():
+                    offenders.append(os.path.relpath(path, root))
+    assert offenders == []
 
 
 # ---------------------------------------------------------------------------

@@ -454,6 +454,30 @@ def test_fused_mha_unsupported_shapes_fall_back():
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=0)
 
 
+def test_fused_mha_on_tpu_never_falls_back_silently(monkeypatch):
+    """When the kernel would be COMPILED (a TPU backend, or
+    interpret=False), a head dim it cannot tile raises at trace time —
+    before any pallas_call — instead of quietly materialising
+    [B, H, T, T]; only T > MAX_T keeps the quiet einsum path."""
+    from speakingstyle_tpu.ops import pallas_attention as pa
+
+    rng = np.random.default_rng(0)
+    mask = jnp.zeros((2, 9), bool)
+    q = jnp.asarray(rng.standard_normal((2, 9, 2, 20)), jnp.float32)
+    monkeypatch.setattr(pa, "on_tpu", lambda: True)
+    with pytest.raises(ValueError, match="cannot tile head dim 20"):
+        pa.fused_mha(q, q, q, mask)
+    with pytest.raises(ValueError, match="attention_kernel: einsum"):
+        pa.fused_mha(q, q, q, mask, interpret=False)
+    # past MAX_T the einsum reference IS the implementation, on any backend
+    T = pa.MAX_T + 1
+    q = jnp.asarray(rng.standard_normal((1, T, 1, 8)), jnp.float32)
+    mask = jnp.zeros((1, T), bool)
+    out = pa.fused_mha(q, q, q, mask)
+    ref = pa._reference_mha(q, q, q, mask, 1.0 / np.sqrt(8), jnp.float32)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=0)
+
+
 def test_model_attention_kernel_knob():
     """attention_kernel="fused" at the model level: same param tree as
     einsum (the kernel is parameter-free) and matching outputs on CPU

@@ -253,8 +253,6 @@ def test_production_dims_bf16_aot_compile_tp():
     DPxTP program compiles and GSPMD inserted cross-device all-reduces.
     Abstract args (jax.eval_shape / ShapeDtypeStruct) keep it compile-only.
     """
-    import os
-
     from speakingstyle_tpu.configs.config import Config, ModelConfig
     from speakingstyle_tpu.models.factory import build_model, init_variables
     from speakingstyle_tpu.parallel.partition import (
@@ -264,14 +262,6 @@ def test_production_dims_bf16_aot_compile_tp():
     from speakingstyle_tpu.training.optim import make_optimizer
     from speakingstyle_tpu.training.state import TrainState
     from speakingstyle_tpu.training.trainer import make_train_step
-
-    # persistent compile cache: repeat runs of this (slow) compile are warm
-    jax.config.update(
-        "jax_compilation_cache_dir",
-        os.path.join(os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__))), ".jax_cache"),
-    )
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
 
     cfg = Config(model=ModelConfig(compute_dtype="bfloat16"))
     model = build_model(cfg)

@@ -130,42 +130,71 @@ def test_registry_counter_lands_in_shared_metrics():
     assert registry.card("id") is not None
 
 
-def test_registry_persistent_cache_survives_late_enablement(tmp_path):
-    # jax latches its persistent-cache state on the FIRST compile of the
-    # process; a serve process compiles during checkpoint restore, before
-    # the engine's registry exists. A registry constructed afterwards must
-    # still get its writes through (the latch is reset), or warm restarts
-    # silently stop hitting while the request counters keep ticking.
+def _cache_entries(path):
+    import os
+
+    return [f for f in os.listdir(path) if f.endswith("-cache")]
+
+
+def test_registry_cache_lands_where_the_env_var_points(tmp_path):
+    # tests/conftest.py points JAX_COMPILATION_CACHE_DIR at a per-session
+    # directory: every registry reports it, writes there, and ignores the
+    # train.obs.compilation_cache_dir override while the variable is set
     import os
 
     import jax
     import jax.numpy as jnp
 
+    from speakingstyle_tpu.obs.jaxmon import CACHE_DIR_ENV
+    from speakingstyle_tpu.parallel import ProgramRegistry
+
+    env_dir = os.environ[CACHE_DIR_ENV]
+    registry = ProgramRegistry(cache_dir=str(tmp_path / "ignored"))
+    assert registry.cache_dir == env_dir
+    before = set(_cache_entries(env_dir))
+    registry.compile(
+        lambda x: x * 5.0 + 0.125,
+        (jax.ShapeDtypeStruct((6,), jnp.float32),),
+        name="env_placed",
+    )
+    assert set(_cache_entries(env_dir)) - before
+    assert not (tmp_path / "ignored").exists()
+
+
+def test_registry_cache_override_applies_late_when_env_unset(
+        tmp_path, monkeypatch):
+    # jax builds its cache object on the first compile and keeps it. A
+    # serve process compiles during checkpoint restore, before the
+    # engine's registry (and its config override) exists — the override
+    # must still take, or writes keep landing in the old directory while
+    # the request counters tick.
+    import jax
+    import jax.numpy as jnp
+    from jax._src import compilation_cache
+
+    from speakingstyle_tpu.obs.jaxmon import CACHE_DIR_ENV
     from speakingstyle_tpu.parallel import ProgramRegistry
 
     cache_dir = tmp_path / "cc"
     prev_dir = jax.config.jax_compilation_cache_dir
-    # latch: ensure at least one compile happened with no cache dir set
+    # the cache object is now built on the session directory
     jax.jit(lambda x: x + 1.0)(jnp.zeros((2,), jnp.float32))
+    monkeypatch.delenv(CACHE_DIR_ENV)
     try:
         registry = ProgramRegistry(cache_dir=str(cache_dir))
+        assert registry.cache_dir == str(cache_dir)
         registry.compile(
             lambda x: x * 3.0,
             (jax.ShapeDtypeStruct((4,), jnp.float32),),
             name="late",
         )
-        assert any(
-            f.endswith("-cache") for f in os.listdir(cache_dir)
-        ), "registry compile never reached the persistent cache"
+        assert _cache_entries(cache_dir), (
+            "registry compile never reached the overridden cache"
+        )
     finally:
         # leave the process-global cache the way we found it
         jax.config.update("jax_compilation_cache_dir", prev_dir)
-        try:
-            from jax._src import compilation_cache as _cc
-
-            _cc.reset_cache()
-        except Exception:
-            pass
+        compilation_cache.reset_cache()
 
 
 # ---------------------------------------------------------------------------
