@@ -15,12 +15,14 @@ padding (utils/tools.py:285-316) would trigger a recompile every step.
 
 import json
 import os
+import time
 from dataclasses import dataclass
 from typing import Dict, Iterator, List, Optional, Sequence
 
 import numpy as np
 
 from speakingstyle_tpu.configs.config import Config
+from speakingstyle_tpu.obs import MetricsRegistry, Span, get_registry
 from speakingstyle_tpu.text import text_to_sequence
 
 
@@ -85,6 +87,12 @@ class SpeechDataset:
     preemptible slices); ``fault_plan`` (training/faults.py) injects a
     ``loader_ioerror`` exactly once at the named feature-load count so the
     retry path is exercised deterministically in tests.
+
+    ``read_seconds``/``read_files``/``read_bytes`` accumulate what
+    ``np.load`` alone cost (``loader_read``): plain sums and no span, at
+    four files a sample; the batcher that drives the dataset reports
+    their growth once per super-batch (the seconds as a counter, files
+    and bytes on its ``loader_fetch`` span).
     """
 
     def __init__(
@@ -110,6 +118,7 @@ class SpeechDataset:
         self.backoff = backoff
         self.fault_plan = fault_plan
         self._feature_loads = 0  # loader_ioerror@N counter (1-based)
+        self.read_seconds, self.read_files, self.read_bytes = 0.0, 0, 0
         self.entries = parse_metadata(os.path.join(self.root, filename))
         with open(os.path.join(self.root, "speakers.json")) as f:
             self.speaker_map = json.load(f)
@@ -129,7 +138,12 @@ class SpeechDataset:
                 "loader_ioerror", n
             ):
                 raise IOError(f"injected loader_ioerror@{n} ({path})")
-            return np.load(path)
+            t0 = time.monotonic()
+            arr = np.load(path)
+            self.read_seconds += time.monotonic() - t0
+            self.read_files += 1
+            self.read_bytes += arr.nbytes
+            return arr
 
         if not self.retries:
             return load()
@@ -167,6 +181,13 @@ class BucketedBatcher:
     prefetch worker, and the run fails only past the quarantine's
     bad-sample budget. Without it, the first loader error propagates
     (the pre-resilience behavior).
+
+    Spans (obs/trace.py, into ``registry``), on whichever thread drives
+    the iterator (the prefetch worker): ``loader_fetch`` around a
+    super-batch's sample loads (fields: samples, files, bytes), with the
+    seconds of the dataset's ``loader_read`` sum published beside it as
+    ``loader_read_seconds_total``, and ``loader_collate`` around the
+    length sort and each ``_pad_batch``.
     """
 
     def __init__(
@@ -179,6 +200,7 @@ class BucketedBatcher:
         batch_pad_multiple: int = 1,
         seed: int = 1234,
         quarantine=None,
+        registry: Optional[MetricsRegistry] = None,
     ):
         self.ds = dataset
         self.src_bucket = src_bucket
@@ -188,6 +210,7 @@ class BucketedBatcher:
         self.batch_pad_multiple = batch_pad_multiple
         self.quarantine = quarantine
         self.rng = np.random.default_rng(seed)
+        self.registry = registry if registry is not None else get_registry()
 
     def _fetch(self, idx: int) -> Optional[Dict]:
         """Load one sample; quarantine-and-skip (returns None) on failure
@@ -202,6 +225,24 @@ class BucketedBatcher:
                 raise
             self.quarantine.add(sample_id, e)  # raises past the budget
             return None
+
+    def _fetch_all(self, chunk) -> List[Dict]:
+        """One super-batch's samples under a ``loader_fetch`` span (its
+        files and bytes as fields), and the seconds ``np.load`` took of it
+        into ``loader_read_seconds_total``."""
+        ds, reg = self.ds, self.registry
+        before = (ds.read_seconds, ds.read_files, ds.read_bytes)
+        with Span("loader_fetch", registry=reg) as sp:
+            items = [it for i in chunk
+                     if (it := self._fetch(int(i))) is not None]
+            seconds, files, nbytes = (
+                now - was for now, was in
+                zip((ds.read_seconds, ds.read_files, ds.read_bytes), before)
+            )
+            sp.note(samples=len(items), files=files, bytes=nbytes)
+        reg.counter("loader_read_seconds_total",
+                    help="seconds inside np.load of feature files").inc(seconds)
+        return items
 
     def _pad_batch(self, items: Sequence[Dict]) -> Batch:
         n_real = len(items)
@@ -273,17 +314,25 @@ class BucketedBatcher:
         super_size = ds.batch_size * ds.group_size
         for s in range(0, len(order), super_size):
             chunk = order[s : s + super_size]
-            items = [it for i in chunk if (it := self._fetch(int(i))) is not None]
+            items = self._fetch_all(chunk)
             if not items:
                 continue
             if ds.sort:
-                idx = np.argsort([-len(d["text"]) for d in items], kind="stable")
-                items = [items[int(i)] for i in idx]
+                with Span("loader_collate", registry=self.registry,
+                          rows=len(items)):
+                    idx = np.argsort([-len(d["text"]) for d in items],
+                                     kind="stable")
+                    items = [items[int(i)] for i in idx]
             for b in range(0, len(items), ds.batch_size):
                 sub = items[b : b + ds.batch_size]
                 if len(sub) < ds.batch_size and ds.drop_last:
                     continue
-                yield self._pad_batch(sub)
+                with Span("loader_collate", registry=self.registry,
+                          rows=len(sub)) as sp:
+                    batch = self._pad_batch(sub)
+                    sp.note(padded_frames=batch.mels.shape[0] * batch.mels.shape[1],
+                            real_frames=int(batch.mel_lens.sum()))
+                yield batch
 
     def __iter__(self) -> Iterator[Batch]:
         """Infinite stream of batches (the reference's while-True epoch loop)."""

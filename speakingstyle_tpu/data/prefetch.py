@@ -12,6 +12,15 @@ terminal item — either a clean end-of-stream or the error that killed
 the source — never both. ``stop()`` drains, joins the worker, and is
 idempotent; the class is also a context manager so short-lived
 prefetchers (validation passes) cannot leak their thread.
+
+The worker's spans (obs/trace.py): ``loader_h2d`` around each batch's
+host→device transfer where the worker makes one (a mesh is given; on
+the single-chip path the host arrays go to the device inside the jitted
+call, under the step loop's ``train_dispatch``, and no ``loader_h2d`` is
+opened), and ``loader_blocked`` around the put of a batch that found the
+queue full: the loader waiting for the device. Blocked time near zero
+while the step loop's ``train_data_wait`` is not means the loader sets
+the pace; the other way round, the device does.
 """
 
 import queue
@@ -21,7 +30,7 @@ from typing import Iterator, Optional
 import jax
 
 from speakingstyle_tpu.data.dataset import Batch
-from speakingstyle_tpu.obs import MetricsRegistry, get_registry
+from speakingstyle_tpu.obs import MetricsRegistry, Span, get_registry
 from speakingstyle_tpu.parallel.mesh import batch_sharding
 from speakingstyle_tpu.training.resilience import retry_io
 
@@ -75,15 +84,7 @@ class DevicePrefetcher:
         self.queue: "queue.Queue" = queue.Queue(maxsize=depth)
         self.transfer_retries = transfer_retries
         self.transfer_backoff = transfer_backoff
-        # queue occupancy is THE data-pipeline health signal: pinned at
-        # `depth` means the device is the bottleneck (good); at 0 the
-        # step loop is starving on data (the data-wait split in the
-        # trainer says how badly)
         self.registry = registry if registry is not None else get_registry()
-        self._depth_gauge = self.registry.gauge(
-            "data_prefetch_queue_depth",
-            help="prefetch queue occupancy (0 = step loop is data-starved)",
-        )
         self._batches_ctr = self.registry.counter(
             "data_prefetch_batches_total",
             help="batches handed to the step loop",
@@ -124,23 +125,32 @@ class DevicePrefetcher:
 
     def _transfer(self, batch: Batch):
         """Host→device transfer with retry-with-backoff on transient
-        runtime errors (re-entrant, unlike the source iterator)."""
-        if not self.transfer_retries:
+        runtime errors (re-entrant, unlike the source iterator). Without a
+        sharding there is none to make: the jitted call moves the host
+        arrays, and no ``loader_h2d`` span is opened."""
+        if self.sharding is None:
             return self._put(batch)
-        return retry_io(
-            lambda: self._put(batch),
-            retries=self.transfer_retries,
-            backoff=self.transfer_backoff,
-            exceptions=(OSError, jax.errors.JaxRuntimeError),
-            describe="device transfer",
-        )
+        with Span("loader_h2d", registry=self.registry,
+                  bytes=sum(a.nbytes for a in batch.arrays().values())):
+            if not self.transfer_retries:
+                return self._put(batch)
+            return retry_io(
+                lambda: self._put(batch),
+                retries=self.transfer_retries,
+                backoff=self.transfer_backoff,
+                exceptions=(OSError, jax.errors.JaxRuntimeError),
+                describe="device transfer",
+            )
 
     def _bounded_put(self, item) -> bool:
-        """Stop-aware bounded put (see module-level ``bounded_put``)."""
-        ok = bounded_put(self.queue, item, self._stopped)
-        if ok:
-            self._depth_gauge.set(self.queue.qsize())
-        return ok
+        """Stop-aware bounded put (see module-level ``bounded_put``); the
+        wait on a full queue is a ``loader_blocked`` span. The worker is
+        the queue's one producer, so a queue that is not full takes the
+        item without a wait."""
+        if not self.queue.full():
+            return bounded_put(self.queue, item, self._stopped)
+        with Span("loader_blocked", registry=self.registry):
+            return bounded_put(self.queue, item, self._stopped)
 
     def _worker(self):
         terminal = Terminal()
@@ -161,7 +171,6 @@ class DevicePrefetcher:
         if self._finished:
             raise StopIteration
         item = self.queue.get()
-        self._depth_gauge.set(self.queue.qsize())
         if isinstance(item, Terminal):
             self._finished = True
             if item.error is not None:

@@ -8,7 +8,8 @@ The single instrumented spine shared by training, data, and serving
     ``prometheus_text()`` (``GET /metrics``) export surfaces;
   * ``events`` — rotating JSONL event log with a stable documented
     schema (the training run's structured record);
-  * ``trace`` — lightweight monotonic-clock spans feeding both;
+  * ``trace`` — lightweight monotonic-clock spans feeding both, and the
+    profiler's host plane while a trace is taken;
   * ``jaxmon`` — the jax.monitoring bridge (backend compile + persistent
     cache counters, scoped ``CompileMonitor`` windows, the
     ``enable_compilation_cache`` knob);

@@ -9,12 +9,27 @@ consumer can rely on:
     clock and named ``*_s``) and ``event`` (the record type);
   * training emits (trainer.py): ``train_start`` (one per run: step,
     total_step + the build identity — git_sha, jax/jaxlib, backend,
-    device_count; obs/buildinfo.py), ``program_card`` (one per run,
+    device_count; obs/buildinfo.py — and ``setup_s``: seconds of the
+    set-up spans ``model_init``, ``restore``, ``build_steps``,
+    ``datasets``, and ``total`` from the entry of ``run_training``),
+    ``program_card`` (one per run,
     after the first compile: the train step's ProgramCard fields —
     flops, bytes_accessed, argument/output/temp/peak bytes,
-    ``mosaic_calls`` (Pallas kernels in the program); obs/cost.py), ``train_step`` (step, per-loss fields,
-    ``lr``, ``step_time_s``, ``data_wait_s``, ``steps_per_sec``,
-    ``mel_frames_per_sec``), ``val`` (step + per-loss fields),
+    ``mosaic_calls`` (Pallas kernels in the program); obs/cost.py),
+    ``train_step`` (step, per-loss fields, ``lr``, ``steps_per_sec``,
+    ``mel_frames_per_sec``, and per step of the window that ends at
+    this record — a window runs from one log boundary's ``train_log``
+    span to the next's, and a span counts in the window it closes in —
+    the main thread's four disjoint spans ``data_wait_s``,
+    ``dispatch_s``, ``sync_s``, ``log_s`` (``step_time_s`` is dispatch +
+    sync), the prefetch worker's ``loader_fetch_s``, ``loader_read_s``
+    (``np.load`` alone, inside fetch), ``loader_collate_s``,
+    ``loader_h2d_s`` (the worker's ``device_put``: 0 without a mesh,
+    where the jitted call moves the host arrays inside ``dispatch_s``),
+    ``loader_blocked_s``, and ``frames_real`` / ``frames_padded``; the
+    training stream's loader alone, not a validation pass's), ``profile_start`` / ``profile_stop`` (``dir``,
+    step, ``duration_s``: what the profiler's own start and stop held
+    the loop for), ``val`` (step + per-loss fields),
     ``checkpoint_save`` (step), ``rollback`` (step, ``rollback_n``,
     ``restore_step``), ``fault_fire`` (kind, step), ``preempt_flush``
     (signal, step), ``quarantine`` (sample ids), ``note`` (msg),

@@ -34,13 +34,23 @@ traffic is pinned at a configured deterministic sample rate
 ``GET /debug/trace/<req_id>`` on the router assembles the cross-process
 trace with ``assemble_trace`` + ``critical_path``.
 
-For device-side timing use ``jax.profiler.StepTraceAnnotation`` (the
-train loop does) or the on-demand profile capture hooks
-(``POST /debug/profile`` on serve, ``--profile_at`` on train).
+The profiler's clock
+--------------------
+Every ``Span`` also opens a ``jax.profiler.TraceAnnotation`` of its own
+name for as long as it is open, so while a profiler trace is being taken
+(``POST /debug/profile`` on serve, ``run_training(profile_dir=)`` on
+train) each span of the program lands on the host plane of that trace,
+on the clock the device's ``XLA Ops`` line uses: a device idle gap can
+then be put down to the span that was open on the host; the span's
+``fields`` ride on the event as its stats. With no trace running the
+annotation is inert (under a microsecond). jax is never
+imported from here: a process that has not imported it cannot be traced,
+and its spans skip the annotation.
 """
 
 import itertools
 import os
+import sys
 import threading
 import time
 import zlib
@@ -336,6 +346,22 @@ class TailSampler:
         return False
 
 
+_trace_annotation = None  # jax.profiler.TraceAnnotation, once jax is loaded
+
+
+def _profiler_annotation(name: str):
+    """An unopened ``jax.profiler.TraceAnnotation(name)``, or None in a
+    process that has not imported jax (nothing can trace it)."""
+    global _trace_annotation
+    if _trace_annotation is None:
+        if "jax" not in sys.modules:
+            return None
+        from jax.profiler import TraceAnnotation
+
+        _trace_annotation = TraceAnnotation
+    return _trace_annotation(name)
+
+
 class Span:
     """Context manager timing one operation.
 
@@ -351,6 +377,9 @@ class Span:
     ``add_event`` attaches point-in-time events (lease expiry, requeue,
     retry) to the span record. Finished traced spans are appended to
     ``ring`` (default: the process ring) unless tracing is disarmed.
+
+    Profiler: the span is also a ``jax.profiler.TraceAnnotation`` of the
+    same name and extent (module docstring, "The profiler's clock").
     """
 
     def __init__(
@@ -380,6 +409,7 @@ class Span:
         self.ctx: Optional[TraceContext] = None
         self.span_events: List[Dict[str, Any]] = []
         self._ambient_pushed = False
+        self._annotation = None
 
     def note(self, **fields) -> "Span":
         self.fields.update(fields)
@@ -409,12 +439,21 @@ class Span:
         if self.ctx is not None:
             _ctx_stack().append(self.ctx)
             self._ambient_pushed = True
+        self._annotation = _profiler_annotation(self.name)
+        if self._annotation is not None:
+            self._annotation.__enter__()
         self._t0_wall = time.time()
         self._t0 = time.monotonic()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         self.duration_s = time.monotonic() - self._t0
+        if self._annotation is not None:
+            if self.fields and self._annotation.is_enabled():
+                # a trace is being taken: the fields ride on the event
+                self._annotation.set_metadata(**self.fields)
+            self._annotation.__exit__(exc_type, exc, tb)
+            self._annotation = None
         if self._ambient_pushed:
             stack = _ctx_stack()
             if stack and stack[-1] is self.ctx:

@@ -10,7 +10,9 @@ The train step is compiled once per batch-bucket shape (data/dataset.py
 bucket grid); state is replicated, donated, and updated in place.
 """
 
+import contextlib
 import os
+import time
 from typing import Dict, Iterator, Optional
 
 import jax
@@ -21,6 +23,7 @@ from speakingstyle_tpu import obs
 from speakingstyle_tpu.analysis import contracts
 from speakingstyle_tpu.configs.config import Config
 from speakingstyle_tpu.models.loss import fastspeech2_loss
+from speakingstyle_tpu.obs.trace import new_context
 from speakingstyle_tpu.parallel.registry import ProgramRegistry, jit_program
 from speakingstyle_tpu.training import faults, resilience
 from speakingstyle_tpu.training.state import TrainState
@@ -251,6 +254,37 @@ def evaluate(eval_step, state, batches: Iterator) -> Dict[str, float]:
     return {k: v / count for k, v in sums.items()}
 
 
+# What a ``train_step`` event says of its window beyond the losses: each
+# field is the growth, between two log boundaries, of the histogram sum
+# (spans, ``<name>_seconds``) or the counter beside it, over the window's
+# steps. Spans of the step loop first, then the prefetch worker's.
+_WINDOW_HISTOGRAMS = {
+    "data_wait_s": "train_data_wait_seconds",
+    "dispatch_s": "train_step_seconds",
+    "sync_s": "train_sync_seconds",
+    "log_s": "train_log_seconds",
+    "loader_fetch_s": "loader_fetch_seconds",
+    "loader_collate_s": "loader_collate_seconds",
+    "loader_h2d_s": "loader_h2d_seconds",
+    "loader_blocked_s": "loader_blocked_seconds",
+}
+_WINDOW_COUNTERS = {
+    "loader_read_s": "loader_read_seconds_total",
+    "frames_real": "train_frames_real_total",
+    "frames_padded": "train_frames_padded_total",
+}
+
+
+def _window_totals(registry) -> Dict[str, float]:
+    """The sums behind a ``train_step`` event's window fields, as they
+    stand now; a window's share is the difference of two readings."""
+    out = {field: registry.histogram(name).sum
+           for field, name in _WINDOW_HISTOGRAMS.items()}
+    out.update((field, registry.value(name))
+               for field, name in _WINDOW_COUNTERS.items())
+    return out
+
+
 # run_training's mesh default: "resolve from cfg.train.parallel". An
 # explicit mesh=None pins the single-chip path even when the config block
 # names a mesh (the CLI's flag-override contract).
@@ -288,23 +322,33 @@ def run_training(
     each of those paths.
 
     Telemetry (``speakingstyle_tpu/obs``, ARCHITECTURE.md
-    "Observability"): the loop records per-step wall time split into
-    data-wait (time blocked on the prefetcher) vs step time into
-    ``registry`` histograms, wraps the jitted step in
-    ``jax.profiler.StepTraceAnnotation`` so on-demand traces label step
-    boundaries, and — via TrainLogger — appends structured JSONL events
-    (``train_step``/``val``/``checkpoint_save``/``rollback``/
+    "Observability"): the main thread's time is split over four spans
+    (obs/trace.py) that feed ``registry`` histograms and, while a
+    profile is taken, the profiler's host plane: ``train_data_wait``
+    (blocked on the prefetcher), ``train_dispatch`` (the jitted call's
+    enqueue, inside a ``jax.profiler.StepTraceAnnotation``; histogram
+    ``train_step_seconds``), and at a log boundary ``train_sync`` (the
+    device catching up) and ``train_log`` (what logging costs the
+    host). The prefetch worker's spans are in data/prefetch.py and
+    data/dataset.py. Via TrainLogger the loop appends structured JSONL
+    events (``train_step``/``val``/``checkpoint_save``/``rollback``/
     ``fault_fire``/``preempt_flush``/``quarantine``; schema in
     obs/events.py) to a rotating ``events.jsonl`` under
-    ``train.path.log_path`` (``train.obs.*`` knobs). A ``train_start``
-    event records the build identity (git SHA, jax versions, backend,
-    device count); after the first step compiles, a one-time
+    ``train.path.log_path`` (``train.obs.*`` knobs); a ``train_step``
+    event carries its window's share of every span above. Set-up is
+    four spans under one trace id in the process's span ring
+    (``setup_model_init``, ``setup_restore``, ``setup_build_steps``,
+    ``setup_datasets``), joined there by each batch shape's first
+    ``train_dispatch`` (compile or cache load) and the program card's
+    build. A ``train_start`` event records the build identity (git SHA,
+    jax versions, backend, device count) and the set-up spans'
+    durations; after the first step compiles, a one-time
     ``program_card`` event records XLA's own cost/memory accounting of
     the step program (obs/cost.py; gated by ``train.obs.program_card``),
-    which also feeds the ``train_achieved_flops_per_sec`` histogram and
-    the ``device_memory_watermark_bytes`` gauge at log boundaries.
+    which also backs the ``device_memory_watermark_bytes`` gauge at log
+    boundaries.
     """
-    import time
+    t_entry = time.monotonic()
     import jax.numpy as jnp
 
     from speakingstyle_tpu.data import (
@@ -335,6 +379,16 @@ def run_training(
         local_batch_size(cfg.train.optimizer.batch_size, mesh)
 
     registry = registry if registry is not None else obs.get_registry()
+    # the run's trace: set-up phases, first calls and the card build go
+    # into the span ring under it; per-step spans carry no trace id and
+    # stay out (the ring holds thousands of records, a run millions of steps)
+    run_ctx = new_context(f"train-{os.getpid():x}-{time.time():.0f}")
+    setup_spans = []
+
+    def setup_span(name: str) -> obs.Span:
+        setup_spans.append(obs.Span(name, registry=registry, parent=run_ctx))
+        return setup_spans[-1]
+
     # one compile entry point for the run: places the persistent compile
     # cache BEFORE the first jit-on-call compile and counts/publishes
     # per-program cards for anything compiled through it (the train-step
@@ -345,12 +399,13 @@ def run_training(
         counter_name="train_compiles_total",
         prefix="train",
     )
-    step_hist = registry.histogram(
+    # registered here for their help text; the loop's spans observe into them
+    registry.histogram(
         "train_step_seconds",
-        help="per-step wall time excluding data wait (host dispatch; "
-             "device-honest at log boundaries where the loop syncs)",
+        help="per-step host dispatch of the jitted step alone (enqueue "
+             "time; the device catching up is train_sync_seconds)",
     )
-    wait_hist = registry.histogram(
+    registry.histogram(
         "train_data_wait_seconds",
         help="per-step time blocked on the prefetcher",
     )
@@ -364,11 +419,12 @@ def run_training(
     fault_ctr = registry.counter(
         "faults_fired_total", help="injected faults fired (drills)"
     )
-    flops_hist = registry.histogram(
-        "train_achieved_flops_per_sec",
-        edges=obs.FLOPS_PER_SEC_BUCKETS,
-        help="ProgramCard train-step FLOPs / per-step wall time "
-             "(host-dispatch-based; device-honest at log boundaries)",
+    frames_real_ctr = registry.counter(
+        "train_frames_real_total", help="real mel frames handed to the step"
+    )
+    frames_padded_ctr = registry.counter(
+        "train_frames_padded_total",
+        help="mel frames of the padded batch shapes handed to the step",
     )
     mem_gauge = registry.gauge(
         "device_memory_watermark_bytes",
@@ -379,68 +435,67 @@ def run_training(
     if cfg.train.fast_prng:
         jax.config.update("jax_default_prng_impl", "rbg")
 
-    model = build_model(cfg)
-    rng = jax.random.PRNGKey(cfg.train.seed)
-    variables = init_variables(model, cfg, rng)
-    tx = make_optimizer(cfg.train)
-    state = TrainState.create(variables, tx)
-    schedule = make_lr_schedule(cfg.train)
+    with setup_span("setup_model_init"):
+        model = build_model(cfg)
+        rng = jax.random.PRNGKey(cfg.train.seed)
+        variables = init_variables(model, cfg, rng)
+        tx = make_optimizer(cfg.train)
+        state = TrainState.create(variables, tx)
+        schedule = make_lr_schedule(cfg.train)
 
-    ckpt = CheckpointManager(
-        cfg.train.path.ckpt_path,
-        max_to_keep=res.max_to_keep or None,
-        async_save=res.async_checkpointing,
-        keep_best=res.keep_best,
-        fault_plan=plan,
-        registry=registry,
-    )
-
-    state_shardings = None
-    tp_rules = None
-    if mesh is not None:
-        from speakingstyle_tpu.parallel.partition import (
-            parse_rule_overrides,
-            shard_train_state,
-            train_state_shardings,
+    # the checkpoint manager, the state's placement on the mesh, and the
+    # restore where one is asked for
+    with setup_span("setup_restore"):
+        ckpt = CheckpointManager(
+            cfg.train.path.ckpt_path,
+            max_to_keep=res.max_to_keep or None,
+            async_save=res.async_checkpointing,
+            keep_best=res.keep_best,
+            fault_plan=plan,
+            registry=registry,
         )
 
-        if cfg.train.parallel.partition_rules:
-            tp_rules = parse_rule_overrides(cfg.train.parallel.partition_rules)
-        if mesh.shape.get("model", 1) > 1:
-            state_shardings = train_state_shardings(state, mesh, tp_rules)
-            state = shard_train_state(state, mesh, tp_rules)
-        else:
-            state = jax.device_put(state, NamedSharding(mesh, P()))
+        state_shardings = None
+        tp_rules = None
+        if mesh is not None:
+            from speakingstyle_tpu.parallel.partition import (
+                parse_rule_overrides,
+                shard_train_state,
+                train_state_shardings,
+            )
 
-    if restore_step is not None:
-        # cross-mesh-shape resume: the restore runs AFTER sharding, so the
-        # state passed in already carries THIS run's (target) mesh layout.
-        # CheckpointManager.restore builds its abstract template from those
-        # shardings and Orbax materializes the checkpoint directly into the
-        # target layout — whatever mesh shape wrote it (save on 8x1,
-        # restore onto 4x2 or 1x1).
-        state = ckpt.restore(
-            state,
-            step=restore_step if restore_step > 0 else None,
-            ignore_layers=cfg.train.ignore_layers,
+            if cfg.train.parallel.partition_rules:
+                tp_rules = parse_rule_overrides(
+                    cfg.train.parallel.partition_rules)
+            if mesh.shape.get("model", 1) > 1:
+                state_shardings = train_state_shardings(state, mesh, tp_rules)
+                state = shard_train_state(state, mesh, tp_rules)
+            else:
+                state = jax.device_put(state, NamedSharding(mesh, P()))
+
+        if restore_step is not None:
+            # cross-mesh-shape resume: the restore runs AFTER sharding, so
+            # the state passed in already carries THIS run's (target) mesh
+            # layout. CheckpointManager.restore builds its abstract template
+            # from those shardings and Orbax materializes the checkpoint
+            # directly into the target layout — whatever mesh shape wrote
+            # it (save on 8x1, restore onto 4x2 or 1x1).
+            state = ckpt.restore(
+                state,
+                step=restore_step if restore_step > 0 else None,
+                ignore_layers=cfg.train.ignore_layers,
+            )
+
+    with setup_span("setup_build_steps"):
+        train_step = make_train_step(
+            model, tx, cfg, mesh=mesh, state_shardings=state_shardings
         )
-
-    train_step = make_train_step(
-        model, tx, cfg, mesh=mesh, state_shardings=state_shardings
-    )
-    eval_step = make_eval_step(
-        model, cfg, mesh=mesh, state_shardings=state_shardings
-    )
+        eval_step = make_eval_step(
+            model, cfg, mesh=mesh, state_shardings=state_shardings
+        )
 
     max_src = max_mel = cfg.model.max_seq_len
     pad_mult = mesh.shape["data"] if mesh is not None else 1
-    train_ds = SpeechDataset(
-        "train.txt", cfg, sort=True, drop_last=True,
-        retries=res.loader_retries, backoff=res.loader_backoff,
-        fault_plan=plan,
-    )
-    quarantine = resilience.Quarantine(budget=res.bad_sample_budget)
-
     step = int(state.step)
     start_step = step  # profile window is relative to where this run begins
 
@@ -456,6 +511,7 @@ def run_training(
             batch_pad_multiple=pad_mult,
             seed=cfg.train.seed + start_step + 7919 * retry,
             quarantine=quarantine,
+            registry=registry,
         )
         return DevicePrefetcher(
             iter(batcher), mesh=mesh, transfer_retries=res.loader_retries,
@@ -477,15 +533,27 @@ def run_training(
                 s = jax.device_put(s, NamedSharding(mesh, P()))
         return s
 
-    prefetch = make_stream(0)
-    val_ds = SpeechDataset("val.txt", cfg, sort=False, drop_last=False)
-    val_batcher = BucketedBatcher(
-        val_ds,
-        max_src=max_src,
-        max_mel=max_mel,
-        batch_pad_multiple=pad_mult,
-        seed=0,
-    )
+    with setup_span("setup_datasets"):
+        train_ds = SpeechDataset(
+            "train.txt", cfg, sort=True, drop_last=True,
+            retries=res.loader_retries, backoff=res.loader_backoff,
+            fault_plan=plan,
+        )
+        quarantine = resilience.Quarantine(budget=res.bad_sample_budget)
+        prefetch = make_stream(0)
+        val_ds = SpeechDataset("val.txt", cfg, sort=False, drop_last=False)
+        # the validation stream's loader spans observe into a registry of
+        # their own: a train_step event's window fields are deltas of the
+        # run's registry and count the training loader alone
+        val_registry = obs.MetricsRegistry()
+        val_batcher = BucketedBatcher(
+            val_ds,
+            max_src=max_src,
+            max_mel=max_mel,
+            batch_pad_multiple=pad_mult,
+            seed=0,
+            registry=val_registry,
+        )
 
     logger = None
     if log:
@@ -505,19 +573,22 @@ def run_training(
     mesh_devices = (
         list(mesh.devices.flat) if mesh is not None else jax.devices()[:1]
     )
-    n_mesh_devices = len(mesh_devices)
-    device_labels = [f"{d.platform}:{d.id}" for d in mesh_devices]
     if logger:
         # one identity record per run: build + runtime stack + mesh shape,
         # so a log directory is attributable without the shell that
-        # launched it
+        # launched it; and where the start's seconds went, phase by phase
+        # (``total``: entry of run_training to here, imports included)
+        setup_s = {sp.name.removeprefix("setup_"): sp.duration_s
+                   for sp in setup_spans}
+        setup_s["total"] = time.monotonic() - t_entry
         logger.event(
             "train_start", step=step, total_step=total_step,
             mesh_shape=(dict(mesh.shape) if mesh is not None
                         else {"data": 1, "model": 1}),
-            mesh_devices=n_mesh_devices,
+            mesh_devices=len(mesh_devices),
             checkpoint_step=ckpt.last_restored_step,
             weights_digest=ckpt.last_weights_digest,
+            setup_s=setup_s,
             **obs.build_info(),
         )
     if synth_callback == "default":
@@ -534,23 +605,40 @@ def run_training(
     guard = resilience.RollbackGuard(res.max_rollbacks)
     last_val: Optional[float] = None
     last_saved: Optional[int] = None
-    window_t0, window_step0, window_frames = time.perf_counter(), step, 0
-    window_wait = window_compute = 0.0
+    events_log = logger.events if logger else None
+    seen_shapes = set()
+
+    @contextlib.contextmanager
+    def dispatch_span(shape: tuple):
+        """``train_dispatch`` around the jitted call. A batch shape's first
+        call (its compile, or its load from the persistent cache) also goes
+        into the ring, with the shape and what the compile counters grew by."""
+        first = shape not in seen_shapes
+        seen_shapes.add(shape)
+        before = obs.jaxmon.compile_totals(registry) if first else None
+        ring = {"parent": run_ctx, "shape": list(shape)} if first else {}
+        with obs.Span("train_dispatch", registry=registry,
+                      histogram="train_step_seconds", **ring) as sp:
+            yield
+            if first:
+                after = obs.jaxmon.compile_totals(registry)
+                sp.note(**{k: after[k] - before[k] for k in after})
+
+    # a window runs from one log boundary's train_log span to the next's:
+    # the four main-thread spans inside it are disjoint, so their sum can
+    # not pass the window's wall time
+    window_t0, window_step0 = time.monotonic(), step
+    window_totals = _window_totals(registry)
     trace_active = False
     shutdown = resilience.GracefulShutdown()
     try:
         with shutdown:
             while step < total_step and not shutdown.requested:
-                t_iter = time.perf_counter()
-                try:
-                    batch, arrays = next(prefetch)
-                except StopIteration:
+                with obs.Span("train_data_wait", registry=registry):
+                    item = next(prefetch, None)
+                if item is None:
                     break
-                # the data-wait vs device-time split: time blocked on the
-                # prefetcher here, the rest of the iteration below
-                data_wait = time.perf_counter() - t_iter
-                wait_hist.observe(data_wait)
-                window_wait += data_wait
+                batch, arrays = item
                 if plan.fire("nan_grads", step + 1):
                     # under a DP mesh the poison is shard-local (one
                     # device's rows only): the harsher drill — the
@@ -565,43 +653,37 @@ def run_training(
                     and not trace_active
                     and profile_steps[0] <= step - start_step < profile_steps[1]
                 ):
-                    jax.profiler.start_trace(profile_dir)
+                    with obs.Span("profile_start", registry=registry,
+                                  events=events_log, dir=profile_dir,
+                                  step=step):
+                        jax.profiler.start_trace(profile_dir)
                     trace_active = True
                 # step_fn folds state.step into the key, so passing the same
                 # step_rng every iteration yields a fresh per-step stream
-                with jax.profiler.StepTraceAnnotation("train", step_num=step):
+                with jax.profiler.StepTraceAnnotation("train", step_num=step), \
+                        dispatch_span(batch.mels.shape[:2] + batch.texts.shape[1:]):
                     state, losses = train_step(state, arrays, step_rng)  # jaxlint: disable=JL006
                 step += 1
                 steps_ctr.inc()
-                step_time = time.perf_counter() - t_iter - data_wait
-                step_hist.observe(step_time)
-                window_compute += step_time
                 if card_pending:
                     card_pending = False
-                    program_card = build_train_step_card(
-                        train_step, state, arrays, step_rng,
-                        program_registry=program_registry,
-                    )
+                    with obs.Span("train_program_card", registry=registry,
+                                  parent=run_ctx):
+                        program_card = build_train_step_card(
+                            train_step, state, arrays, step_rng,
+                            program_registry=program_registry,
+                        )
                     if program_card is not None and logger:
                         logger.event("program_card", **program_card.as_dict())
-                if program_card is not None and program_card.flops \
-                        and step_time > 0:
-                    flops_hist.observe(program_card.flops / step_time)
-                    # per-device MFU gauges: SPMD splits the step's FLOPs
-                    # evenly over the mesh, so each chip's achieved rate is
-                    # the program total divided by the device count
-                    per_dev = program_card.flops / n_mesh_devices / step_time
-                    for dev in device_labels:
-                        registry.gauge(
-                            "train_achieved_flops_per_sec",
-                            labels={"device": dev},
-                            help="per-device achieved FLOP/s share of the "
-                                 "train step program",
-                        ).set(per_dev)
-                window_frames += int(batch.mel_lens.sum())  # host-side, no sync
+                # host-side, no sync
+                frames_real_ctr.inc(int(batch.mel_lens.sum()))
+                frames_padded_ctr.inc(batch.mels.shape[0] * batch.mels.shape[1])
                 if trace_active and step - start_step >= profile_steps[1]:
-                    jax.block_until_ready(losses["total_loss"])
-                    jax.profiler.stop_trace()
+                    with obs.Span("profile_stop", registry=registry,
+                                  events=events_log, dir=profile_dir,
+                                  step=step):
+                        jax.block_until_ready(losses["total_loss"])
+                        jax.profiler.stop_trace()
                     trace_active = False
                 if plan.fire("sigterm", step):
                     fault_ctr.inc()
@@ -612,12 +694,12 @@ def run_training(
                 if step % steps.log_step == 0:
                     # host boundary: the loop blocks here for logging anyway,
                     # so the sentinel read adds no extra sync point. The
-                    # drain time is charged to the window's compute bucket —
-                    # it IS device time the async dispatches above deferred.
-                    t_sync = time.perf_counter()
-                    jax.block_until_ready(losses["total_loss"])
-                    window_compute += time.perf_counter() - t_sync
-                    if "_finite" in losses and not bool(losses["_finite"]):
+                    # drain IS device time the async dispatches above
+                    # deferred: the event's step_time_s is dispatch + sync.
+                    with obs.Span("train_sync", registry=registry):
+                        jax.block_until_ready(losses["total_loss"])
+                        finite = "_finite" not in losses or bool(losses["_finite"])
+                    if not finite:
                         n = guard.trip(step)  # raises past max_rollbacks
                         ckpt.wait()
                         good = ckpt.latest_step()
@@ -642,62 +724,67 @@ def run_training(
                             state = fresh_state()
                         step = int(state.step)  # jaxlint: disable=JL004
                         prefetch = make_stream(guard.count)
-                        window_t0, window_step0, window_frames = (
-                            time.perf_counter(), step, 0,
-                        )
-                        window_wait = window_compute = 0.0
+                        window_t0, window_step0 = time.monotonic(), step
+                        window_totals = _window_totals(registry)
                         continue
-                    guard.ok()
-                    watermark = obs.device_memory_watermark(program_card)
-                    if watermark is not None:
-                        mem_gauge.set(watermark)
-                    for dev, wm in obs.device_memory_watermarks(
-                        program_card, devices=mesh_devices
-                    ).items():
-                        registry.gauge(
-                            "device_memory_watermark_bytes",
-                            labels={"device": dev},
-                            help="per-device memory watermark (backend "
-                                 "memory_stats peak, else ProgramCard "
-                                 "argument+temp bytes)",
-                        ).set(wm)
-                    if logger:
-                        contracts.assert_tree_finite(
-                            public_losses(losses), "train_step.losses"
-                        )
-                        lr = float(schedule(jnp.asarray(step - 1)))
-                        n_window = step - window_step0
-                        dt = time.perf_counter() - window_t0
-                        timing = None
-                        if n_window > 0:
-                            timing = {
-                                "step_time_s": window_compute / n_window,
-                                "data_wait_s": window_wait / n_window,
-                            }
-                            if dt > 0:
-                                timing["steps_per_sec"] = n_window / dt
-                                timing["mel_frames_per_sec"] = window_frames / dt
-                        logger.log(
-                            step,
-                            {k: float(v) for k, v in public_losses(losses).items()},
-                            lr=lr,
-                            timing=timing,
-                        )
-                        if timing and "steps_per_sec" in timing:
-                            logger.log_throughput(
-                                step, timing["steps_per_sec"],
-                                timing["mel_frames_per_sec"],
+                    t_log = time.monotonic()
+                    with obs.Span("train_log", registry=registry):
+                        guard.ok()
+                        watermark = obs.device_memory_watermark(program_card)
+                        if watermark is not None:
+                            mem_gauge.set(watermark)
+                        for dev, wm in obs.device_memory_watermarks(
+                            program_card, devices=mesh_devices
+                        ).items():
+                            registry.gauge(
+                                "device_memory_watermark_bytes",
+                                labels={"device": dev},
+                                help="per-device memory watermark (backend "
+                                     "memory_stats peak, else ProgramCard "
+                                     "argument+temp bytes)",
+                            ).set(wm)
+                        if logger:
+                            contracts.assert_tree_finite(
+                                public_losses(losses), "train_step.losses"
                             )
-                        window_t0, window_step0, window_frames = (
-                            time.perf_counter(), step, 0,
-                        )
-                        window_wait = window_compute = 0.0
+                            lr = float(schedule(jnp.asarray(step - 1)))
+                            n_window = step - window_step0
+                            dt = t_log - window_t0
+                            timing = None
+                            if n_window > 0:
+                                totals = _window_totals(registry)
+                                timing = {
+                                    k: (totals[k] - window_totals[k]) / n_window
+                                    for k in totals
+                                }
+                                timing["step_time_s"] = (
+                                    timing["dispatch_s"] + timing["sync_s"]
+                                )
+                                window_totals = totals
+                                if dt > 0:
+                                    timing["steps_per_sec"] = n_window / dt
+                                    timing["mel_frames_per_sec"] = (
+                                        timing["frames_real"] * n_window / dt
+                                    )
+                            logger.log(
+                                step,
+                                {k: float(v)
+                                 for k, v in public_losses(losses).items()},
+                                lr=lr,
+                                timing=timing,
+                            )
+                            if timing and "steps_per_sec" in timing:
+                                logger.log_throughput(
+                                    step, timing["steps_per_sec"],
+                                    timing["mel_frames_per_sec"],
+                                )
+                            window_t0, window_step0 = t_log, step
                 if synth_callback is not None and step % steps.synth_step == 0:
                     synth_callback(state, batch, arrays, step, model)
                 if step % steps.val_step == 0:
                     with DevicePrefetcher(
                         val_batcher.epoch(shuffle=False), mesh=mesh,
-                        registry=registry,
+                        registry=val_registry,
                     ) as val_prefetch:
                         val_losses = evaluate(eval_step, state, val_prefetch)
                     # evaluate() already returns host floats

@@ -310,7 +310,8 @@ def test_mesh_train_smoke_nan_rollback_and_per_device_gauges(
     """One drill, three acceptance criteria: run_training resolves the
     8x1 mesh from train.parallel alone; the shard-local nan_grads fault
     trips the dp-reduced sentinel into the same rollback as single-chip;
-    and the per-device MFU/memory gauges land in the registry snapshot."""
+    and the per-device memory gauges land in the registry snapshot, the
+    loop's spans in every train_step event."""
     from speakingstyle_tpu.obs import get_registry
 
     monkeypatch.setenv(faults.ENV_VAR, "nan_grads@3")
@@ -322,11 +323,21 @@ def test_mesh_train_smoke_nan_rollback_and_per_device_gauges(
     assert "non-finite losses/grads at step 3" in log
     assert "rollback 1/3 to checkpoint step 2" in log
 
+    from speakingstyle_tpu.obs import read_events
+    from tests.test_obs import WINDOW_FIELDS
+
+    steps = list(read_events(str(tmp_path / "log"), event="train_step"))
+    assert [e["step"] for e in steps][-1] == 6 and len(steps) >= 6
+    for rec in steps:
+        assert all(rec[f] >= 0 for f in WINDOW_FIELDS), rec
+        assert (rec["data_wait_s"] + rec["dispatch_s"] + rec["sync_s"]
+                + rec["log_s"]) <= 1.0 / rec["steps_per_sec"]
+    # under a mesh the prefetch worker makes the transfer, and times it
+    assert sum(rec["loader_h2d_s"] for rec in steps) > 0
+    (start,) = read_events(str(tmp_path / "log"), event="train_start")
+    assert start["setup_s"]["restore"] >= 0 and start["setup_s"]["total"] > 0
+
     snap = get_registry().snapshot()["gauges"]
-    labels = [f'train_achieved_flops_per_sec{{device="cpu:{i}"}}'
-              for i in range(8)]
-    assert all(k in snap for k in labels), sorted(snap)
-    assert all(snap[k] > 0 for k in labels)
     mem = [k for k in snap
            if k.startswith('device_memory_watermark_bytes{device="cpu:')]
     assert len(mem) == 8 and all(snap[k] > 0 for k in mem), sorted(snap)

@@ -9,7 +9,8 @@ Layers:
   2. events — schema round-trip (every record carries ts + event),
      numpy-value coercion, size rotation, cross-rotation reads, and the
      summarize/filter CLI;
-  3. spans — duration into the histogram + a joinable JSONL record;
+  3. spans — duration into the histogram + a joinable JSONL record, and
+     the same span on the host plane of a profiler trace;
   4. the training smoke — a supertiny run_training populates
      step-time/data-wait histograms and writes train_step events with
      the documented step/loss/step_time_s/data_wait_s fields (the
@@ -270,6 +271,81 @@ def test_span_records_error_and_still_observes(tmp_path):
     (rec,) = read_events(str(tmp_path))
     assert rec["ok"] is False and rec["error"] == "ValueError"
     assert reg.histogram("op_seconds").count == 1
+
+
+def test_span_lands_on_the_profilers_host_plane(tmp_path):
+    """A Span closed while jax.profiler takes a trace is an event of its
+    own name on a host plane of the .xplane.pb, its fields the event's
+    stats, on the main thread and on a worker alike."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    reg = MetricsRegistry()
+
+    def worker():
+        with Span("loader_fetch_probe", registry=reg) as sp:
+            sp.note(files=4)
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with Span("train_data_wait_probe", registry=reg, rows=2):
+            t = threading.Thread(target=worker)
+            t.start()
+            t.join(timeout=30)
+    finally:
+        jax.profiler.stop_trace()
+    assert not t.is_alive()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    found = {}
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.endswith("_probe"):
+                    found[ev.name] = (ev.duration_ns, dict(ev.stats))
+    assert set(found) == {"train_data_wait_probe", "loader_fetch_probe"}
+    assert found["train_data_wait_probe"][1]["rows"] == 2
+    assert found["loader_fetch_probe"][1]["files"] == 4
+    # the worker's span closed inside the main thread's
+    assert 0 < found["loader_fetch_probe"][0] <= found["train_data_wait_probe"][0]
+    # the histograms saw the same two spans
+    assert reg.histogram("train_data_wait_probe_seconds").count == 1
+    assert reg.histogram("loader_fetch_probe_seconds").count == 1
+
+
+def test_span_annotation_is_inert_without_a_trace():
+    """With no trace running a span's annotation is switched off (nothing
+    is recorded anywhere) and its cost is far below what the step loop
+    could notice: a dozen spans a step against a step of 0.3 s."""
+    import time
+
+    import jax  # noqa: F401  (the annotation exists only once jax is loaded)
+    from speakingstyle_tpu.obs import trace as obs_trace
+
+    ann = obs_trace._profiler_annotation("idle_probe")
+    assert ann is not None and not ann.is_enabled()
+    reg = MetricsRegistry()
+    n = 2000
+    t0 = time.monotonic()
+    for _ in range(n):
+        with Span("idle_probe", registry=reg):
+            pass
+    per_span = (time.monotonic() - t0) / n
+    assert reg.histogram("idle_probe_seconds").count == n
+    assert per_span < 1e-3  # microseconds here; a millisecond would be a fault
+
+
+def test_obs_import_does_not_import_jax():
+    import subprocess
+    import sys
+
+    code = ("import sys, speakingstyle_tpu.obs as o\n"
+            "with o.Span('x', registry=o.MetricsRegistry()): pass\n"
+            "sys.exit(1 if 'jax' in sys.modules else 0)")
+    assert subprocess.run([sys.executable, "-c", code], timeout=120).returncode == 0
 
 
 # ---------------------------------------------------------------------------
@@ -619,13 +695,22 @@ def test_events_cli_programs_pretty_prints_and_rooflines(tmp_path, capsys):
 # ---------------------------------------------------------------------------
 
 
+WINDOW_FIELDS = (
+    "dispatch_s", "sync_s", "log_s", "loader_read_s", "loader_fetch_s",
+    "loader_collate_s", "loader_h2d_s", "loader_blocked_s", "frames_real",
+    "frames_padded",
+)
+
+
 def test_train_smoke_populates_metrics_and_event_log(
     synthetic_preprocessed, tmp_path
 ):
     """A tiny run_training must (a) record step-time and data-wait into
     the registry histograms, and (b) write train_step JSONL events
-    carrying the documented step/loss/step_time_s/data_wait_s fields,
-    plus the checkpoint_save record for the final flush."""
+    carrying the documented step/loss/step_time_s/data_wait_s fields and
+    the window's share of every span of the loop and the loader, plus the
+    checkpoint_save record for the final flush and a train_start that says
+    where set-up's seconds went."""
     from tests.test_resilience import _train_config
 
     cfg = _train_config(synthetic_preprocessed, tmp_path, total=3, save=2,
@@ -645,11 +730,17 @@ def test_train_smoke_populates_metrics_and_event_log(
     assert wait_hist["count"] == 3 and wait_hist["p95"] is not None
     # the prefetcher reported its side of the pipeline too
     assert snap["counters"]["data_prefetch_batches_total"] >= 3
-    # the ProgramCard layer: achieved FLOP/s observed once per step from
-    # the card built after the first compile, and the memory watermark
-    # gauge set at every log boundary (card fallback on CPU)
-    flops_hist = snap["histograms"]["train_achieved_flops_per_sec"]
-    assert flops_hist["count"] == 3 and flops_hist["p50"] > 0
+    # the loader's spans and counter, from the worker thread (no mesh: the
+    # worker makes no transfer, so loader_h2d stays empty)
+    for name in ("loader_fetch_seconds", "loader_collate_seconds"):
+        assert snap["histograms"][name]["count"] >= 1, name
+    assert snap["histograms"]["loader_h2d_seconds"]["count"] == 0
+    assert (0 < snap["counters"]["loader_read_seconds_total"]
+            <= snap["histograms"]["loader_fetch_seconds"]["sum"])
+    assert (snap["counters"]["train_frames_padded_total"]
+            >= snap["counters"]["train_frames_real_total"] > 0)
+    # the ProgramCard layer: the memory watermark gauge set at every log
+    # boundary (card fallback on CPU)
     assert snap["gauges"]["device_memory_watermark_bytes"] > 0
 
     log_dir = cfg.train.path.log_path
@@ -662,13 +753,68 @@ def test_train_smoke_populates_metrics_and_event_log(
         assert rec["step_time_s"] >= 0
         assert rec["data_wait_s"] >= 0
         assert "lr" in rec
+        for field in WINDOW_FIELDS:
+            assert rec[field] >= 0, field
+        assert rec["step_time_s"] == pytest.approx(
+            rec["dispatch_s"] + rec["sync_s"])
+        # the main thread's four spans are disjoint inside the window
+        assert (rec["data_wait_s"] + rec["dispatch_s"] + rec["sync_s"]
+                + rec["log_s"]) <= 1.0 / rec["steps_per_sec"]
+        assert 0 < rec["frames_real"] <= rec["frames_padded"]
     saves = list(read_events(log_dir, event="checkpoint_save"))
     assert saves and saves[-1]["step"] == 3  # final tail-step flush
     # one train_start event identifying the stack that ran
     (start,) = read_events(log_dir, event="train_start")
     assert start["jax"] and start["backend"] and start["device_count"] >= 1
+    setup = start["setup_s"]
+    assert set(setup) == {"model_init", "restore", "build_steps", "datasets",
+                          "total"}
+    assert all(v >= 0 for v in setup.values())
+    assert sum(v for k, v in setup.items() if k != "total") <= setup["total"]
+    # the same phases, each shape's first call and the card's build are in
+    # the process's span ring under the run's one trace id
+    from speakingstyle_tpu.obs.trace import get_span_ring
+
+    ring = get_span_ring().spans()
+    run = [s for s in ring if s["name"] == "setup_model_init"][-1]["trace_id"]
+    mine = [s for s in ring if s["trace_id"] == run]
+    names = [s["name"] for s in mine]
+    assert names[:4] == ["setup_model_init", "setup_restore",
+                         "setup_build_steps", "setup_datasets"]
+    firsts = [s for s in mine if s["name"] == "train_dispatch"]
+    assert 1 <= len(firsts) <= 3 and "train_program_card" in names
+    assert len({tuple(s["fields"]["shape"]) for s in firsts}) == len(firsts)
+    assert all(s["fields"]["compiles"] >= 0 for s in firsts)
     # one program_card event: XLA's own accounting of the step program
     (card,) = read_events(log_dir, event="program_card")
     assert card["name"] == "train_step"
     assert card["flops"] > 0 and card["bytes_accessed"] > 0
     assert card["peak_bytes"] > 0 and card["partial"] is False
+
+
+def test_validation_pass_stays_out_of_the_windows_loader_fields(
+    synthetic_preprocessed, tmp_path
+):
+    """A validation pass has a loader of its own under the same span names;
+    it observes into a registry of its own, so the run's registry, whose
+    deltas are a ``train_step`` event's window fields, counts the training
+    loader alone."""
+    import dataclasses
+
+    from speakingstyle_tpu.training.trainer import run_training
+    from tests.test_resilience import _train_config
+
+    cfg = _train_config(synthetic_preprocessed, tmp_path, total=4, save=10,
+                        log=2)
+    step = dataclasses.replace(cfg.train.step, val_step=2)
+    cfg = dataclasses.replace(
+        cfg, train=dataclasses.replace(cfg.train, step=step))
+    reg = MetricsRegistry()
+    run_training(cfg, max_steps=4, registry=reg)
+    log_dir = cfg.train.path.log_path
+    assert len(list(read_events(log_dir, event="val"))) == 2
+    # four training batches reached the step loop; validation's did not count
+    assert reg.value("data_prefetch_batches_total") == 4
+    padded = sum(e["frames_padded"] * 2
+                 for e in read_events(log_dir, event="train_step"))
+    assert padded == reg.value("train_frames_padded_total")
