@@ -156,14 +156,15 @@ def _restored_state(cfg, model, restore_step):
 
 
 def _predictions(cfg, split, restore_step, max_batches):
-    from speakingstyle_tpu.data import BucketedBatcher, SpeechDataset
+    from speakingstyle_tpu.data import BucketedBatcher, CacheBudget, SpeechDataset
     from speakingstyle_tpu.models.factory import build_model
     from speakingstyle_tpu.parallel.registry import jit_program
 
     model = build_model(cfg)
     state = _restored_state(cfg, model, restore_step)
 
-    ds = SpeechDataset(split, cfg, sort=False, drop_last=False)
+    ds = SpeechDataset(split, cfg, sort=False, drop_last=False,
+                       cache=CacheBudget(0))  # one pass: nothing to keep
     batcher = BucketedBatcher(
         ds, max_src=cfg.model.max_seq_len, max_mel=cfg.model.max_seq_len
     )
@@ -205,7 +206,7 @@ def _predictions(cfg, split, restore_step, max_batches):
 def _style(cfg, split, restore_step, max_batches):
     from flax.traverse_util import flatten_dict
 
-    from speakingstyle_tpu.data import BucketedBatcher, SpeechDataset
+    from speakingstyle_tpu.data import BucketedBatcher, CacheBudget, SpeechDataset
     from speakingstyle_tpu.models.factory import build_model
 
     model = build_model(cfg)
@@ -217,7 +218,8 @@ def _style(cfg, split, restore_step, max_batches):
         if k[-1] in ("s_gamma", "s_beta")
     }
 
-    ds = SpeechDataset(split, cfg, sort=False, drop_last=False)
+    ds = SpeechDataset(split, cfg, sort=False, drop_last=False,
+                       cache=CacheBudget(0))  # one pass: nothing to keep
     batcher = BucketedBatcher(
         ds, max_src=cfg.model.max_seq_len, max_mel=cfg.model.max_seq_len
     )

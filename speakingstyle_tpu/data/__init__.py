@@ -3,6 +3,7 @@
 from speakingstyle_tpu.data.dataset import (
     Batch,
     BucketedBatcher,
+    CacheBudget,
     SpeechDataset,
     TextBatcher,
     bucket_length,
@@ -13,6 +14,7 @@ from speakingstyle_tpu.data.prefetch import DevicePrefetcher
 __all__ = [
     "Batch",
     "BucketedBatcher",
+    "CacheBudget",
     "SpeechDataset",
     "TextBatcher",
     "bucket_length",

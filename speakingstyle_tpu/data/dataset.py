@@ -11,10 +11,16 @@ shape from a small static bucket grid — (src rounded up to ``src_bucket``,
 mel rounded up to ``mel_bucket``) — so XLA compiles a handful of programs
 instead of one per batch shape. The reference's dynamic per-batch max-length
 padding (utils/tools.py:285-316) would trigger a recompile every step.
+
+A run reads the same utterances once an epoch, thousands of times over, so
+``SpeechDataset`` keeps each finished sample in host memory after its first
+read (``CacheBudget``): from the second epoch on, whatever fits is served
+without opening a file.
 """
 
 import json
 import os
+import threading
 import time
 from dataclasses import dataclass
 from typing import Dict, Iterator, List, Optional, Sequence
@@ -79,8 +85,58 @@ class Batch:
         }
 
 
+def host_available_bytes() -> int:
+    """What the host says a process may still take: ``MemAvailable`` of
+    ``/proc/meminfo``, the physical memory where there is no such line."""
+    try:
+        with open("/proc/meminfo") as f:
+            for line in f:
+                if line.startswith("MemAvailable:"):
+                    return int(line.split()[1]) * 1024
+    except OSError:
+        pass
+    return os.sysconf("SC_PHYS_PAGES") * os.sysconf("SC_PAGE_SIZE")
+
+
+class CacheBudget:
+    """Bytes of finished samples that the datasets given this budget may
+    hold between them. Observed, not set: with no ``limit`` it is a quarter
+    of what the host reports as available when the budget is made (each
+    process of a multi-host run makes its own). A limit of 0 holds nothing.
+
+    Admission only, never a release: every epoch is a fresh permutation, so
+    of a corpus larger than the budget any eviction rule would hit the
+    share it holds and no more.
+    """
+
+    HOST_SHARE = 0.25
+
+    def __init__(self, limit: Optional[int] = None):
+        self.limit = (int(self.HOST_SHARE * host_available_bytes())
+                      if limit is None else int(limit))
+        self.held = 0
+        self._lock = threading.Lock()  # train and validation loaders share it
+
+    def admit(self, nbytes: int) -> bool:
+        """Take ``nbytes`` of the budget if that much is left."""
+        with self._lock:
+            if self.held + nbytes > self.limit:
+                return False
+            self.held += nbytes
+            return True
+
+
 class SpeechDataset:
     """Feature-loading dataset (reference: dataset.py:12-146).
+
+    A sample is built once and then kept, finished (ids and the four arrays
+    after their casts, marked read-only), for as long as ``cache`` has room:
+    ``__getitem__`` serves it from memory from then on and reads nothing. A
+    sample that does not fit is built from its files every time, as is one
+    whose load raised. ``cache=None`` makes a budget of this dataset's own
+    from the host's memory; a run's datasets share one; one-pass callers
+    pass ``CacheBudget(0)``. ``cache_hits``/``cache_misses`` count the
+    samples served either way.
 
     ``retries``/``backoff`` engage retry-with-exponential-backoff on
     transient OSErrors in the feature loads (flaky network filesystems on
@@ -90,9 +146,9 @@ class SpeechDataset:
 
     ``read_seconds``/``read_files``/``read_bytes`` accumulate what
     ``np.load`` alone cost (``loader_read``): plain sums and no span, at
-    four files a sample; the batcher that drives the dataset reports
-    their growth once per super-batch (the seconds as a counter, files
-    and bytes on its ``loader_fetch`` span).
+    four files a sample that is not held; the batcher that drives the
+    dataset reports their growth once per super-batch (the seconds as a
+    counter, files and bytes on its ``loader_fetch`` span).
     """
 
     def __init__(
@@ -104,6 +160,7 @@ class SpeechDataset:
         retries: int = 0,
         backoff: float = 0.05,
         fault_plan=None,
+        cache: Optional[CacheBudget] = None,
     ):
         pp = config.preprocess
         self.root = pp.path.preprocessed_path
@@ -118,6 +175,9 @@ class SpeechDataset:
         self.backoff = backoff
         self.fault_plan = fault_plan
         self._feature_loads = 0  # loader_ioerror@N counter (1-based)
+        self.cache = cache if cache is not None else CacheBudget()
+        self._held: Dict[int, Dict] = {}
+        self.cache_hits, self.cache_misses = 0, 0
         self.read_seconds, self.read_files, self.read_bytes = 0.0, 0, 0
         self.entries = parse_metadata(os.path.join(self.root, filename))
         with open(os.path.join(self.root, "speakers.json")) as f:
@@ -152,7 +212,7 @@ class SpeechDataset:
             exceptions=(OSError,), describe=path,
         )
 
-    def __getitem__(self, idx: int) -> Dict:
+    def _build(self, idx: int) -> Dict:
         basename, speaker, text, raw = self.entries[idx]
         phones = np.asarray(text_to_sequence(text, self.cleaners), np.int32)
         return {
@@ -165,6 +225,20 @@ class SpeechDataset:
             "energy": self._feature("energy", speaker, basename).astype(np.float32),
             "duration": self._feature("duration", speaker, basename).astype(np.int32),
         }
+
+    def __getitem__(self, idx: int) -> Dict:
+        sample = self._held.get(idx)
+        if sample is not None:
+            self.cache_hits += 1
+            return dict(sample)
+        sample = self._build(idx)  # a load that raises keeps nothing
+        self.cache_misses += 1
+        arrays = [v for v in sample.values() if isinstance(v, np.ndarray)]
+        if self.cache.admit(sum(a.nbytes for a in arrays)):
+            for a in arrays:
+                a.flags.writeable = False
+            self._held[idx] = sample
+        return dict(sample)
 
 
 class BucketedBatcher:
@@ -184,10 +258,13 @@ class BucketedBatcher:
 
     Spans (obs/trace.py, into ``registry``), on whichever thread drives
     the iterator (the prefetch worker): ``loader_fetch`` around a
-    super-batch's sample loads (fields: samples, files, bytes), with the
-    seconds of the dataset's ``loader_read`` sum published beside it as
-    ``loader_read_seconds_total``, and ``loader_collate`` around the
-    length sort and each ``_pad_batch``.
+    super-batch's sample loads (fields: samples, of them ``hits`` served
+    from the dataset's memory, and the files and bytes still read), with
+    the seconds of the dataset's ``loader_read`` sum published beside it as
+    ``loader_read_seconds_total``, the samples served from memory and from
+    files as ``loader_cache_hits_total`` / ``loader_cache_misses_total``,
+    the bytes held against the budget as ``loader_cache_bytes``; and
+    ``loader_collate`` around the length sort and each ``_pad_batch``.
     """
 
     def __init__(
@@ -227,21 +304,32 @@ class BucketedBatcher:
             return None
 
     def _fetch_all(self, chunk) -> List[Dict]:
-        """One super-batch's samples under a ``loader_fetch`` span (its
-        files and bytes as fields), and the seconds ``np.load`` took of it
-        into ``loader_read_seconds_total``."""
+        """One super-batch's samples under a ``loader_fetch`` span (how
+        many came from memory, and the files and bytes still read, as
+        fields); the seconds ``np.load`` took of it and the samples served
+        either way into the registry's counters."""
         ds, reg = self.ds, self.registry
-        before = (ds.read_seconds, ds.read_files, ds.read_bytes)
+
+        def sums():
+            return (ds.read_seconds, ds.read_files, ds.read_bytes,
+                    ds.cache_hits, ds.cache_misses)
+
+        before = sums()
         with Span("loader_fetch", registry=reg) as sp:
             items = [it for i in chunk
                      if (it := self._fetch(int(i))) is not None]
-            seconds, files, nbytes = (
-                now - was for now, was in
-                zip((ds.read_seconds, ds.read_files, ds.read_bytes), before)
-            )
-            sp.note(samples=len(items), files=files, bytes=nbytes)
+            seconds, files, nbytes, hits, misses = (
+                now - was for now, was in zip(sums(), before))
+            sp.note(samples=len(items), hits=hits, files=files, bytes=nbytes)
         reg.counter("loader_read_seconds_total",
                     help="seconds inside np.load of feature files").inc(seconds)
+        reg.counter("loader_cache_hits_total",
+                    help="samples served from host memory").inc(hits)
+        reg.counter("loader_cache_misses_total",
+                    help="samples built from their feature files").inc(misses)
+        reg.gauge("loader_cache_bytes",
+                  help="bytes of finished samples held in host memory"
+                  ).set(ds.cache.held)
         return items
 
     def _pad_batch(self, items: Sequence[Dict]) -> Batch:

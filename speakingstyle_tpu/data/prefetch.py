@@ -1,9 +1,12 @@
 """Background-thread host→device prefetch.
 
 Replaces the reference's 40-worker torch DataLoader (reference:
-train.py:33-41): feature loading + collate run on a worker thread pool while
-the device computes, and finished batches are device_put with the mesh's
-batch sharding ahead of time so each step starts with data already in HBM.
+train.py:33-41): feature loading + collate run on one worker thread while
+the device computes, and under a mesh finished batches are device_put with
+its batch sharding ahead of time so each step starts with data already in
+HBM. One thread is enough because the dataset keeps every sample it has
+read (data/dataset.py, ``CacheBudget``): only a run's first epoch, and what
+of a corpus does not fit the host's memory, is paced by the file system.
 
 Shutdown contract (ISSUE 2 hardening): the worker only ever blocks on a
 *stop-aware bounded put* (it polls the stop event while the queue is

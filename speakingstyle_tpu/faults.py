@@ -21,7 +21,11 @@ Counter semantics per kind:
   training (consumed via training/faults.py, which re-exports this plan):
 
   ``loader_ioerror@N``  Nth call of ``SpeechDataset._feature`` (1-based,
-                        counted per dataset instance)
+                        counted per dataset instance). A call is a file
+                        read: four a sample through a first epoch, and
+                        none for a sample the dataset then holds in
+                        memory, so an N past the first epoch's reads
+                        fires only where the corpus outgrows the budget
   ``nan_grads@N``       the batch consumed by the train step whose
                         post-increment step counter is N
   ``sigterm@N``         delivered after step N completes

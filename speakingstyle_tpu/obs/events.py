@@ -11,7 +11,9 @@ consumer can rely on:
     total_step + the build identity — git_sha, jax/jaxlib, backend,
     device_count; obs/buildinfo.py — and ``setup_s``: seconds of the
     set-up spans ``model_init``, ``restore``, ``build_steps``,
-    ``datasets``, and ``total`` from the entry of ``run_training``),
+    ``datasets``, and ``total`` from the entry of ``run_training``;
+    and ``loader_cache_budget_bytes``, the host memory the run's
+    datasets may keep finished samples in),
     ``program_card`` (one per run,
     after the first compile: the train step's ProgramCard fields —
     flops, bytes_accessed, argument/output/temp/peak bytes,
@@ -26,7 +28,9 @@ consumer can rely on:
     (``np.load`` alone, inside fetch), ``loader_collate_s``,
     ``loader_h2d_s`` (the worker's ``device_put``: 0 without a mesh,
     where the jitted call moves the host arrays inside ``dispatch_s``),
-    ``loader_blocked_s``, and ``frames_real`` / ``frames_padded``; the
+    ``loader_blocked_s``, ``loader_cache_hits`` /
+    ``loader_cache_misses`` (samples served from host memory / built
+    from their files), and ``frames_real`` / ``frames_padded``; the
     training stream's loader alone, not a validation pass's), ``profile_start`` / ``profile_stop`` (``dir``,
     step, ``duration_s``: what the profiler's own start and stop held
     the loop for), ``val`` (step + per-loss fields),

@@ -270,6 +270,8 @@ _WINDOW_HISTOGRAMS = {
 }
 _WINDOW_COUNTERS = {
     "loader_read_s": "loader_read_seconds_total",
+    "loader_cache_hits": "loader_cache_hits_total",
+    "loader_cache_misses": "loader_cache_misses_total",
     "frames_real": "train_frames_real_total",
     "frames_padded": "train_frames_padded_total",
 }
@@ -335,15 +337,18 @@ def run_training(
     ``fault_fire``/``preempt_flush``/``quarantine``; schema in
     obs/events.py) to a rotating ``events.jsonl`` under
     ``train.path.log_path`` (``train.obs.*`` knobs); a ``train_step``
-    event carries its window's share of every span above. Set-up is
+    event carries its window's share of every span above and how many
+    of its samples the loader served from host memory and from files
+    (``loader_cache_hits`` / ``loader_cache_misses``). Set-up is
     four spans under one trace id in the process's span ring
     (``setup_model_init``, ``setup_restore``, ``setup_build_steps``,
     ``setup_datasets``), joined there by each batch shape's first
     ``train_dispatch`` (compile or cache load) and the program card's
     build. A ``train_start`` event records the build identity (git SHA,
-    jax versions, backend, device count) and the set-up spans'
-    durations; after the first step compiles, a one-time
-    ``program_card`` event records XLA's own cost/memory accounting of
+    jax versions, backend, device count), the set-up spans' durations
+    and the bytes of host memory the datasets may keep samples in
+    (``loader_cache_budget_bytes``, data/dataset.CacheBudget); after
+    the first step compiles, a one-time ``program_card`` event records XLA's own cost/memory accounting of
     the step program (obs/cost.py; gated by ``train.obs.program_card``),
     which also backs the ``device_memory_watermark_bytes`` gauge at log
     boundaries.
@@ -353,6 +358,7 @@ def run_training(
 
     from speakingstyle_tpu.data import (
         BucketedBatcher,
+        CacheBudget,
         DevicePrefetcher,
         SpeechDataset,
     )
@@ -534,14 +540,19 @@ def run_training(
         return s
 
     with setup_span("setup_datasets"):
+        # one budget of host memory for the samples both datasets keep
+        # after their first read; a rollback's new stream reads the same
+        # train_ds, so what it holds survives
+        sample_cache = CacheBudget()
         train_ds = SpeechDataset(
             "train.txt", cfg, sort=True, drop_last=True,
             retries=res.loader_retries, backoff=res.loader_backoff,
-            fault_plan=plan,
+            fault_plan=plan, cache=sample_cache,
         )
         quarantine = resilience.Quarantine(budget=res.bad_sample_budget)
         prefetch = make_stream(0)
-        val_ds = SpeechDataset("val.txt", cfg, sort=False, drop_last=False)
+        val_ds = SpeechDataset("val.txt", cfg, sort=False, drop_last=False,
+                               cache=sample_cache)
         # the validation stream's loader spans observe into a registry of
         # their own: a train_step event's window fields are deltas of the
         # run's registry and count the training loader alone
@@ -589,6 +600,7 @@ def run_training(
             checkpoint_step=ckpt.last_restored_step,
             weights_digest=ckpt.last_weights_digest,
             setup_s=setup_s,
+            loader_cache_budget_bytes=sample_cache.limit,
             **obs.build_info(),
         )
     if synth_callback == "default":

@@ -253,10 +253,11 @@ def test_batcher_quarantines_corrupt_sample(synthetic_preprocessed):
     total = sum(b.n_real for b in batcher.epoch(shuffle=False))
     assert total == 9  # 10 train samples, 1 skipped
     assert len(q) == 1 and "utt003" in q
-    # a second epoch skips the known-bad sample without re-loading it
+    # a second epoch skips the known-bad sample without re-loading it,
+    # and the nine good ones are held in memory: it loads nothing at all
     loads_before = ds._feature_loads
     assert sum(b.n_real for b in batcher.epoch(shuffle=False)) == 9
-    assert ds._feature_loads == loads_before + 9 * 4
+    assert ds._feature_loads == loads_before
     # zero budget: the first bad sample fails the run
     b0 = BucketedBatcher(
         ds, max_src=256, max_mel=256, quarantine=Quarantine(budget=0)
