@@ -14,7 +14,6 @@ import time
 T0 = time.time()  # process start, as near as Python lets us see it
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-BENCH = os.path.join(ROOT, "benchmark")
 
 
 def log(*parts):
@@ -41,6 +40,17 @@ def cell_files(workload: str):
     entry = {c["name"]: c for c in man["configs"]}[cell["config"]]
     traffic = load_json(f"benchmark/traffic/{cell['traffic']}.json")
     return cell, entry, load_json(entry["file"]), traffic
+
+
+def load_module(rel: str, name: str):
+    """A file of the benchmark as a module of its own: a configuration's
+    bindings, a metric's reader. Found by path, since names carry dots."""
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location(name, os.path.join(ROOT, rel))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def sized(block: dict, toy: bool) -> dict:
@@ -86,18 +96,13 @@ def fs_type(path: str) -> str:
     return kind
 
 
-PROGRAM_MODEL_KEYS = (
-    "transformer", "reference_encoder", "variance_predictor",
-    "variance_embedding", "multi_speaker", "use_reference_encoder",
-    "max_seq_len", "compute_dtype", "vocoder",
-)
-
-
 def write_program_configs(cfg: dict, work: str, corpus: str, lexicon: str,
                           step: dict = None) -> dict:
     """The program's three YAML files: the preset the configuration names,
     with paths pointed into ``work``, the model block as the configuration's
-    JSON states it, and the step block the cell runs with."""
+    JSON states it (the keys of ``model`` that its ``program_model_keys``
+    lists are the program's; the others are the reference's alone), and the
+    step block the cell runs with."""
     import yaml
 
     preset = os.path.join(ROOT, "speakingstyle_tpu", "configs", "presets",
@@ -110,7 +115,7 @@ def write_program_configs(cfg: dict, work: str, corpus: str, lexicon: str,
     pre, trn = load("preprocess.yaml"), load("train.yaml")
     pre["path"]["preprocessed_path"] = corpus
     pre["path"]["lexicon_path"] = lexicon
-    model = {k: cfg["model"][k] for k in PROGRAM_MODEL_KEYS if k in cfg["model"]}
+    model = {k: cfg["model"][k] for k in cfg["program_model_keys"]}
     trn["path"] = {k: os.path.join(work, k.split("_")[0])
                    for k in ("ckpt_path", "log_path", "result_path")}
     trn["seed"] = int(cfg.get("program_seed", 1234))

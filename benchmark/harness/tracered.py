@@ -1,13 +1,19 @@
 """Reduction from a profiler trace to numbers. Works on a compact form
 {"devices": [{line: [[name, start_ns, dur_ns], ...]}], "host": {line: [...]}}
 so a small recorded trace can check it; ``compact`` makes that form from the
-``.xplane.pb`` the profiler wrote."""
+``.xplane.pb`` the profiler wrote. A device event may carry a fourth element,
+{"tf_op": ..., "hlo_op": ...}: the operation's name in the program (its
+``jax.named_scope`` / module path down to the primitive) and the HLO
+instruction's own name, as the trace's event metadata has them. A recorded
+trace without it reads as before."""
 
 import glob
 import os
 import re
 
 DEVICE_LINE = "XLA Ops"
+# the stats of an event's metadata that say where in the program it comes from
+OP_STATS = ("tf_op", "hlo_op")
 
 
 def compact(trace_dir: str) -> dict:
@@ -17,7 +23,9 @@ def compact(trace_dir: str) -> dict:
                              recursive=True), key=os.path.getmtime)
     if not files:
         raise FileNotFoundError(f"no .xplane.pb under {trace_dir}")
-    data = ProfileData.from_file(files[-1])
+    with open(files[-1], "rb") as f:
+        raw = f.read()
+    data, named = ProfileData.from_serialized_xspace(raw), op_names(raw)
     out = {"devices": [], "host": {}}
     for plane in data.planes:
         name = plane.name
@@ -25,9 +33,11 @@ def compact(trace_dir: str) -> dict:
         is_host = name.startswith("/host:CPU")
         if not (is_device or is_host):
             continue
+        ops = named.get(name, {}) if is_device else {}
         lines = {}
         for line in plane.lines:
             events = [[e.name, int(e.start_ns), int(e.duration_ns)]
+                      + ([ops[e.name]] if e.name in ops else [])
                       for e in line.events]
             if events:
                 lines.setdefault(line.name, []).extend(events)
@@ -36,6 +46,93 @@ def compact(trace_dir: str) -> dict:
         elif is_host:
             for k, v in lines.items():
                 out["host"].setdefault(k, []).extend(v)
+    return out
+
+
+# -- the .xplane.pb itself ---------------------------------------------------
+# ``ProfileData`` gives an event its name and times and the stats of the event
+# alone; what the event's *metadata* carries (tf_op, hlo_op) it leaves out. So
+# the file's wire format is read here, as far as that needs: XSpace.planes = 1;
+# XPlane.name = 2, .event_metadata = 4, .stat_metadata = 5 (maps: key = 1,
+# value = 2); XEventMetadata.name = 2, .stats = 5; XStatMetadata.name = 2;
+# XStat.metadata_id = 1, .str_value = 5, .ref_value = 7 (a stat_metadata id
+# whose name is the string). tsl/profiler/protobuf/xplane.proto.
+
+def _fields(buf):
+    """(field number, wire type, value) of one message: an int for a varint
+    or a fixed-width field, the bytes of a length-delimited one."""
+    i, n = 0, len(buf)
+    while i < n:
+        key, i = _varint(buf, i)
+        kind = key & 7
+        if kind == 0:
+            value, i = _varint(buf, i)
+        elif kind == 2:
+            size, i = _varint(buf, i)
+            value, i = buf[i:i + size], i + size
+        elif kind in (1, 5):
+            size = 8 if kind == 1 else 4
+            value, i = int.from_bytes(buf[i:i + size], "little"), i + size
+        else:
+            raise ValueError(f"wire type {kind} at byte {i}")
+        yield key >> 3, kind, value
+
+
+def _varint(buf, i):
+    value = shift = 0
+    while True:
+        b = buf[i]
+        i += 1
+        value |= (b & 0x7F) << shift
+        if b < 0x80:
+            return value, i
+        shift += 7
+
+
+def _map_entry(buf):
+    key = value = None
+    for no, _, v in _fields(buf):
+        if no == 1:
+            key = v
+        elif no == 2:
+            value = v
+    return key, value
+
+
+def op_names(xspace: bytes) -> dict:
+    """{plane name: {event name: {stat: string}}} for the ``OP_STATS`` that
+    each event's metadata carries; an event without any is left out."""
+    out = {}
+    for no, _, plane in _fields(memoryview(xspace)):
+        if no != 1:
+            continue
+        name, events, stat_names = "", [], {}
+        for pno, _, v in _fields(plane):
+            if pno == 2:
+                name = bytes(v).decode()
+            elif pno == 4:
+                events.append(_map_entry(v)[1])
+            elif pno == 5:
+                key, meta = _map_entry(v)
+                stat_names[key] = next(
+                    (bytes(x).decode() for n, _, x in _fields(meta) if n == 2), "")
+        wanted = {i for i, n in stat_names.items() if n in OP_STATS}
+        named = {}
+        for meta in events:
+            ev_name, stats = "", {}
+            for mno, _, v in _fields(meta or b""):
+                if mno == 2:
+                    ev_name = bytes(v).decode(errors="replace")
+                elif mno == 5:
+                    stat = {n: x for n, _, x in _fields(v)}
+                    if stat.get(1) in wanted:
+                        text = (bytes(stat[5]).decode(errors="replace")
+                                if 5 in stat else stat_names.get(stat.get(7), ""))
+                        stats[stat_names[stat[1]]] = text
+            if stats:
+                named[ev_name] = stats
+        if named:
+            out[name] = named
     return out
 
 
@@ -73,8 +170,8 @@ def busy_and_window(trace: dict, window_ns=None):
 def top_ops(trace: dict, n=10, width=64):
     totals = {}
     for ev in device_lines(trace):
-        for name, _, dur in ev:
-            totals[name] = totals.get(name, 0) + dur
+        for e in ev:
+            totals[e[0]] = totals.get(e[0], 0) + e[2]
     k = max(len(device_lines(trace)), 1)
     top = sorted(totals.items(), key=lambda kv: -kv[1])[:n]
     return [[short_name(name, width), dur / k / 1e9] for name, dur in top]
@@ -127,17 +224,29 @@ def idle_gaps(trace: dict, n=10, min_gap_ns=20_000):
 _SHAPE = re.compile(r"= \(?\w+\[(\d+),(\d+),(\d+),(\d+)\]")
 
 
+def is_fused_attention(event) -> bool:
+    """Whether a device event is a call of the fused-attention kernel: by its
+    name in the program where the trace kept it (``tf_op``: the module path
+    down to the primitive, ``.../pallas_call``), else, on a trace recorded
+    without (the fixture of PR 24), by its form: a custom call that is not the
+    random generator's."""
+    name = event[0]
+    if len(event) > 3 and event[3].get("tf_op"):
+        return "pallas_call" in event[3]["tf_op"].rsplit("/", 1)[-1]
+    return "custom-call" in name and "rng" not in name.split("=")[0]
+
+
 def kernel_calls(trace: dict):
-    """The fused-attention kernel's events on the first device: a custom call
-    whose result is [B, H, D, T] (one array forward, a tuple of three
-    backward). Returns [(b, h, d, t, backward, seconds)]."""
+    """The fused-attention kernel's events on the first device, found by
+    name; the result's shape says only what was computed: [B, H, D, T], one
+    array forward, a tuple of three backward. Returns
+    [(b, h, d, t, backward, seconds)]."""
     out = []
     lines = device_lines(trace)
-    for name, _, dur in (lines[0] if lines else []):
-        if "custom-call" not in name or "rng" in name.split("=")[0]:
-            continue
+    for event in (lines[0] if lines else []):
+        name, dur = event[0], event[2]
         m = _SHAPE.search(name)
-        if not m:
+        if not m or not is_fused_attention(event):
             continue
         b, h, d, t = (int(x) for x in m.groups())
         backward = name.split("custom-call")[0].count("[") >= 3

@@ -8,6 +8,13 @@ and closes on the last ``train_step`` event inside ``--seconds``. The harness
 records around the loop's compiled step (the same object the window drives):
 the first two steps' batches, losses and state for the comparison with the
 plain reference, and nothing after them but a call count.
+
+What is one configuration's own comes from the module its file names
+(``reference``; PERF.md section 4): the seeded weights, the corpus writer,
+the operations of a cycle and the comparison that decides ``correct``. The
+working directory, the program's YAML, the loop, its events, the window, the
+trace, the readers' ``ctx`` and the result line are here, for every
+configuration the program trains.
 """
 
 import gc
@@ -19,7 +26,7 @@ import time
 
 import numpy as np
 
-from . import common, flops, peaks, tracered, trafficgen
+from . import common, contract, peaks, tracered
 from .common import log
 
 # the reference follows the first two steps (two for three: its compile is
@@ -42,8 +49,9 @@ class StepRecorder:
     def __init__(self, inner, open_at, on_open):
         self.inner, self.open_at, self.on_open = inner, open_at, on_open
         self.calls = 0
-        self.batches, self.losses, self.shapes = [], [], []
-        self.first_mu = self.params_after = None
+        self.batches, self.losses = [], []
+        self.first_mu = None
+        self.params_after = []  # the parameters after each captured step
 
     def __getattr__(self, name):
         return getattr(self.inner, name)
@@ -54,9 +62,6 @@ class StepRecorder:
         k = self.calls
         if k < CAPTURE_STEPS:
             self.batches.append({n: np.asarray(v) for n, v in arrays.items()})
-        if k < 2 * self.open_at:
-            self.shapes.append(tuple(arrays["mels"].shape[:2])
-                               + (arrays["texts"].shape[1],))
         if k == self.open_at:
             self.on_open()
         new_state, losses = self.inner(state, arrays, rng)
@@ -64,8 +69,7 @@ class StepRecorder:
             self.losses.append(float(jax.device_get(losses["total_loss"])))
             if k == 0:
                 self.first_mu = jax.device_get(find_mu(new_state.opt_state))
-            if k == CAPTURE_STEPS - 1:
-                self.params_after = jax.device_get(new_state.params)
+            self.params_after.append(jax.device_get(new_state.params))
         self.calls += 1
         return new_state, losses
 
@@ -106,76 +110,9 @@ def save_seed_checkpoint(pcfg, params, stats):
         ckpt.close()
 
 
-def norm_gaps(prog: dict, ref: dict, skip=()):
-    """Worst leaf of |‖prog‖ − ‖ref‖| over max(‖ref‖ of that leaf, ‖ref‖ of
-    the median leaf). Returns (gap, leaf)."""
-    names = [n for n in sorted(ref) if n not in skip]
-    ref_norms = {n: float(np.linalg.norm(np.asarray(ref[n], np.float64)))
-                 for n in names}
-    median = float(np.median(list(ref_norms.values())))
-    worst, leaf = 0.0, None
-    for n in names:
-        p = float(np.linalg.norm(np.asarray(prog[n], np.float64)))
-        gap = abs(p - ref_norms[n]) / max(ref_norms[n], median, 1e-30)
-        if not gap <= worst:  # NaN counts as worst
-            worst, leaf = gap, n
-    return worst, leaf
-
-
 def load_reference(cfg: dict):
     """The configuration's plain reference: the module its file names."""
-    import importlib.util
-
-    path = os.path.join(common.ROOT, cfg["reference"])
-    spec = importlib.util.spec_from_file_location(
-        "bench_reference_" + cfg["name"], path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-# gradients closer than a hundredth of the reference's norm differ by nothing
-DIFF_FLOOR = 1e-4
-
-
-def compare_training(rec, ref_out, params0, b1: float, other_grad):
-    """The numbers compared, from the recorder's captures and the reference's
-    (losses, first clipped gradient, parameters after the last step).
-    ``other_grad`` is the reference's first gradient again under other
-    dropout masks: what two sound draws differ by."""
-    from ..reference import fs2
-
-    ref_losses, ref_grad, ref_params = ref_out
-    readings, notes = {}, {}
-    for i, (lp, lr) in enumerate(zip(rec.losses, ref_losses)):
-        readings[f"loss_gap_step{i + 1}"] = abs(lp - lr) / abs(lr)
-    ref_grad = {k: np.asarray(v) for k, v in fs2.flatten(ref_grad).items()}
-    prog_grad = {k: np.asarray(v) / (1.0 - b1)
-                 for k, v in fs2.flatten(rec.first_mu).items()}
-    readings["grad_norm_gap"], notes["grad_leaf"] = norm_gaps(prog_grad, ref_grad)
-    # the norm of the difference over all leaves together, against the same
-    # between two draws of the reference: which rows went in shows here, where
-    # a gap of norms is blind to it (a mean over half the rows has the norms)
-    sq = lambda t: sum(float(np.sum(np.square(np.asarray(v, np.float64))))
-                       for v in t.values())
-    other = {k: np.asarray(v) for k, v in fs2.flatten(other_grad).items()}
-    mine = sq({k: prog_grad[k] - ref_grad[k] for k in ref_grad}) / sq(ref_grad)
-    draws = sq({k: other[k] - ref_grad[k] for k in ref_grad}) / sq(ref_grad)
-    readings["grad_diff"], notes["grad_diff_draws"] = mine ** 0.5, draws ** 0.5
-    readings["grad_diff_excess"] = abs(mine - draws) / max(draws, DIFF_FLOOR)
-    # leaves whose gradient is nought to rounding in the reference move under
-    # Adam by round-off alone: out of the change, by a rule on the gradient
-    norms = {k: float(np.linalg.norm(v)) for k, v in ref_grad.items()}
-    floor = 1e-3 * float(np.median(list(norms.values())))
-    skip = sorted(k for k, v in norms.items() if v < floor)
-    p0 = fs2.flatten(params0)
-    change = lambda tree: {k: np.asarray(v, np.float64) - p0[k]
-                           for k, v in fs2.flatten(tree).items()}
-    readings["change_norm_gap"], notes["change_leaf"] = norm_gaps(
-        change(rec.params_after), change(ref_params), skip)
-    notes["left_out"] = skip
-    notes["losses"] = {"program": rec.losses, "reference": list(ref_losses)}
-    return readings, notes
+    return common.load_module(cfg["reference"], "bench_reference_" + cfg["name"])
 
 
 def run(workload, seed, seconds, trace, toy=False, fault_hook=None,
@@ -195,16 +132,11 @@ def run(workload, seed, seconds, trace, toy=False, fault_hook=None,
     import jax
 
     enable_cache()
-    fs2 = load_reference(cfg)
+    ref = load_reference(cfg)
 
     work = common.workdir()
     corpus = os.path.join(work, "corpus")
-    deck_spec = {**traffic["deck"], "batch_size": traffic["batch_size"],
-                 "pitch_range": cfg["model"]["pitch_range"],
-                 "energy_range": cfg["model"]["energy_range"]}
-    info = trafficgen.write_corpus(corpus, deck_spec, seed,
-                                   cfg["model"]["n_mel_channels"])
-    read_ms = corpus_read_ms(corpus, traffic["batch_size"])
+    info = ref.write_corpus(corpus, cfg, traffic, seed)
     spans["corpus"] = time.time()
     log_step = traffic["log_step"]
     warm = traffic["warmup_cycles"] * log_step
@@ -218,9 +150,9 @@ def run(workload, seed, seconds, trace, toy=False, fault_hook=None,
 
     pcfg = load_config(preprocess=prog["paths"]["preprocess"],
                        model=prog["paths"]["model"], train=prog["paths"]["train"])
-    hp = fs2.hyper(cfg["model"])
-    params0 = fs2.init_params(hp, seed)
-    stats0 = fs2.init_batch_stats(hp)
+    hp = ref.hyper(cfg["model"])
+    params0 = ref.init_params(hp, seed)
+    stats0 = ref.init_batch_stats(hp)
     save_seed_checkpoint(pcfg, params0, stats0)
     spans["weights"] = time.time()
 
@@ -273,55 +205,19 @@ def run(workload, seed, seconds, trace, toy=False, fault_hook=None,
     with open(os.path.join(pcfg.train.path.log_path, "events.jsonl")) as f:
         events = [json.loads(line) for line in f if line.strip()]
     steps = [e for e in events if e.get("event") == "train_step"]
-    opt = common.optimizer_for_reference(prog["train"])
-    block = cfg.get("reference_block_rows", 8)
-    t_ref = time.time()
-    ticks = []
-    ref_out = fs2.train_steps(hp, opt, params0, stats0, rec.batches, seed,
-                              block_rows=block,
-                              clock=lambda name: ticks.append((name, time.time())))
-    log("reference phases (s): " + ", ".join(
-        f"{b[0]}={b[1] - a[1]:.1f}" for a, b in zip(ticks, ticks[1:])
-        if b[0] != "start"))
-    other_grad = fs2.train_steps(hp, opt, params0, stats0, rec.batches[:1],
-                                 seed + 1, block_rows=block)[1]
-    readings, notes = compare_training(rec, ref_out, params0, opt["betas"][0],
-                                       other_grad)
-    log(f"reference: {time.time() - t_ref:.1f} s; notes "
-        f"{json.dumps({k: notes[k] for k in ('grad_leaf', 'change_leaf', 'grad_diff_draws')})}; "
-        f"losses {notes['losses']}; readings {json.dumps(readings)}")
-    for name in (control or "").split(",") if control else ():
-        # the reference put in the program's place, with masks of its own
-        # as the program has
-        if name == "half_batch":  # the fault: half the rows left out
-            rows = [{k: v[: len(v) // 2] for k, v in b.items()}
-                    for b in rec.batches]
-            ctl = fs2.train_steps(hp, opt, params0, stats0, rows, seed + 1,
-                                  block_rows=block)
-        elif name == "other_masks":
-            ctl = fs2.train_steps(hp, opt, params0, stats0, rec.batches,
-                                  seed + 1, block_rows=block)
-        else:
-            ctl = fs2.train_steps(hp, opt, params0, stats0, rec.batches, seed,
-                                  block_rows=block, quant=quantizer(name))
-        fake = type("R", (), {})()
-        fake.losses = ctl[0]
-        fake.first_mu = jax.tree_util.tree_map(
-            lambda g: np.asarray(g) * (1.0 - opt["betas"][0]), ctl[1])
-        fake.params_after = ctl[2]
-        got, where = compare_training(fake, ref_out, params0, opt["betas"][0],
-                                      other_grad)
-        limits = common.load_json(f"benchmark/limits/{workload}.json")["limits"]
-        mine = {k: v for k, v in limits.items() if k in got}
-        notes.setdefault("control", {})[name] = got
-        log(f"control {name}: correct {common.judge(got, mine)[0]} "
-            f"{json.dumps(got)} leaves "
-            f"{json.dumps([where['grad_leaf'], where['change_leaf']])}")
+    limits = common.load_json(f"benchmark/limits/{workload}.json")["limits"]
+    readings, notes = ref.compare(
+        cfg, hp, common.optimizer_for_reference(prog["train"]), params0,
+        stats0, rec, seed, controls=control.split(",") if control else (),
+        limits=limits)
     if limits_only:
         print(json.dumps({"seed": seed, "readings": readings,
                           "control": notes.get("control"),
-                          "leaves": [notes["grad_leaf"], notes["change_leaf"]],
-                          "losses": notes["losses"]}), flush=True)
+                          "worst": notes.get("worst"),
+                          "losses": notes.get("losses"),
+                          "leaf_norms": notes.get("leaf_norms"),
+                          "control_leaf_norms": notes.get("control_leaf_norms")}),
+              flush=True)
         return (readings, notes) if toy else 0
 
     opened = [e for e in steps if e["step"] == warm]
@@ -342,7 +238,6 @@ def run(workload, seed, seconds, trace, toy=False, fault_hook=None,
     readings["frames_per_cycle_gap"] = cycle_gap
     readings["window_compiles"] = (marks["compiles_close"]["compiles"]
                                    - marks["compiles_open"]["compiles"])
-    limits = common.load_json(f"benchmark/limits/{workload}.json")["limits"]
     correct, compared = common.judge(readings, limits)
 
     cycles = [b["ts"] - a["ts"] for a, b in zip([opened[0]] + inside, inside)]
@@ -362,24 +257,20 @@ def run(workload, seed, seconds, trace, toy=False, fault_hook=None,
     }
     breakdown = None
     if trace:
-        lengths = [(n, int(d.sum())) for n, d in trafficgen.train_deck(deck_spec)]
-        cap = cfg["model"]["max_seq_len"]
-        lengths = [(min(s, cap), min(t, cap)) for s, t in lengths]
         tr = tracered.compact(trace_dir)
         busy_s, traced_s = tracered.busy_and_window(tr)
         device["busy_s"], device["window_s"] = busy_s, traced_s
         calm = calm_cycles(inside, cycles, warm + t_steps[0],
                            warm + t_steps[1], log_step)
+        # what a training driver owes the readers: PERF.md section 3
         ctx = {
-            "cell": cell, "device": device, "peaks": peaks.peaks_or_none(device["kind"], toy),
+            "device": device, "peaks": peaks.peaks_or_none(device["kind"], toy),
             "events": [e for e, _ in calm], "cycles_s": [c for _, c in calm],
             "window_s": sum(c for _, c in calm),
             "trace": tr, "compiles_open": marks["compiles_open"],
             "compiles_close": marks["compiles_close"],
-            "shapes": rec.shapes[warm:2 * warm] or rec.shapes,
-            "frames_per_cycle": info["frames_per_cycle"],
-            "flops_per_cycle": flops.train_step_flops(cfg["model"], lengths),
-            "log_step": log_step, "corpus_read_ms": read_ms,
+            "flops_per_cycle": ref.cycle_flops(cfg, traffic),
+            "log_step": log_step,
         }
         metrics = read_per_layer(workload, ctx)
         breakdown = tracered.breakdown(tr)
@@ -398,55 +289,22 @@ def calm_cycles(inside, cycles, first, last, log_step):
     return calm or list(zip(inside, cycles))
 
 
-def corpus_read_ms(corpus: str, batch_size: int) -> float:
-    """Milliseconds to ``np.load`` one batch's feature files (four a row)
-    from the working directory, just written: what the file system charges
-    the loader, read in set-up so that a slow one is on the record."""
-    with open(os.path.join(corpus, "train.txt")) as f:
-        names = [line.split("|")[0] for line in f][:batch_size]
-    t0 = time.perf_counter()
-    for n in names:
-        for kind in ("mel", "pitch", "energy", "duration"):
-            np.load(os.path.join(corpus, kind, f"S-{kind}-{n}.npy"))
-    ms = 1e3 * (time.perf_counter() - t0)
-    log(f"corpus_read_ms {ms:.1f} ({len(names)} rows x 4 files)")
-    return ms
-
-
 def read_per_layer(workload: str, ctx: dict) -> dict:
-    """Every per-layer metric that lists this cell (or lists none), each from
-    its own reader ``benchmark/metrics/<name>.py``; a reader that finds
-    nothing to read returns None and the metric is left out."""
-    import importlib.util
-
+    """Every per-layer metric that lists this cell, or lists none and moves
+    what the cell reports, each from its own reader
+    ``benchmark/metrics/<name>.py``; a reader that finds nothing to read
+    returns None and the metric is left out."""
+    man = common.manifest()
+    units = {m["name"]: m["unit"] for m in man["per_layer"]}
     out = {}
-    for m in common.manifest()["per_layer"]:
-        if "workloads" in m and workload not in m["workloads"]:
-            continue
-        path = os.path.join(common.BENCH, "metrics", m["name"] + ".py")
-        spec = importlib.util.spec_from_file_location(
-            "bench_metric_" + m["name"].replace(".", "_"), path)
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
+    for name in contract.readers_of(man, workload):
+        mod = common.load_module(f"benchmark/metrics/{name}.py",
+                                 "bench_metric_" + name.replace(".", "_"))
         try:
             value = mod.read(ctx)
-        except (KeyError, LookupError, ZeroDivisionError, TypeError) as e:
-            log(f"metric {m['name']}: nothing to read ({type(e).__name__}: {e})")
+        except (LookupError, ZeroDivisionError, TypeError, ValueError) as e:
+            log(f"metric {name}: nothing to read ({type(e).__name__}: {e})")
             value = None
         if value is not None:
-            out[m["name"]] = {"value": float(value), "unit": m["unit"]}
+            out[name] = {"value": float(value), "unit": units[name]}
     return out
-
-
-def quantizer(name: str):
-    """The control's rounding: both operands of every product, to ``name``
-    and back."""
-    import jax.numpy as jnp
-
-    dtype = jnp.dtype(name)
-    if jnp.issubdtype(dtype, jnp.integer):
-        def q(x):
-            scale = jnp.maximum(jnp.max(jnp.abs(x)), 1e-30) / 127.0
-            return jnp.round(x / scale).astype(dtype).astype(jnp.float32) * scale
-        return q
-    return lambda x: x.astype(dtype).astype(jnp.float32)

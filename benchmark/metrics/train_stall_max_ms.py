@@ -4,4 +4,6 @@ import statistics
 
 def read(ctx):
     c = ctx["cycles_s"]
+    if not c:
+        return None
     return 1e3 * (max(c) - statistics.median(c))

@@ -573,14 +573,14 @@ def adam_step(opt: dict, params, grads, state):
 def train_steps(hp, opt, params, stats, batches, seed, block_rows=8, quant=None,
                 clock=None):
     """Follow the first ``len(batches)`` optimizer steps. Returns per-step
-    losses, the first clipped gradient and the parameters after the last."""
+    losses, the first clipped gradient and the parameters after each step."""
     params = jax.tree_util.tree_map(jnp.asarray, params)
     stats = jax.tree_util.tree_map(jnp.asarray, stats)
     state = adam_init(params)
     key = jax.random.PRNGKey(int(seed) % (2 ** 31))
     pad_to = (max(b["texts"].shape[1] for b in batches),
               max(b["mels"].shape[1] for b in batches))
-    losses, first_grad = [], None
+    losses, first_grad, after = [], None, []
     for i, batch in enumerate(batches):
         loss, grads, stats = loss_and_grads(
             hp, params, stats, batch, jax.random.fold_in(key, i),
@@ -589,4 +589,5 @@ def train_steps(hp, opt, params, stats, batches, seed, block_rows=8, quant=None,
         if first_grad is None:
             first_grad = clipped
         losses.append(loss)
-    return losses, first_grad, params
+        after.append(params)
+    return losses, first_grad, after

@@ -14,17 +14,10 @@ import argparse
 import os
 import sys
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, ROOT)
-
-# The persistent compile cache sits inside the checkout at a fixed path and
-# is not held to a size, for this process and for every child: the program
-# takes the directory the environment names (obs/jaxmon), so only the first
-# run of a cell in a checkout compiles. Set before JAX is imported anywhere.
-os.environ["JAX_COMPILATION_CACHE_DIR"] = os.path.join(ROOT, ".jax_cache")
-os.environ["JAX_COMPILATION_CACHE_MAX_SIZE"] = "-1"
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from benchmark.harness import common  # noqa: E402  (starts the set-up clock)
+
 
 def main(argv=None, **options):
     """``options`` go to the driver as they are: the builder's control and
@@ -36,7 +29,14 @@ def main(argv=None, **options):
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
     ap.add_argument("--toy", type=int, choices=(0, 1), default=0)
     args = ap.parse_args(argv)
-    if not os.path.isdir(os.path.join(ROOT, "speakingstyle_tpu")):
+    # The persistent compile cache sits inside the checkout at a fixed path
+    # and is not held to a size, for this process and for every child: the
+    # program takes the directory the environment names (obs/jaxmon), so only
+    # the first run of a cell in a checkout compiles. Set before JAX is
+    # imported anywhere.
+    os.environ["JAX_COMPILATION_CACHE_DIR"] = os.path.join(common.ROOT, ".jax_cache")
+    os.environ["JAX_COMPILATION_CACHE_MAX_SIZE"] = "-1"
+    if not os.path.isdir(os.path.join(common.ROOT, "speakingstyle_tpu")):
         common.log("the program (speakingstyle_tpu/) is not in this directory: "
                    "nothing to measure")
         return 3

@@ -21,7 +21,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)
 
 def child(argv, options):
     sys.path.insert(0, ROOT)
-    from benchmark import run  # sets the compile cache's place on import
+    from benchmark import run
 
     return run.main(argv, **options)
 

@@ -2,11 +2,10 @@
 on events that carry the program's two counts, None (and no error) on the
 events of a program without them, which is the parent's side of a check.
 
-The metric's ``per_layer`` entry is not in ``BENCHMARK.json`` yet: a test of
-PR 26 (``test_spans.py``) holds the manifest to its twenty-four metrics, and
-no file of the benchmark may be edited by the PR that brings the cache. The
-entry a ``benchmark`` PR appends is ``ENTRY`` below, held here to the
-manifest's rules and run through the harness's own ``read_per_layer``."""
+The metric's ``per_layer`` entry is ``ENTRY`` below: it lists no cell, so
+every cell that reports ``train_frames_per_s`` reports it (the program's loop
+writes the two counts whatever it trains). Held here to the manifest's rules
+and run through the harness's own ``read_per_layer``."""
 
 import importlib.util
 import os
@@ -23,7 +22,7 @@ TRAIN = "train_ljspeech_b200"
 NAME = "loader_cache_hit_pct"
 ENTRY = {"name": NAME, "unit": "%", "better": "higher",
          "source": "program_counter", "layer": "train loop",
-         "moves": "train_frames_per_s", "workloads": [TRAIN]}
+         "moves": "train_frames_per_s"}
 
 
 def reader():
@@ -63,26 +62,20 @@ def test_reader_gives_a_share_or_none(evs, expected):
     assert got == (None if expected is None else pytest.approx(expected))
 
 
-def test_entry_is_one_the_manifest_takes_and_is_not_in_it_yet():
+def test_entry_is_the_manifests_and_lists_no_cell():
     man = common.manifest()
-    assert NAME not in [m["name"] for m in man["per_layer"]]
-    assert set(ENTRY) == {"name", "unit", "better", "source", "layer", "moves",
-                          "workloads"}
-    assert ENTRY["layer"] in {m["layer"] for m in man["per_layer"]}
-    moved = {m["name"]: m for m in man["end_to_end"]}[ENTRY["moves"]]
-    assert set(ENTRY["workloads"]) <= set(moved["workloads"])
+    assert [m for m in man["per_layer"] if m["name"] == NAME] == [ENTRY]
+    assert set(ENTRY) == {"name", "unit", "better", "source", "layer", "moves"}
+    assert ENTRY["moves"] in {m["name"] for m in man["end_to_end"]}
 
 
 @pytest.mark.parametrize("evs,expected", [
     (events((200.0, 0.0), (200.0, 0.0)), 100.0), (OLD_EVENTS, None)],
     ids=["change", "parent"])
-def test_harness_reports_it_once_the_entry_is_listed(monkeypatch, evs, expected):
-    """With the entry appended, ``read_per_layer`` finds the reader by its
-    name and reports the share; on the parent's events the line leaves the
-    metric out and nothing is raised."""
-    man = common.manifest()
-    man["per_layer"] = man["per_layer"] + [ENTRY]
-    monkeypatch.setattr(common, "manifest", lambda: man)
+def test_harness_reports_it_for_the_cell(evs, expected):
+    """``read_per_layer`` finds the reader by its name and reports the share;
+    on the events of a program without the cache the line leaves the metric
+    out and nothing is raised."""
     ctx = {"trace": {"devices": [], "host": {}}, "events": evs,
            "window_s": 4.0, "log_step": 4}
     out = train_cell.read_per_layer(TRAIN, ctx)

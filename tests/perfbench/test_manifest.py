@@ -1,6 +1,7 @@
 """The manifest against its contract, and the harness's data files against
 the manifest: names, units, `moves`, one file per configuration, traffic mix
-and per-layer metric."""
+and per-layer metric. Nothing here holds the manifest to a length, an order
+or a last entry: a later PR adds to it by files and entries alone."""
 
 import json
 import os
@@ -12,7 +13,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)
 import sys
 
 sys.path.insert(0, ROOT)
-from benchmark.harness import common  # noqa: E402
+from benchmark.harness import common, contract  # noqa: E402
 
 with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
     RAW = json.load(f)
@@ -27,7 +28,7 @@ E2E = {m["name"]: m for m in MAN["end_to_end"]}
 
 def reporting(metric):
     """The cells that report an end-to-end metric."""
-    return set(metric.get("workloads", CELLS))
+    return contract.reporting(MAN, metric)
 
 
 def test_top_level_keys_and_sizes():
@@ -105,8 +106,74 @@ def test_configuration_file_and_its_reference(config):
     assert os.path.dirname(ref) == os.path.dirname(config["file"])
     src = open(os.path.join(ROOT, ref)).read()
     assert "speakingstyle_tpu" not in src.split('"""', 2)[2]
-    forbidden = ("dim", "rank", "hidden", "filter_size", "head")
-    assert not any(any(w in k for w in forbidden) for k in config["reduced"])
+    assert not [k for k in config["reduced"] if contract.names_a_width(k)]
+    # what is the configuration's own in a cell comes from that module, and
+    # the file says which keys of its model block are the program's
+    for name in ("hyper", "init_params", "init_batch_stats", "write_corpus",
+                 "cycle_flops", "compare"):
+        assert re.search(rf"^(def {name}\(|{name} = )", src, re.M), name
+    assert set(body["program_model_keys"]) <= set(body["model"])
+
+
+@pytest.mark.parametrize("key", [
+    "hidden_size", "head_dim", "intermediate_size", "moe_intermediate_size",
+    "kv_lora_rank", "q_lora_rank", "conv_filter_size", "encoder_hidden",
+    "filter_size", "num_experts_per_tok", "d_model", "qk_rope_head_dim",
+    "v_head_dim", "expansion_factor", "ssm_state_size", "n_embd",
+    "postnet_embedding_dim", "moe_top_k", "head_size", "n_mel_channels"])
+def test_reduced_refuses_a_key_that_names_a_width(key):
+    assert contract.names_a_width(key)
+
+
+@pytest.mark.parametrize("key", [
+    "num_hidden_layers", "encoder_layer", "decoder_layer", "num_experts",
+    "n_routed_experts", "vocab_size", "num_attention_heads",
+    "num_key_value_heads", "conv_layer", "postnet_layers", "num_layers",
+    "first_k_dense_replace"])
+def test_reduced_admits_a_count_of_layers_experts_heads_or_rows(key):
+    assert not contract.names_a_width(key)
+
+
+def test_every_entry_has_its_reader_and_every_reader_its_entry():
+    assert contract.reader_problems(MAN, ROOT) == []
+
+
+@pytest.mark.parametrize("plant,said", [
+    (lambda d, man: open(os.path.join(d, "orphan_ms.py"), "w").write(
+        "def read(ctx):\n    return 1.0\n"), "orphan_ms.py: not in per_layer"),
+    (lambda d, man: man["per_layer"].append({"name": "ghost_ms"}),
+     "ghost_ms: no benchmark/metrics/ghost_ms.py"),
+    (lambda d, man: open(os.path.join(d, "step_ms.py"), "w").write(
+        "def measure(ctx):\n    return 1.0\n"), "step_ms.py: no read(ctx)"),
+    (lambda d, man: man["per_layer"].append(dict(man["per_layer"][0])),
+     "listed twice"),
+], ids=["orphan_file", "entry_without_file", "file_without_read", "twice"])
+def test_reader_contract_names_what_is_wrong(tmp_path, plant, said):
+    import copy
+    import shutil
+
+    folder = tmp_path / "benchmark" / "metrics"
+    shutil.copytree(os.path.join(ROOT, "benchmark", "metrics"), folder,
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    man = copy.deepcopy(MAN)
+    assert contract.reader_problems(man, str(tmp_path)) == []
+    plant(str(folder), man)
+    wrong = contract.reader_problems(man, str(tmp_path))
+    assert len(wrong) == 1 and said in wrong[0]
+
+
+def test_harness_names_no_model():
+    """The shared harness finds what is one configuration's own through the
+    configuration: no module of it imports a reference's equations or names a
+    model's keys."""
+    folder = os.path.join(ROOT, "benchmark", "harness")
+    for name in os.listdir(folder):
+        if name.endswith(".py"):
+            code = open(os.path.join(folder, name)).read()
+            assert not re.search(r"reference\W+(import\W+)?fs2", code), name
+    code = open(os.path.join(folder, "common.py")).read()
+    for key in common.load_json("benchmark/configs/ljspeech.json")["model"]:
+        assert f'"{key}"' not in code, key
 
 
 def test_reference_imports_nothing_of_the_program():

@@ -12,7 +12,7 @@ import pytest
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 sys.path.insert(0, ROOT)
 
-from benchmark.harness import common, spans, train_cell  # noqa: E402
+from benchmark.harness import common, contract, spans, train_cell, trafficgen  # noqa: E402
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 TRAIN = "train_ljspeech_b200"
@@ -57,15 +57,30 @@ def read_all(ctx, names=NEW_READERS):
     return {n: out[n]["value"] if n in out else None for n in names}
 
 
-def test_manifest_lists_the_twelve_new_readers_last():
+def test_manifest_lists_every_reader_and_every_reader_is_listed():
+    """Whatever the list's length and order: each ``per_layer`` entry has its
+    file under ``benchmark/metrics/`` and each file there its entry."""
+    assert contract.reader_problems(common.manifest(), common.ROOT) == []
     names = [m["name"] for m in common.manifest()["per_layer"]]
-    assert names[-len(NEW_READERS):] == [
-        "loader_read_ms", "loader_prepare_ms", "loader_blocked_pct",
-        "step_dispatch_ms", "step_sync_ms", "loader_padding_pct",
-        "setup_model_init_s", "setup_restore_s",
-        "setup_first_calls_s", "idle_in_data_wait_pct",
-        "idle_in_loader_fetch_pct", "idle_unattributed_pct"]
-    assert len(names) == 12 + 12
+    assert set(NEW_READERS) <= set(names)
+
+
+def deck_padding_share(traffic: dict, mel_bucket=128) -> float:
+    """The deck's own arithmetic: sorted by length, cut into batches, each
+    batch padded to the next multiple of the loader's mel bucket."""
+    spec = {**traffic["deck"], "batch_size": traffic["batch_size"]}
+    frames = [int(d.sum()) for _, d in trafficgen.train_deck(spec)]
+    rows = spec["batch_size"]
+    padded = sum(rows * -(-max(frames[i:i + rows]) // mel_bucket) * mel_bucket
+                 for i in range(0, len(frames), rows))
+    return 100.0 * (1.0 - sum(frames) / padded)
+
+
+def test_deck_arithmetic_gives_the_cells_padding():
+    """At the cell's own size: four batches of 200 at 896/768/640/512 frames
+    against the deck's 429,521 real ones (the ledger's 23.736)."""
+    t = common.load_json("benchmark/traffic/train_ljspeech_lengths.json")
+    assert deck_padding_share(t) == pytest.approx(100 * (1 - 429521 / 563200))
 
 
 def test_idle_intervals_and_overlap_by_hand(recorded):
@@ -163,10 +178,29 @@ def test_traced_toy_run_gives_every_span_reader_a_value(monkeypatch, capsys):
     assert [got[n] for n in TRACE_READERS] == [None] * 3
     assert not [ln for ln in cap.err.splitlines()
                 if ln.startswith("metric ") and any(n in ln for n in NEW_READERS)]
-    # the program's counters and the harness's shapes agree on the padding
+    # the program's counters against the deck's own arithmetic
+    toy = common.sized(common.load_json(
+        "benchmark/traffic/train_ljspeech_lengths.json"), True)
     assert got["loader_padding_pct"] == pytest.approx(
-        line["metrics"]["padding_pct"]["value"], abs=1e-6)
+        deck_padding_share(toy), abs=1e-6)
     # one batch shape at toy size: its first call is the ring's only one
     names = [s["name"] for s in spans.run_spans()]
     assert names.count("train_dispatch") == 1
     assert sum(n.startswith("setup_") for n in names) == 4
+
+
+
+@pytest.mark.parametrize(
+    "name", [m["name"] for m in common.manifest()["per_layer"]])
+def test_reader_reads_nothing_and_raises_nothing_without_its_key(
+    name, empty_ring, capsys
+):
+    """A driver that owes a reader a ``ctx`` key and does not supply it, or
+    supplies it empty: the metric is left out of the line, never an error
+    and never a nought."""
+    bare = {"trace": {"devices": [], "host": {}}, "events": [], "cycles_s": [],
+            "device": {}, "peaks": None, "window_s": 0.0, "log_step": 4,
+            "compiles_open": {}, "compiles_close": {}}
+    for ctx in ({}, bare):
+        assert name not in train_cell.read_per_layer(TRAIN, ctx)
+    capsys.readouterr()

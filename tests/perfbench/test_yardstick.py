@@ -44,6 +44,78 @@ def test_trace_reduction_breakdown_and_kernel(recorded):
     assert 0 < share <= 1.0
 
 
+@pytest.fixture(scope="module")
+def probe():
+    """A traced probe of the fused kernel on a TPU v5 lite (chip run of PR 28:
+    forward and backward of [8, 2, 128, 256] under ``named_scope
+    ("probe_scope")``, three times), as the profiler wrote it."""
+    return tracered.compact(os.path.join(HERE, "data", "probe_trace"))
+
+
+def test_compact_keeps_where_in_the_program_a_device_event_comes_from(probe):
+    ops = probe["devices"][0]["XLA Ops"]
+    # a copy the compiler put in comes from nowhere in the program
+    assert len(ops) == 21 and [e[0].split(" ")[0] for e in ops if len(e) == 3] \
+        == ["%copy.1"] * 3
+    assert {e[3]["tf_op"] for e in ops if len(e) == 4} == {
+        "q:", "jit(step)/jvp(probe_scope)/broadcast_in_dim:",
+        "jit(step)/jvp(probe_scope)/pallas_call:",
+        "jit(step)/transpose(jvp())/convert_element_type:",
+        "jit(step)/transpose(jvp(probe_scope))/pallas_call:",
+        "jit(step)/transpose(jvp(probe_scope))/add_any:"}
+    # host events carry none, and the reductions read either form
+    assert all(len(e) == 3 for evs in probe["host"].values() for e in evs)
+    busy, window = tracered.busy_and_window(probe)
+    assert 0 < busy < window
+    assert len(tracered.breakdown(probe)["device_ops"]) == 7
+
+
+def test_kernel_is_found_by_its_name_and_its_shape_only_read(probe):
+    calls = tracered.kernel_calls(probe)
+    assert [c[:5] for c in calls] == [(8, 2, 128, 256, False),
+                                      (8, 2, 128, 256, True)] * 3
+    ops = probe["devices"][0]["XLA Ops"]
+    # the name decides: a custom call of the same form under another
+    # primitive's name is not the kernel, and without any name the form is
+    # all there is (the recorded fixture of PR 24)
+    other = [e[:3] + [{"tf_op": "jit(step)/jvp(conv)/other_call:"}]
+             if len(e) == 4 and "pallas_call" in e[3]["tf_op"] else e
+             for e in ops]
+    assert tracered.kernel_calls({"devices": [{"XLA Ops": other}]}) == []
+    bare = [e[:3] for e in ops]
+    assert tracered.kernel_calls({"devices": [{"XLA Ops": bare}]}) == calls
+
+
+def test_wire_reader_takes_strings_and_references():
+    def varint(n):
+        out = b""
+        while True:
+            out += bytes([(n & 0x7F) | (0x80 if n > 0x7F else 0)])
+            n >>= 7
+            if not n:
+                return out
+
+    def field(no, payload):
+        if isinstance(payload, int):
+            return varint(no << 3) + varint(payload)
+        return varint(no << 3 | 2) + varint(len(payload)) + payload
+
+    entry = lambda key, value: field(1, key) + field(2, value)
+    stat_meta = lambda i, name: field(5, entry(i, field(1, i) + field(2, name)))
+    by_string = field(5, field(1, 7) + field(5, b"jit(f)/scope/dot_general:"))
+    by_ref = field(5, field(1, 8) + field(7, 300))
+    other = field(5, field(1, 9) + field(2 << 0, 1))  # a stat nobody asked for
+    plane = (field(2, b"/device:TPU:0") + stat_meta(7, b"tf_op")
+             + stat_meta(8, b"hlo_op") + stat_meta(9, b"flops")
+             + stat_meta(300, b"fusion.1")
+             + field(4, entry(1, field(1, 1) + field(2, b"%fusion.1 = f32[]")
+                              + by_string + by_ref + other))
+             + field(4, entry(2, field(1, 2) + field(2, b"%copy") + other)))
+    assert tracered.op_names(field(1, plane) + field(4, b"host")) == {
+        "/device:TPU:0": {"%fusion.1 = f32[]": {
+            "tf_op": "jit(f)/scope/dot_general:", "hlo_op": "fusion.1"}}}
+
+
 def test_idle_gaps_named_by_the_innermost_host_event():
     trace = {"devices": [{"XLA Ops": [["a", 0, 1000], ["b", 101_000, 1000]]}],
              "host": {"main": [["outer", 0, 200_000], ["$loader.py:1 read", 10_000, 80_000]]}}
