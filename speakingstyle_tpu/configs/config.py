@@ -165,7 +165,122 @@ class VocoderConfig:
 
 
 @dataclass(frozen=True)
+class RopeConfig:
+    """One layer type's rotary positions, keys as a Hugging Face
+    ``rope_parameters`` group has them. ``default``: inverse frequencies
+    ``theta^(-2k/d)``. ``yarn``: those blended with the same over ``factor``
+    along the linear ramp between the two correction dimensions
+    (``beta_fast``, ``beta_slow`` rotations over
+    ``original_max_position_embeddings``), cos and sin times
+    ``attention_factor``."""
+
+    rope_type: str = "default"
+    rope_theta: float = 500000.0
+    factor: float = 1.0
+    original_max_position_embeddings: int = 8192
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
+    def __post_init__(self):
+        if self.rope_type not in ("default", "yarn"):
+            raise ValueError(f"rope_type must be default|yarn, got {self.rope_type}")
+
+
+@dataclass(frozen=True)
+class RopeParametersConfig:
+    full_attention: RopeConfig = field(default_factory=lambda: RopeConfig(
+        rope_type="yarn", factor=16.0, attention_factor=1.2772588722239782))
+    sliding_attention: RopeConfig = field(default_factory=RopeConfig)
+
+
+@dataclass(frozen=True)
+class DecoderLMConfig:
+    """The ``decoder_lm`` family (models/mellum.py): a pre-norm decoder of
+    RMSNorm, grouped-query rotary attention (a window or the full causal
+    triangle, by layer) and a sparse-expert feed-forward, trained on
+    next-token cross-entropy. The keys a published ``config.json`` has keep
+    its names and defaults (Mellum2-12B-A2.5B); the rest say what this chip
+    holds of an expert-parallel deployment and what a row of the data is.
+    ``layer_types`` may be longer than ``num_hidden_layers`` (a depth cut
+    keeps the published list whole and runs its head)."""
+
+    model_type: str = "mellum"
+    vocab_size: int = 98304
+    hidden_size: int = 2304
+    intermediate_size: int = 7168   # dense layers' width: none here, unused
+    num_hidden_layers: int = 28
+    num_attention_heads: int = 32
+    num_key_value_heads: int = 4
+    head_dim: int = 128
+    hidden_act: str = "silu"
+    attention_bias: bool = False
+    layer_types: List[str] = field(default_factory=lambda: [
+        "sliding_attention", "sliding_attention", "sliding_attention",
+        "full_attention"] * 7)
+    mlp_layer_types: List[str] = field(default_factory=lambda: ["sparse"] * 28)
+    sliding_window: int = 1024
+    use_sliding_window: bool = True
+    max_window_layers: int = 0
+    max_position_embeddings: int = 131072
+    rms_norm_eps: float = 1e-6
+    num_experts: int = 64           # the router's outputs: all of the model's
+    num_experts_per_tok: int = 8
+    moe_intermediate_size: int = 896
+    norm_topk_prob: bool = True
+    tie_word_embeddings: bool = False
+    rope_parameters: RopeParametersConfig = field(
+        default_factory=RopeParametersConfig)
+    # -- what this chip holds (0: everything) --------------------------------
+    expert_offset: int = 0          # the first expert held here
+    experts_held: int = 0           # how many, from expert_offset on
+    vocab_held: int = 0             # rows of embedding and head: ids < this
+    # -- the data's rows -----------------------------------------------------
+    seq_len: int = 8192             # positions of a packed row
+    eod_id: int = 0                 # closes a document inside a row
+
+    def __post_init__(self):
+        n = self.num_hidden_layers
+        if len(self.layer_types) < n or len(self.mlp_layer_types) < n:
+            raise ValueError(
+                f"layer_types/mlp_layer_types list {len(self.layer_types)}/"
+                f"{len(self.mlp_layer_types)} layers, num_hidden_layers is {n}")
+        for kind in self.layer_types[:n]:
+            if kind not in ("sliding_attention", "full_attention"):
+                raise ValueError(f"unknown layer type {kind!r}")
+        if any(k != "sparse" for k in self.mlp_layer_types[:n]):
+            raise ValueError("only sparse feed-forward layers are built")
+        if self.hidden_act != "silu" or self.attention_bias \
+                or self.tie_word_embeddings:
+            raise ValueError("decoder_lm builds silu experts, no attention "
+                             "bias and an untied head")
+        if self.num_attention_heads % self.num_key_value_heads:
+            raise ValueError("num_attention_heads must be a multiple of "
+                             "num_key_value_heads")
+        held = self.experts_held or self.num_experts
+        if self.expert_offset < 0 or self.expert_offset + held > self.num_experts:
+            raise ValueError(
+                f"experts [{self.expert_offset}, {self.expert_offset + held}) "
+                f"are not among the router's {self.num_experts}")
+        if not 0 <= self.vocab_held <= self.vocab_size:
+            raise ValueError("vocab_held must lie within vocab_size")
+
+    @property
+    def n_experts_held(self) -> int:
+        return self.experts_held or self.num_experts
+
+    @property
+    def n_vocab_held(self) -> int:
+        return self.vocab_held or self.vocab_size
+
+
+@dataclass(frozen=True)
 class ModelConfig:
+    # which model the factory builds: "acoustic" (FastSpeech2 with the style
+    # reference encoder: every block below but decoder_lm) or "decoder_lm"
+    # (the decoder_lm block alone, with compute_dtype)
+    family: str = "acoustic"
+    decoder_lm: DecoderLMConfig = field(default_factory=DecoderLMConfig)
     transformer: TransformerConfig = field(default_factory=TransformerConfig)
     reference_encoder: ReferenceEncoderConfig = field(default_factory=ReferenceEncoderConfig)
     variance_predictor: VariancePredictorConfig = field(default_factory=VariancePredictorConfig)
@@ -232,6 +347,9 @@ class ModelConfig:
     dropout_impl: str = "hash"
 
     def __post_init__(self):
+        if self.family not in ("acoustic", "decoder_lm"):
+            raise ValueError(
+                f"model family must be acoustic|decoder_lm, got {self.family}")
         if self.attention_impl not in ("dense", "ring"):
             raise ValueError(
                 f"attention_impl must be dense|ring, got {self.attention_impl}"

@@ -10,6 +10,11 @@ from speakingstyle_tpu.data.dataset import (
     parse_metadata,
 )
 from speakingstyle_tpu.data.prefetch import DevicePrefetcher
+from speakingstyle_tpu.data.token_dataset import (
+    PackedBatcher,
+    TokenBatch,
+    TokenDataset,
+)
 
 __all__ = [
     "Batch",
@@ -20,4 +25,7 @@ __all__ = [
     "bucket_length",
     "parse_metadata",
     "DevicePrefetcher",
+    "PackedBatcher",
+    "TokenBatch",
+    "TokenDataset",
 ]

@@ -72,6 +72,20 @@ class Batch:
     energies: np.ndarray     # [B, L_src or L_mel] float32
     durations: np.ndarray    # [B, L_src] int32
 
+    @property
+    def frames_real(self) -> int:
+        """Real (unpadded) positions of the sequence the decoder runs over."""
+        return int(self.mel_lens.sum())
+
+    @property
+    def frames_padded(self) -> int:
+        return self.mels.shape[0] * self.mels.shape[1]
+
+    @property
+    def shape(self) -> tuple:
+        """What the step compiles once for: (rows, mel bucket, src bucket)."""
+        return self.mels.shape[:2] + self.texts.shape[1:]
+
     def arrays(self) -> Dict[str, np.ndarray]:
         return {
             "speakers": self.speakers,
@@ -126,10 +140,9 @@ class CacheBudget:
             return True
 
 
-class SpeechDataset:
-    """Feature-loading dataset (reference: dataset.py:12-146).
-
-    A sample is built once and then kept, finished (ids and the four arrays
+class CachedSamples:
+    """What every dataset of ``.npy`` samples shares: a sample is built once
+    (``_build(idx)``, the subclass's) and then kept, finished (its arrays
     after their casts, marked read-only), for as long as ``cache`` has room:
     ``__getitem__`` serves it from memory from then on and reads nothing. A
     sample that does not fit is built from its files every time, as is one
@@ -139,38 +152,27 @@ class SpeechDataset:
     samples served either way.
 
     ``retries``/``backoff`` engage retry-with-exponential-backoff on
-    transient OSErrors in the feature loads (flaky network filesystems on
+    transient OSErrors in the file loads (flaky network filesystems on
     preemptible slices); ``fault_plan`` (training/faults.py) injects a
-    ``loader_ioerror`` exactly once at the named feature-load count so the
+    ``loader_ioerror`` exactly once at the named load count so the
     retry path is exercised deterministically in tests.
 
     ``read_seconds``/``read_files``/``read_bytes`` accumulate what
-    ``np.load`` alone cost (``loader_read``): plain sums and no span, at
-    four files a sample that is not held; the batcher that drives the
-    dataset reports their growth once per super-batch (the seconds as a
-    counter, files and bytes on its ``loader_fetch`` span).
+    ``np.load`` alone cost (``loader_read``): plain sums and no span; the
+    batcher that drives the dataset reports their growth once per
+    super-batch (the seconds as a counter, files and bytes on its
+    ``loader_fetch`` span).
     """
 
-    def __init__(
-        self,
-        filename: str,
-        config: Config,
-        sort: bool = True,
-        drop_last: bool = False,
-        retries: int = 0,
-        backoff: float = 0.05,
-        fault_plan=None,
-        cache: Optional[CacheBudget] = None,
-    ):
-        pp = config.preprocess
-        self.root = pp.path.preprocessed_path
-        self.cleaners = pp.preprocessing.text.text_cleaners
+    group_size = 4  # super-batch factor (reference: train.py:31)
+
+    def __init__(self, config: Config, sort: bool, drop_last: bool,
+                 retries: int, backoff: float, fault_plan,
+                 cache: Optional[CacheBudget]):
+        self.root = config.preprocess.path.preprocessed_path
         self.batch_size = config.train.optimizer.batch_size
-        self.group_size = 4  # super-batch factor (reference: train.py:31)
         self.sort = sort
         self.drop_last = drop_last
-        self.pitch_level = pp.preprocessing.pitch.feature
-        self.energy_level = pp.preprocessing.energy.feature
         self.retries = retries
         self.backoff = backoff
         self.fault_plan = fault_plan
@@ -179,17 +181,14 @@ class SpeechDataset:
         self._held: Dict[int, Dict] = {}
         self.cache_hits, self.cache_misses = 0, 0
         self.read_seconds, self.read_files, self.read_bytes = 0.0, 0, 0
-        self.entries = parse_metadata(os.path.join(self.root, filename))
-        with open(os.path.join(self.root, "speakers.json")) as f:
-            self.speaker_map = json.load(f)
+        self.entries: List[tuple] = []
 
     def __len__(self):
         return len(self.entries)
 
-    def _feature(self, kind: str, speaker: str, basename: str) -> np.ndarray:
+    def _load(self, path: str) -> np.ndarray:
         from speakingstyle_tpu.training.resilience import retry_io
 
-        path = os.path.join(self.root, kind, f"{speaker}-{kind}-{basename}.npy")
         self._feature_loads += 1
         n = self._feature_loads
 
@@ -213,18 +212,7 @@ class SpeechDataset:
         )
 
     def _build(self, idx: int) -> Dict:
-        basename, speaker, text, raw = self.entries[idx]
-        phones = np.asarray(text_to_sequence(text, self.cleaners), np.int32)
-        return {
-            "id": basename,
-            "speaker": self.speaker_map[speaker],
-            "raw_text": raw,
-            "text": phones,
-            "mel": self._feature("mel", speaker, basename).astype(np.float32),
-            "pitch": self._feature("pitch", speaker, basename).astype(np.float32),
-            "energy": self._feature("energy", speaker, basename).astype(np.float32),
-            "duration": self._feature("duration", speaker, basename).astype(np.int32),
-        }
+        raise NotImplementedError
 
     def __getitem__(self, idx: int) -> Dict:
         sample = self._held.get(idx)
@@ -239,6 +227,51 @@ class SpeechDataset:
                 a.flags.writeable = False
             self._held[idx] = sample
         return dict(sample)
+
+
+class SpeechDataset(CachedSamples):
+    """Feature-loading dataset (reference: dataset.py:12-146): four feature
+    files a sample (mel, pitch, energy, duration), kept after their first
+    read as ``CachedSamples`` says."""
+
+    def __init__(
+        self,
+        filename: str,
+        config: Config,
+        sort: bool = True,
+        drop_last: bool = False,
+        retries: int = 0,
+        backoff: float = 0.05,
+        fault_plan=None,
+        cache: Optional[CacheBudget] = None,
+    ):
+        super().__init__(config, sort, drop_last, retries, backoff, fault_plan,
+                         cache)
+        pp = config.preprocess
+        self.cleaners = pp.preprocessing.text.text_cleaners
+        self.pitch_level = pp.preprocessing.pitch.feature
+        self.energy_level = pp.preprocessing.energy.feature
+        self.entries = parse_metadata(os.path.join(self.root, filename))
+        with open(os.path.join(self.root, "speakers.json")) as f:
+            self.speaker_map = json.load(f)
+
+    def _feature(self, kind: str, speaker: str, basename: str) -> np.ndarray:
+        return self._load(
+            os.path.join(self.root, kind, f"{speaker}-{kind}-{basename}.npy"))
+
+    def _build(self, idx: int) -> Dict:
+        basename, speaker, text, raw = self.entries[idx]
+        phones = np.asarray(text_to_sequence(text, self.cleaners), np.int32)
+        return {
+            "id": basename,
+            "speaker": self.speaker_map[speaker],
+            "raw_text": raw,
+            "text": phones,
+            "mel": self._feature("mel", speaker, basename).astype(np.float32),
+            "pitch": self._feature("pitch", speaker, basename).astype(np.float32),
+            "energy": self._feature("energy", speaker, basename).astype(np.float32),
+            "duration": self._feature("duration", speaker, basename).astype(np.int32),
+        }
 
 
 class BucketedBatcher:

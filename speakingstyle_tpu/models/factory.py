@@ -1,4 +1,6 @@
-"""Model factory: build FastSpeech2 from config + preprocessed-dataset stats.
+"""Model factory: build the configured family's model (FastSpeech2 from
+config + preprocessed-dataset stats; the decoder language model from its
+config block alone).
 
 Reference: utils/model.py:11-45 (get_model). Pitch/energy bin ranges come
 from stats.json and the speaker count from speakers.json, both written by
@@ -98,10 +100,16 @@ def fft_stack_from_config(
 
 def build_model(
     cfg: Config, n_position: Optional[int] = None, seq_mesh=None
-) -> FastSpeech2:
-    """``seq_mesh`` (a Mesh with a "seq" axis) is required when
+):
+    """The model of ``cfg.model.family``: FastSpeech2 (``acoustic``) or the
+    decoder language model (``decoder_lm``, models/mellum.py). ``seq_mesh`` (a Mesh with a "seq" axis) is required when
     cfg.model.attention_impl == "ring"; build one with
     parallel.mesh.make_seq_mesh() for long-sequence inference."""
+    if cfg.model.family == "decoder_lm":
+        from speakingstyle_tpu.models.mellum import DecoderLM
+
+        return DecoderLM(cfg.model.decoder_lm,
+                         dtype=jnp.dtype(cfg.model.compute_dtype))
     if cfg.model.attention_impl == "ring" and seq_mesh is None:
         raise ValueError(
             'attention_impl="ring" needs a seq mesh: '
@@ -121,7 +129,13 @@ def build_model(
 
 
 def init_variables(model: FastSpeech2, cfg: Config, rng: jax.Array):
-    """Initialize params/batch_stats with a minimal teacher-forced dummy batch."""
+    """Initialize params/batch_stats with a minimal teacher-forced dummy
+    batch (decoder_lm: a row of a few ids; parameters only, and under jit,
+    so that nothing but the initializers runs on the device)."""
+    if cfg.model.family == "decoder_lm":
+        from speakingstyle_tpu.parallel.registry import jit_program
+
+        return jit_program(model.init)(rng, jnp.zeros((1, 8), jnp.int32))
     n_mels = cfg.preprocess.preprocessing.mel.n_mel_channels
     B, L, T = 2, 8, 16
     dummy = dict(

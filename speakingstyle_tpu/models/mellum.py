@@ -1,0 +1,315 @@
+"""The ``decoder_lm`` family: a pre-norm decoder language model with
+grouped-query rotary attention (a window or the full causal triangle, by
+layer) and a sparse-expert feed-forward that is told which experts it holds
+(Mellum2-12B-A2.5B's block; ``configs/config.py:DecoderLMConfig``).
+
+    x_0 = E[ids]
+    h   = x + Attn(RMSNorm(x))          self_attn  (ops/blocked_attention.py)
+    y   = h + MoE(RMSNorm(h))           moe        (ops/grouped_matmul.py)
+    loss = mean cross-entropy of id t+1 given ids <= t, over the held
+           vocabulary rows, after a final RMSNorm and an untied head
+
+**Expert-parallel share.** The router scores all ``num_experts`` and takes
+the ``num_experts_per_tok`` largest; the layer computes the part of the sum
+that experts ``[expert_offset, expert_offset + experts_held)`` give, for
+every (token, choice) pair that names one of them, whatever the imbalance
+(no capacity, no drop). What the absent experts would add is left out and
+the partial result goes on. On one chip there is no exchange and nothing
+stands in for one. Embedding and head hold ``vocab_held`` rows; the ids
+and the loss are over that slice.
+
+**Memory.** Parameters are float32, compute is ``compute_dtype``; softmax,
+router, norms' statistics and the loss are float32. Each
+layer keeps two residual-stream arrays for the backward pass and recomputes
+its two halves: attention as one block, the experts one batch row at a time
+(a row's worst case, every pair held here, sizes the dispatch buffers).
+The logits stand ``LOSS_CHUNK`` positions at a time.
+
+The step's counters come back with the loss (``aux``): per layer, the pairs
+each held expert drew, the pairs routed to held experts and the pairs that
+got a row (equal, or something was dropped).
+"""
+
+import math
+from typing import Tuple
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from speakingstyle_tpu.configs.config import DecoderLMConfig, RopeConfig
+from speakingstyle_tpu.ops import expert_dispatch
+from speakingstyle_tpu.ops.blocked_attention import blocked_attention
+from speakingstyle_tpu.ops.grouped_matmul import TILE_ROWS, grouped_matmul
+
+# positions whose float32 logits stand at once (fewer where a batch has fewer)
+LOSS_CHUNK = 4096
+
+
+def rope_inv_freq(rope: RopeConfig, head_dim: int) -> Tuple[np.ndarray, float]:
+    """(inverse frequencies ``[head_dim / 2]``, the factor on cos and sin)."""
+    k = np.arange(0, head_dim, 2, dtype=np.float64)
+    extrapolation = rope.rope_theta ** (-k / head_dim)
+    if rope.rope_type == "default":
+        return extrapolation, 1.0
+    interpolation = extrapolation / rope.factor
+
+    def correction_dim(rotations):
+        return head_dim * math.log(
+            rope.original_max_position_embeddings / (rotations * 2 * math.pi)
+        ) / (2 * math.log(rope.rope_theta))
+
+    low = max(math.floor(correction_dim(rope.beta_fast)), 0)
+    high = min(math.ceil(correction_dim(rope.beta_slow)), head_dim - 1)
+    ramp = np.clip((np.arange(head_dim // 2) - low) / max(high - low, 1e-3), 0, 1)
+    return interpolation * ramp + extrapolation * (1 - ramp), rope.attention_factor
+
+
+def rope_tables(rope: RopeConfig, head_dim: int, length: int):
+    """cos and sin ``[length, head_dim]`` (each half repeated: rotate-half)."""
+    inv_freq, factor = rope_inv_freq(rope, head_dim)
+    angles = np.arange(length, dtype=np.float64)[:, None] * inv_freq[None, :]
+    angles = np.concatenate([angles, angles], axis=1)
+    return (jnp.asarray(np.cos(angles) * factor, jnp.float32),
+            jnp.asarray(np.sin(angles) * factor, jnp.float32))
+
+
+def apply_rope(x, cos, sin):
+    """x ``[B, T, H, D]``; rotate-half, in float32."""
+    x32 = x.astype(jnp.float32)
+    half = x.shape[-1] // 2
+    rotated = jnp.concatenate([-x32[..., half:], x32[..., :half]], axis=-1)
+    return (x32 * cos[None, :, None, :]
+            + rotated * sin[None, :, None, :]).astype(x.dtype)
+
+
+def rms_norm(x, scale, eps):
+    x32 = x.astype(jnp.float32)
+    var = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
+    return (x32 * jax.lax.rsqrt(var + eps) * scale).astype(x.dtype)
+
+
+class RMSNorm(nn.Module):
+    eps: float
+
+    @nn.compact
+    def __call__(self, x):
+        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],),
+                           jnp.float32)
+        return rms_norm(x, scale, self.eps)
+
+
+INIT_STD = 0.02
+
+
+def writes_std(cfg: DecoderLMConfig) -> float:
+    """The initial scale of the projections that write into the residual
+    stream (``o_proj``, the experts' ``down``): ``INIT_STD`` over the root of
+    twice the model's depth (the whole ``layer_types`` list, also where
+    ``num_hidden_layers`` runs only its head), as GPT-2 and Megatron-LM start.
+    With the embedding at unit scale the stream then carries the token and
+    the router's input depends on it; at ``INIT_STD`` everywhere the first
+    attention output, much the same at every position, swamps the embedding
+    and every token of a layer chooses the same experts."""
+    return INIT_STD / math.sqrt(2 * len(cfg.layer_types))
+
+
+def _dense(features, name, dtype, std=INIT_STD):
+    return nn.Dense(features, use_bias=False, dtype=dtype,
+                    param_dtype=jnp.float32, name=name,
+                    kernel_init=nn.initializers.normal(std))
+
+
+class SelfAttention(nn.Module):
+    """The layer's first half, its norm included: ``Attn(RMSNorm(x))``."""
+
+    cfg: DecoderLMConfig
+    window: int  # 0: the full causal triangle
+    dtype: jnp.dtype
+
+    @nn.compact
+    def __call__(self, x, cos, sin):
+        c = self.cfg
+        B, T, _ = x.shape
+        H, Hkv, D = c.num_attention_heads, c.num_key_value_heads, c.head_dim
+        u = RMSNorm(c.rms_norm_eps, name="input_norm")(x)
+        q = _dense(H * D, "q_proj", self.dtype)(u).reshape(B, T, H, D)
+        k = _dense(Hkv * D, "k_proj", self.dtype)(u).reshape(B, T, Hkv, D)
+        v = _dense(Hkv * D, "v_proj", self.dtype)(u).reshape(B, T, Hkv, D)
+        q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
+        # the attention core, under a name of its own (the per-module
+        # readers hold on to ``self_attn/core``, whatever implements it)
+        with jax.named_scope("core"):
+            o = blocked_attention(
+                q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
+                v.transpose(0, 2, 1, 3), window=self.window or None,
+                sm_scale=1.0 / math.sqrt(D))
+            o = o.transpose(0, 2, 1, 3).reshape(B, T, H * D)
+        return _dense(c.hidden_size, "o_proj", self.dtype, writes_std(c))(o)
+
+
+def moe_row(h, norm_scale, w_router, w_gate, w_up, w_down, *,
+            cfg: DecoderLMConfig):
+    """``MoE(RMSNorm(h))`` for one row ``[T, d]``: (the held experts' part of
+    the result, the router's choices ``[T, k]``, per held expert the pairs
+    it drew, pairs routed to held experts, pairs that got a row)."""
+    # a row tile no longer than an expert's even share of the row's pairs
+    share = h.shape[0] * cfg.num_experts_per_tok // cfg.num_experts
+    tm = min(TILE_ROWS, max(8, share // 8 * 8))
+    u = rms_norm(h, norm_scale, cfg.rms_norm_eps)
+    with jax.named_scope("router"):
+        logits = jnp.dot(u.astype(jnp.float32), w_router,
+                         precision=jax.lax.Precision.HIGHEST)
+        weights, idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1),
+                                     cfg.num_experts_per_tok)
+        if cfg.norm_topk_prob:
+            weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    with jax.named_scope("dispatch"):
+        plan = expert_dispatch.plan(idx, cfg.expert_offset, cfg.n_experts_held, tm)
+        rows = expert_dispatch.dispatch(u, plan)
+    with jax.named_scope("experts"):
+        args = (plan.tile_expert, plan.n_used, tm)
+        gate = grouped_matmul(rows, w_gate, *args)
+        up = grouped_matmul(rows, w_up, *args)
+        act = (jax.nn.silu(gate.astype(jnp.float32))
+               * up.astype(jnp.float32)).astype(rows.dtype)
+        out_rows = grouped_matmul(act, w_down, *args)
+    with jax.named_scope("combine"):
+        out = expert_dispatch.combine(out_rows, weights, plan)
+    local = idx - cfg.expert_offset
+    routed = jnp.sum((local >= 0) & (local < cfg.n_experts_held))
+    placed = jnp.sum(plan.row_pair >= 0)
+    return out, idx, plan.counts, routed, placed
+
+
+class Router(nn.Module):
+    n_experts: int
+
+    @nn.compact
+    def __call__(self, d):
+        return self.param("kernel", nn.initializers.normal(INIT_STD),
+                          (d, self.n_experts), jnp.float32)
+
+
+class Experts(nn.Module):
+    n_held: int
+    width: int
+    down_std: float
+
+    @nn.compact
+    def __call__(self, d):
+        init = nn.initializers.normal(INIT_STD)
+        shape = (self.n_held, d, self.width)
+        return (self.param("gate", init, shape, jnp.float32),
+                self.param("up", init, shape, jnp.float32),
+                self.param("down", nn.initializers.normal(self.down_std),
+                           (self.n_held, self.width, d), jnp.float32))
+
+
+class SparseMoE(nn.Module):
+    """The layer's second half, its norm included, one batch row at a time."""
+
+    cfg: DecoderLMConfig
+
+    @nn.compact
+    def __call__(self, h):
+        c = self.cfg
+        d = h.shape[-1]
+        scale = self.param("norm_scale", nn.initializers.ones, (d,), jnp.float32)
+        w_router = Router(c.num_experts, name="router")(d)
+        w_gate, w_up, w_down = Experts(
+            c.n_experts_held, c.moe_intermediate_size, writes_std(c),
+            name="experts")(d)
+
+        def row(h_row):
+            return moe_row(h_row, scale, w_router, w_gate, w_up, w_down, cfg=c)
+
+        out, idx, counts, routed, placed = jax.lax.map(jax.checkpoint(row), h)
+        return out, (jnp.sum(counts, axis=0), jnp.sum(routed), jnp.sum(placed),
+                     idx)
+
+
+class DecoderLayer(nn.Module):
+    cfg: DecoderLMConfig
+    window: int
+    dtype: jnp.dtype
+
+    @nn.compact
+    def __call__(self, x, cos, sin):
+        h = x + nn.remat(SelfAttention)(
+            self.cfg, self.window, self.dtype, name="self_attn")(x, cos, sin)
+        out, aux = SparseMoE(self.cfg, name="moe")(h)
+        return h + out, aux
+
+
+def next_token_loss(hidden, head, tokens, chunk: int = LOSS_CHUNK):
+    """Mean cross-entropy of ``tokens[:, t + 1]`` from ``hidden[:, t]``
+    (``[B, T, d]``, already normed) under ``head`` ``[d, V]``, float32, the
+    logits standing ``chunk`` positions at a time and recomputed backward."""
+    B, T, d = hidden.shape
+    n = B * T
+    targets = jnp.concatenate(
+        [tokens[:, 1:], jnp.zeros((B, 1), tokens.dtype)], axis=1).reshape(n)
+    weight = jnp.concatenate(
+        [jnp.ones((B, T - 1), jnp.float32), jnp.zeros((B, 1), jnp.float32)],
+        axis=1).reshape(n)
+    chunk = min(chunk, n)
+    pad = -n % chunk
+    flat = jnp.pad(hidden.reshape(n, d), ((0, pad), (0, 0)))
+    targets, weight = jnp.pad(targets, (0, pad)), jnp.pad(weight, (0, pad))
+    w = head.astype(hidden.dtype)
+
+    @jax.checkpoint
+    def part(args):
+        x, t, m = args
+        logits = jnp.dot(x, w, preferred_element_type=jnp.float32)
+        lse = jax.nn.logsumexp(logits, axis=-1)
+        picked = jnp.take_along_axis(logits, t[:, None], axis=-1)[:, 0]
+        return jnp.sum((lse - picked) * m)
+
+    sums = jax.lax.map(part, (flat.reshape(-1, chunk, d),
+                              targets.reshape(-1, chunk),
+                              weight.reshape(-1, chunk)))
+    return jnp.sum(sums) / (B * (T - 1))
+
+
+class LMHead(nn.Module):
+    vocab: int
+
+    @nn.compact
+    def __call__(self, hidden, tokens):
+        head = self.param("kernel", nn.initializers.normal(INIT_STD),
+                          (hidden.shape[-1], self.vocab), jnp.float32)
+        return next_token_loss(hidden, head, tokens)
+
+
+class DecoderLM(nn.Module):
+    """tokens ``[B, T]`` int32 -> (loss, aux). ``aux``: ``expert_counts``
+    ``[layers, experts_held]``, ``pairs_routed`` and ``pairs_placed``
+    ``[layers]``, and the router's ``choices`` ``[layers, B, T, k]``."""
+
+    cfg: DecoderLMConfig
+    dtype: jnp.dtype = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, tokens):
+        c = self.cfg
+        T = tokens.shape[1]
+        x = nn.Embed(c.n_vocab_held, c.hidden_size, dtype=self.dtype,
+                     param_dtype=jnp.float32, name="embed",
+                     embedding_init=nn.initializers.normal(1.0))(tokens)
+        tables = {kind: rope_tables(getattr(c.rope_parameters, kind),
+                                    c.head_dim, T)
+                  for kind in set(c.layer_types[:c.num_hidden_layers])}
+        aux = []
+        for i, kind in enumerate(c.layer_types[:c.num_hidden_layers]):
+            window = c.sliding_window if kind == "sliding_attention" else 0
+            x, a = DecoderLayer(c, window, self.dtype, name=f"layers_{i}")(
+                x, *tables[kind])
+            aux.append(a)
+        hidden = RMSNorm(c.rms_norm_eps, name="final_norm")(x)
+        loss = LMHead(c.n_vocab_held, name="lm_head")(hidden, tokens)
+        counts, routed, placed, choices = (jnp.stack(v) for v in zip(*aux))
+        return loss, {"expert_counts": counts, "pairs_routed": routed,
+                      "pairs_placed": placed, "choices": choices}

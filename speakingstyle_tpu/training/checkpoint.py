@@ -53,7 +53,7 @@ from typing import Dict, List, Optional, Sequence
 import jax
 import orbax.checkpoint as ocp
 
-from speakingstyle_tpu.obs.buildinfo import array_sha256, weights_digest
+from speakingstyle_tpu.obs.buildinfo import leaf_sha256, weights_digest
 from speakingstyle_tpu.training.state import TrainState
 from speakingstyle_tpu.obs.locks import make_lock
 
@@ -89,18 +89,12 @@ def _leaf_table(tree) -> Dict[str, Dict]:
     so one flattening convention covers save, verify, and identity."""
     import numpy as np
 
-    table: Dict[str, Dict] = {}
-    for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]:
-        name = "/".join(
-            str(getattr(k, "key", getattr(k, "idx", k))) for k in path
-        )
-        a = np.asarray(leaf)
-        table[name] = {
-            "sha256": array_sha256(a),
-            "shape": list(a.shape),
-            "dtype": str(a.dtype),
-        }
-    return table
+    shas = leaf_sha256(tree)
+    leaves = [np.asarray(leaf) for leaf in jax.tree_util.tree_leaves(tree)]
+    return {
+        name: {"sha256": sha, "shape": list(a.shape), "dtype": str(a.dtype)}
+        for (name, sha), a in zip(shas.items(), leaves)
+    }
 
 
 class CheckpointManager:

@@ -16,6 +16,7 @@ import time
 from typing import Dict, Iterator, Optional
 
 import jax
+import numpy as np
 import optax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -29,7 +30,7 @@ from speakingstyle_tpu.training import faults, resilience
 from speakingstyle_tpu.training.state import TrainState
 
 # keys in the step's losses dict that are sentinel/bookkeeping, not losses
-_INTERNAL_LOSS_KEYS = ("_finite",)
+_INTERNAL_LOSS_KEYS = ("_finite", "_moe", "_choices")
 
 
 def public_losses(losses: Dict) -> Dict:
@@ -100,8 +101,9 @@ def make_train_step(model, tx, cfg: Config, mesh=None, state_shardings=None):
     p_level = cfg.preprocess.preprocessing.pitch.feature
     e_level = cfg.preprocess.preprocessing.energy.feature
     nan_sentinel = cfg.train.resilience.nan_sentinel
+    lm = cfg.model.family == "decoder_lm"
 
-    def step_fn(state: TrainState, arrays: Dict, rng) -> tuple:
+    def acoustic_loss(params, state, arrays, rng):
         # trace-time contracts: shape/dtype metadata only, so these run
         # (and fail) during tracing and add nothing to the compiled step
         B = arrays["texts"].shape[0]
@@ -112,35 +114,47 @@ def make_train_step(model, tx, cfg: Config, mesh=None, state_shardings=None):
         contracts.assert_shape(
             arrays["durations"], arrays["texts"].shape, "train_step.durations"
         )
+        out, updates = model.apply(
+            {"params": params, "batch_stats": state.batch_stats},
+            **_model_kwargs(arrays, teacher_forced=True),
+            deterministic=False,
+            rngs={"dropout": rng},
+            mutable=["batch_stats"],
+        )
+        losses = fastspeech2_loss(
+            out,
+            arrays["mels"],
+            arrays["pitches"],
+            arrays["energies"],
+            arrays["durations"],
+            params,
+            lambda_f=lambda_f,
+            pitch_feature_level=p_level,
+            energy_feature_level=e_level,
+        )
+        return losses["total_loss"], (losses, updates["batch_stats"])
+
+    def lm_loss(params, state, arrays, rng):
+        # next-token cross-entropy over the held vocabulary rows; the
+        # routing's counts ride back beside it (``_moe``: read by the host
+        # at the log boundary only, as the sentinel is) and so do the
+        # router's choices (``_choices``: the loop never reads them)
+        contracts.assert_rank(arrays["tokens"], 2, "train_step.tokens")
+        loss, aux = model.apply({"params": params}, arrays["tokens"])
+        choices = aux.pop("choices")
+        return loss, ({"total_loss": loss, "_moe": aux, "_choices": choices},
+                      state.batch_stats)
+
+    loss_of = lm_loss if lm else acoustic_loss
+
+    def step_fn(state: TrainState, arrays: Dict, rng) -> tuple:
         rng = jax.random.fold_in(rng, state.step)
-
-        def loss_fn(params):
-            out, updates = model.apply(
-                {"params": params, "batch_stats": state.batch_stats},
-                **_model_kwargs(arrays, teacher_forced=True),
-                deterministic=False,
-                rngs={"dropout": rng},
-                mutable=["batch_stats"],
-            )
-            losses = fastspeech2_loss(
-                out,
-                arrays["mels"],
-                arrays["pitches"],
-                arrays["energies"],
-                arrays["durations"],
-                params,
-                lambda_f=lambda_f,
-                pitch_feature_level=p_level,
-                energy_feature_level=e_level,
-            )
-            return losses["total_loss"], (losses, updates["batch_stats"])
-
         (_, (losses, batch_stats)), grads = jax.value_and_grad(
-            loss_fn, has_aux=True
+            lambda params: loss_of(params, state, arrays, rng), has_aux=True
         )(state.params)
         if nan_sentinel:  # trace-time flag: compiled in or out, never branched
             losses = dict(losses)
-            flag = resilience.all_finite(losses, grads)
+            flag = resilience.all_finite(public_losses(losses), grads)
             if mesh is not None:
                 # explicit dp-axis reduction: pin the flag fully replicated
                 # so GSPMD compiles the all-reduce over the data axis into
@@ -182,6 +196,9 @@ def make_eval_step(model, cfg: Config, mesh=None, state_shardings=None):
     e_level = cfg.preprocess.preprocessing.energy.feature
 
     def eval_fn(state: TrainState, arrays: Dict) -> Dict:
+        if cfg.model.family == "decoder_lm":
+            loss, _ = model.apply({"params": state.params}, arrays["tokens"])
+            return {"total_loss": loss}
         out = model.apply(
             {"params": state.params, "batch_stats": state.batch_stats},
             **_model_kwargs(arrays, teacher_forced=True),
@@ -277,14 +294,61 @@ _WINDOW_COUNTERS = {
 }
 
 
-def _window_totals(registry) -> Dict[str, float]:
+# the decoder_lm family's routing counters, per step of the window as the
+# others are (``_count_routing`` feeds them at the log boundary)
+_MOE_WINDOW_COUNTERS = {
+    "moe_pairs_held": "moe_pairs_held_total",
+    "moe_pairs_dropped": "moe_pairs_dropped_total",
+    "moe_expert_tokens_max": "moe_expert_tokens_max_total",
+    "moe_expert_tokens_mean": "moe_expert_tokens_mean_total",
+}
+
+
+def _window_totals(registry, moe: bool = False) -> Dict[str, float]:
     """The sums behind a ``train_step`` event's window fields, as they
     stand now; a window's share is the difference of two readings."""
     out = {field: registry.histogram(name).sum
            for field, name in _WINDOW_HISTOGRAMS.items()}
-    out.update((field, registry.value(name))
-               for field, name in _WINDOW_COUNTERS.items())
+    counters = {**_WINDOW_COUNTERS, **(_MOE_WINDOW_COUNTERS if moe else {})}
+    out.update((field, registry.value(name)) for field, name in counters.items())
     return out
+
+
+def _count_routing(registry, pending: list, parent) -> None:
+    """The routing counts the steps since the last log boundary returned
+    (``losses["_moe"]``, already computed: the boundary has synced) into the
+    registry: pairs the held experts computed, pairs routed to a held expert
+    that got no row (0: nothing is ever dropped), and per step the sum over
+    layers of the fullest held expert's pairs and of the mean. The last
+    step's per-layer numbers also go into the span ring (``moe_load``)."""
+    if not pending:
+        return
+    held = registry.counter(
+        "moe_pairs_held_total",
+        help="(token, choice) pairs computed by the experts held here")
+    dropped = registry.counter(
+        "moe_pairs_dropped_total",
+        help="pairs routed to a held expert that got no row (always 0)")
+    most = registry.counter(
+        "moe_expert_tokens_max_total",
+        help="per step, summed over layers: pairs of the fullest held expert")
+    mean = registry.counter(
+        "moe_expert_tokens_mean_total",
+        help="per step, summed over layers: mean pairs of a held expert")
+    fetched = jax.device_get(pending)
+    counts = np.stack([aux["expert_counts"] for aux in fetched])   # [steps, layers, held]
+    placed = np.stack([aux["pairs_placed"] for aux in fetched])    # [steps, layers]
+    lost = np.stack([aux["pairs_routed"] for aux in fetched]) - placed
+    held.inc(placed.sum().item())
+    dropped.inc(lost.sum().item())
+    most.inc(counts.max(axis=2).sum().item())
+    mean.inc(counts.mean(axis=2).sum().item())
+    obs.Span.record(
+        "moe_load", time.time(), 0.0, parent=parent,
+        tokens_max=counts[-1].max(axis=1).tolist(),
+        tokens_mean=counts[-1].mean(axis=1).tolist(),
+        pairs_held=placed[-1].tolist(), pairs_dropped=lost[-1].tolist())
+    pending.clear()
 
 
 # run_training's mesh default: "resolve from cfg.train.parallel". An
@@ -360,7 +424,9 @@ def run_training(
         BucketedBatcher,
         CacheBudget,
         DevicePrefetcher,
+        PackedBatcher,
         SpeechDataset,
+        TokenDataset,
     )
     from speakingstyle_tpu.models.factory import build_model, init_variables
     from speakingstyle_tpu.training.checkpoint import CheckpointManager
@@ -425,12 +491,16 @@ def run_training(
     fault_ctr = registry.counter(
         "faults_fired_total", help="injected faults fired (drills)"
     )
+    # a frame is one position of the sequence the decoder runs over: a mel
+    # frame of the acoustic family, a token of the decoder_lm family
     frames_real_ctr = registry.counter(
-        "train_frames_real_total", help="real mel frames handed to the step"
+        "train_frames_real_total",
+        help="real mel frames (decoder_lm: positions) handed to the step"
     )
     frames_padded_ctr = registry.counter(
         "train_frames_padded_total",
-        help="mel frames of the padded batch shapes handed to the step",
+        help="mel frames (decoder_lm: positions) of the padded batch shapes "
+             "handed to the step",
     )
     mem_gauge = registry.gauge(
         "device_memory_watermark_bytes",
@@ -444,9 +514,10 @@ def run_training(
     with setup_span("setup_model_init"):
         model = build_model(cfg)
         rng = jax.random.PRNGKey(cfg.train.seed)
-        variables = init_variables(model, cfg, rng)
         tx = make_optimizer(cfg.train)
-        state = TrainState.create(variables, tx)
+        # no name is kept on the initial variables: once a restore replaces
+        # the state they are gigabytes of the decoder_lm family's device memory
+        state = TrainState.create(init_variables(model, cfg, rng), tx)
         schedule = make_lr_schedule(cfg.train)
 
     # the checkpoint manager, the state's placement on the mesh, and the
@@ -504,21 +575,31 @@ def run_training(
     pad_mult = mesh.shape["data"] if mesh is not None else 1
     step = int(state.step)
     start_step = step  # profile window is relative to where this run begins
+    # by family: what a sample is (an utterance's features, a document's
+    # ids) and how samples become batches (bucket padding, packing); cache,
+    # budget, fetch spans, quarantine and prefetcher are the same
+    lm = cfg.model.family == "decoder_lm"
+    dataset_cls = TokenDataset if lm else SpeechDataset
+
+    def make_batcher(ds, seed: int, reg, quarantine=None):
+        if lm:
+            return PackedBatcher(
+                ds, cfg.model.decoder_lm.seq_len, cfg.model.decoder_lm.eod_id,
+                seed=seed, quarantine=quarantine, registry=reg,
+                trace_parent=run_ctx)
+        return BucketedBatcher(
+            ds, max_src=max_src, max_mel=max_mel,
+            batch_pad_multiple=pad_mult, seed=seed, quarantine=quarantine,
+            registry=reg)
 
     def make_stream(retry: int) -> DevicePrefetcher:
         # the data seed folds in the resume point AND the rollback retry
         # counter, so a resumed run doesn't replay the original stream
         # from its beginning and a rolled-back run diverges past the
         # batch window that tripped the sentinel
-        batcher = BucketedBatcher(
-            train_ds,
-            max_src=max_src,
-            max_mel=max_mel,
-            batch_pad_multiple=pad_mult,
-            seed=cfg.train.seed + start_step + 7919 * retry,
-            quarantine=quarantine,
-            registry=registry,
-        )
+        batcher = make_batcher(
+            train_ds, cfg.train.seed + start_step + 7919 * retry, registry,
+            quarantine)
         return DevicePrefetcher(
             iter(batcher), mesh=mesh, transfer_retries=res.loader_retries,
             transfer_backoff=res.loader_backoff, registry=registry,
@@ -544,27 +625,20 @@ def run_training(
         # after their first read; a rollback's new stream reads the same
         # train_ds, so what it holds survives
         sample_cache = CacheBudget()
-        train_ds = SpeechDataset(
+        train_ds = dataset_cls(
             "train.txt", cfg, sort=True, drop_last=True,
             retries=res.loader_retries, backoff=res.loader_backoff,
             fault_plan=plan, cache=sample_cache,
         )
         quarantine = resilience.Quarantine(budget=res.bad_sample_budget)
         prefetch = make_stream(0)
-        val_ds = SpeechDataset("val.txt", cfg, sort=False, drop_last=False,
-                               cache=sample_cache)
+        val_ds = dataset_cls("val.txt", cfg, sort=False, drop_last=False,
+                             cache=sample_cache)
         # the validation stream's loader spans observe into a registry of
         # their own: a train_step event's window fields are deltas of the
         # run's registry and count the training loader alone
         val_registry = obs.MetricsRegistry()
-        val_batcher = BucketedBatcher(
-            val_ds,
-            max_src=max_src,
-            max_mel=max_mel,
-            batch_pad_multiple=pad_mult,
-            seed=0,
-            registry=val_registry,
-        )
+        val_batcher = make_batcher(val_ds, 0, val_registry)
 
     logger = None
     if log:
@@ -603,6 +677,8 @@ def run_training(
             loader_cache_budget_bytes=sample_cache.limit,
             **obs.build_info(),
         )
+    if lm:
+        synth_callback = None  # nothing to render: no mel, no vocoder
     if synth_callback == "default":
         synth_callback = default_synth_callback(cfg, logger, vocoder=vocoder)
     step_rng = jax.random.PRNGKey(cfg.train.seed + 1)
@@ -640,7 +716,8 @@ def run_training(
     # the four main-thread spans inside it are disjoint, so their sum can
     # not pass the window's wall time
     window_t0, window_step0 = time.monotonic(), step
-    window_totals = _window_totals(registry)
+    window_totals = _window_totals(registry, lm)
+    moe_pending = []  # each step's routing counts, read at the log boundary
     trace_active = False
     shutdown = resilience.GracefulShutdown()
     try:
@@ -673,8 +750,10 @@ def run_training(
                 # step_fn folds state.step into the key, so passing the same
                 # step_rng every iteration yields a fresh per-step stream
                 with jax.profiler.StepTraceAnnotation("train", step_num=step), \
-                        dispatch_span(batch.mels.shape[:2] + batch.texts.shape[1:]):
+                        dispatch_span(batch.shape):
                     state, losses = train_step(state, arrays, step_rng)  # jaxlint: disable=JL006
+                if "_moe" in losses:
+                    moe_pending.append(losses["_moe"])
                 step += 1
                 steps_ctr.inc()
                 if card_pending:
@@ -688,8 +767,8 @@ def run_training(
                     if program_card is not None and logger:
                         logger.event("program_card", **program_card.as_dict())
                 # host-side, no sync
-                frames_real_ctr.inc(int(batch.mel_lens.sum()))
-                frames_padded_ctr.inc(batch.mels.shape[0] * batch.mels.shape[1])
+                frames_real_ctr.inc(batch.frames_real)
+                frames_padded_ctr.inc(batch.frames_padded)
                 if trace_active and step - start_step >= profile_steps[1]:
                     with obs.Span("profile_stop", registry=registry,
                                   events=events_log, dir=profile_dir,
@@ -711,6 +790,7 @@ def run_training(
                     with obs.Span("train_sync", registry=registry):
                         jax.block_until_ready(losses["total_loss"])
                         finite = "_finite" not in losses or bool(losses["_finite"])
+                        _count_routing(registry, moe_pending, run_ctx)
                     if not finite:
                         n = guard.trip(step)  # raises past max_rollbacks
                         ckpt.wait()
@@ -737,7 +817,7 @@ def run_training(
                         step = int(state.step)  # jaxlint: disable=JL004
                         prefetch = make_stream(guard.count)
                         window_t0, window_step0 = time.monotonic(), step
-                        window_totals = _window_totals(registry)
+                        window_totals = _window_totals(registry, lm)
                         continue
                     t_log = time.monotonic()
                     with obs.Span("train_log", registry=registry):
@@ -764,7 +844,7 @@ def run_training(
                             dt = t_log - window_t0
                             timing = None
                             if n_window > 0:
-                                totals = _window_totals(registry)
+                                totals = _window_totals(registry, lm)
                                 timing = {
                                     k: (totals[k] - window_totals[k]) / n_window
                                     for k in totals

@@ -1,0 +1,70 @@
+"""The decoder_lm family's two kernels compiled for a described v5e at the
+benchmark cell's real widths, without a chip: what the interpreter cannot
+show (a slice off the tiling, a kernel over its fast memory) the TPU's
+compiler refuses here. Nothing runs; a compile that passes is not a chip
+run. The topology is described inside a fixture (never at import), and this
+is the only file that loads the TPU's library."""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def no_compile_cache():
+    """Such a compile is written to the persistent cache and cannot be read
+    back without a chip: keep it out."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
+
+
+@pytest.mark.parametrize("window", [None, 1024], ids=["full", "window_1024"])
+def test_blocked_attention_compiles_at_the_cells_shapes(one_chip, no_compile_cache,
+                                                        window):
+    from speakingstyle_tpu.ops.blocked_attention import blocked_attention
+
+    q = jax.ShapeDtypeStruct((1, 32, 8192, 128), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, 4, 8192, 128), jnp.bfloat16, sharding=one_chip)
+
+    def loss(q, k, v):
+        return jnp.sum(blocked_attention(q, k, v, window=window,
+                                         interpret=False).astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, (0, 1, 2))).lower(q, kv, kv).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 3   # fwd, dq, dk/dv
+
+
+@pytest.mark.parametrize("k,n", [(2304, 896), (896, 2304)], ids=["gate_up", "down"])
+def test_grouped_product_compiles_at_the_cells_shapes(one_chip, no_compile_cache, k, n):
+    from speakingstyle_tpu.ops.grouped_matmul import TILE_ROWS, grouped_matmul
+
+    held, tm = 16, TILE_ROWS
+    rows = (8192 * 8 // tm + held) * tm      # a row's worst case: every pair held
+    args = (jax.ShapeDtypeStruct((rows, k), jnp.bfloat16, sharding=one_chip),
+            jax.ShapeDtypeStruct((held, k, n), jnp.float32, sharding=one_chip),
+            jax.ShapeDtypeStruct((rows // tm,), jnp.int32, sharding=one_chip),
+            jax.ShapeDtypeStruct((1,), jnp.int32, sharding=one_chip))
+
+    def loss(x, w, tile_expert, n_used):
+        return jnp.sum(grouped_matmul(x, w, tile_expert, n_used, tm,
+                                      False).astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, (0, 1))).lower(*args).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 2   # dx and dw
