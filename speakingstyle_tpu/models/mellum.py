@@ -22,12 +22,15 @@ and the loss are over that slice.
 router, norms' statistics and the loss are float32. Each
 layer keeps two residual-stream arrays for the backward pass and recomputes
 its two halves: attention as one block, the experts one batch row at a time
-(a row's worst case, every pair held here, sizes the dispatch buffers).
-The logits stand ``LOSS_CHUNK`` positions at a time.
+(a row's worst case, every pair held here, sizes the dispatch buffers'
+shapes; the work over them follows the tiles the row's plan uses:
+``ops/expert_dispatch.py``). The logits stand ``LOSS_CHUNK`` positions at a
+time.
 
 The step's counters come back with the loss (``aux``): per layer, the pairs
-each held expert drew, the pairs routed to held experts and the pairs that
-got a row (equal, or something was dropped).
+each held expert drew, the pairs routed to held experts, the pairs that
+got a row (equal, or something was dropped), and the row tiles the plans
+used beside the worst case the buffers are sized for.
 """
 
 import math
@@ -149,14 +152,23 @@ class SelfAttention(nn.Module):
         return _dense(c.hidden_size, "o_proj", self.dtype, writes_std(c))(o)
 
 
+def tile_rows(cfg: DecoderLMConfig, tokens: int) -> int:
+    """A row tile no longer than an expert's even share of a row's pairs."""
+    share = tokens * cfg.num_experts_per_tok // cfg.num_experts
+    return min(TILE_ROWS, max(8, share // 8 * 8))
+
+
+def _swiglu(gate, up):
+    return (jax.nn.silu(gate.astype(jnp.float32))
+            * up.astype(jnp.float32)).astype(gate.dtype)
+
+
 def moe_row(h, norm_scale, w_router, w_gate, w_up, w_down, *,
             cfg: DecoderLMConfig):
     """``MoE(RMSNorm(h))`` for one row ``[T, d]``: (the held experts' part of
     the result, the router's choices ``[T, k]``, per held expert the pairs
     it drew, pairs routed to held experts, pairs that got a row)."""
-    # a row tile no longer than an expert's even share of the row's pairs
-    share = h.shape[0] * cfg.num_experts_per_tok // cfg.num_experts
-    tm = min(TILE_ROWS, max(8, share // 8 * 8))
+    tm = tile_rows(cfg, h.shape[0])
     u = rms_norm(h, norm_scale, cfg.rms_norm_eps)
     with jax.named_scope("router"):
         logits = jnp.dot(u.astype(jnp.float32), w_router,
@@ -170,10 +182,10 @@ def moe_row(h, norm_scale, w_router, w_gate, w_up, w_down, *,
         rows = expert_dispatch.dispatch(u, plan)
     with jax.named_scope("experts"):
         args = (plan.tile_expert, plan.n_used, tm)
+        rows, rows_again = expert_dispatch.twice(rows, plan)
         gate = grouped_matmul(rows, w_gate, *args)
-        up = grouped_matmul(rows, w_up, *args)
-        act = (jax.nn.silu(gate.astype(jnp.float32))
-               * up.astype(jnp.float32)).astype(rows.dtype)
+        up = grouped_matmul(rows_again, w_up, *args)
+        act = expert_dispatch.on_used_rows(_swiglu, plan, gate, up)
         out_rows = grouped_matmul(act, w_down, *args)
     with jax.named_scope("combine"):
         out = expert_dispatch.combine(out_rows, weights, plan)
@@ -226,8 +238,14 @@ class SparseMoE(nn.Module):
             return moe_row(h_row, scale, w_router, w_gate, w_up, w_down, cfg=c)
 
         out, idx, counts, routed, placed = jax.lax.map(jax.checkpoint(row), h)
+        # the tiles the rows' plans used (``plan.n_used``, by the plan's own
+        # arithmetic) and the worst case their buffers are sized for
+        rows, tokens, k = idx.shape
+        tm = tile_rows(c, tokens)
+        used = jnp.sum(expert_dispatch.tiles_of(counts, tm))
+        worst = rows * expert_dispatch.worst_tiles(tokens * k, c.n_experts_held, tm)
         return out, (jnp.sum(counts, axis=0), jnp.sum(routed), jnp.sum(placed),
-                     idx)
+                     used, jnp.asarray(worst, jnp.int32), idx)
 
 
 class DecoderLayer(nn.Module):
@@ -286,8 +304,9 @@ class LMHead(nn.Module):
 
 class DecoderLM(nn.Module):
     """tokens ``[B, T]`` int32 -> (loss, aux). ``aux``: ``expert_counts``
-    ``[layers, experts_held]``, ``pairs_routed`` and ``pairs_placed``
-    ``[layers]``, and the router's ``choices`` ``[layers, B, T, k]``."""
+    ``[layers, experts_held]``, ``pairs_routed``, ``pairs_placed``,
+    ``tiles_used`` and ``tiles_worst`` ``[layers]``, and the router's
+    ``choices`` ``[layers, B, T, k]``."""
 
     cfg: DecoderLMConfig
     dtype: jnp.dtype = jnp.bfloat16
@@ -310,6 +329,8 @@ class DecoderLM(nn.Module):
             aux.append(a)
         hidden = RMSNorm(c.rms_norm_eps, name="final_norm")(x)
         loss = LMHead(c.n_vocab_held, name="lm_head")(hidden, tokens)
-        counts, routed, placed, choices = (jnp.stack(v) for v in zip(*aux))
+        counts, routed, placed, used, worst, choices = (
+            jnp.stack(v) for v in zip(*aux))
         return loss, {"expert_counts": counts, "pairs_routed": routed,
-                      "pairs_placed": placed, "choices": choices}
+                      "pairs_placed": placed, "tiles_used": used,
+                      "tiles_worst": worst, "choices": choices}

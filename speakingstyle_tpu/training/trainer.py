@@ -301,6 +301,7 @@ _MOE_WINDOW_COUNTERS = {
     "moe_pairs_dropped": "moe_pairs_dropped_total",
     "moe_expert_tokens_max": "moe_expert_tokens_max_total",
     "moe_expert_tokens_mean": "moe_expert_tokens_mean_total",
+    "moe_tiles_used": "moe_tiles_used_total",
 }
 
 
@@ -319,8 +320,10 @@ def _count_routing(registry, pending: list, parent) -> None:
     (``losses["_moe"]``, already computed: the boundary has synced) into the
     registry: pairs the held experts computed, pairs routed to a held expert
     that got no row (0: nothing is ever dropped), and per step the sum over
-    layers of the fullest held expert's pairs and of the mean. The last
-    step's per-layer numbers also go into the span ring (``moe_load``)."""
+    layers of the fullest held expert's pairs and of the mean, and the row
+    tiles the dispatch plans used (what every pass over the sorted rows
+    costs). The last step's per-layer numbers also go into the span ring
+    (``moe_load``), with the worst case the buffers are sized for."""
     if not pending:
         return
     held = registry.counter(
@@ -335,6 +338,9 @@ def _count_routing(registry, pending: list, parent) -> None:
     mean = registry.counter(
         "moe_expert_tokens_mean_total",
         help="per step, summed over layers: mean pairs of a held expert")
+    tiles = registry.counter(
+        "moe_tiles_used_total",
+        help="per step, summed over layers and rows: row tiles the plans used")
     fetched = jax.device_get(pending)
     counts = np.stack([aux["expert_counts"] for aux in fetched])   # [steps, layers, held]
     placed = np.stack([aux["pairs_placed"] for aux in fetched])    # [steps, layers]
@@ -343,11 +349,14 @@ def _count_routing(registry, pending: list, parent) -> None:
     dropped.inc(lost.sum().item())
     most.inc(counts.max(axis=2).sum().item())
     mean.inc(counts.mean(axis=2).sum().item())
+    tiles.inc(sum(aux["tiles_used"].sum().item() for aux in fetched))
     obs.Span.record(
         "moe_load", time.time(), 0.0, parent=parent,
         tokens_max=counts[-1].max(axis=1).tolist(),
         tokens_mean=counts[-1].mean(axis=1).tolist(),
-        pairs_held=placed[-1].tolist(), pairs_dropped=lost[-1].tolist())
+        pairs_held=placed[-1].tolist(), pairs_dropped=lost[-1].tolist(),
+        moe_tiles_used=fetched[-1]["tiles_used"].sum().item(),
+        moe_tiles_worst=fetched[-1]["tiles_worst"].sum().item())
     pending.clear()
 
 

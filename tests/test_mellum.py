@@ -277,6 +277,94 @@ def test_grouped_product_is_a_loop_over_the_held_experts(interpret, lo):
         assert float(jnp.max(jnp.abs(u - v))) < 1e-4
 
 
+def _loads():
+    """(name, choices ``[n, k]``, lo, held, tm) over eight experts."""
+    rng = np.random.default_rng(5)
+    top = np.argsort(rng.standard_normal((70, 8)), axis=1)[:, :2]   # two of eight
+    # more pairs than one step of a pass takes, in tiles that leave the last
+    # step partial: it reaches back over rows already done
+    tm, k = 64, 4
+    n = (expert_dispatch._CHUNK_ROWS + 5 * tm) // k
+    assert expert_dispatch.worst_tiles(n * k, 2, tm) * tm % expert_dispatch._CHUNK_ROWS
+    return [("none held", top % 4 + 4, 0, 2, 8),
+            ("a quarter held", top, 2, 2, 8),
+            ("all held", top % 3 + 1, 1, 3, 8),
+            ("all pairs to one expert", np.full((70, 2), 6), 5, 3, 8),
+            ("a partial last step", rng.integers(0, 2, (n, k)), 0, 2, tm)]
+
+
+@pytest.mark.parametrize("load", _loads(), ids=lambda load: load[0])
+def test_dispatch_and_combine_are_the_dense_form_at_every_load(load):
+    """``out[t] = sum over t's held pairs of w[t, c] * f_e(x[t])``, with its
+    gradients, whatever share of the worst case the plan uses."""
+    _, idx, lo, held, tm = load
+    idx = jnp.asarray(idx, jnp.int32)
+    n, k = idx.shape
+    ks = jax.random.split(jax.random.PRNGKey(n), 4)
+    x = jax.random.normal(ks[0], (n, 8))
+    weights = jax.nn.softmax(jax.random.normal(ks[1], (n, k)))
+    scale = jax.random.normal(ks[2], (held,)) + 2.0    # f_e = scale[e] * tanh
+    c = jax.random.normal(ks[3], (n, 8))
+
+    def sorted_rows(x, weights, scale):
+        p = expert_dispatch.plan(idx, lo, held, tm)
+        by_row = jnp.repeat(scale[p.tile_expert], tm)[:, None]
+        y = expert_dispatch.on_used_rows(lambda r, s: jnp.tanh(r) * s, p,
+                                         expert_dispatch.dispatch(x, p), by_row)
+        return jnp.sum(c * expert_dispatch.combine(y, weights, p))
+
+    def dense(x, weights, scale):
+        out = 0.0
+        for e in range(held):
+            share = jnp.sum(jnp.where(idx == lo + e, weights, 0.0), axis=1)
+            out = out + share[:, None] * jnp.tanh(x) * scale[e]
+        return jnp.sum(c * out)
+
+    a, ga = jax.value_and_grad(sorted_rows, (0, 1, 2))(x, weights, scale)
+    b, gb = jax.value_and_grad(dense, (0, 1, 2))(x, weights, scale)
+    assert abs(float(a - b)) < 1e-4 * max(1.0, abs(float(b)))
+    for u, v in zip(ga, gb):
+        assert float(jnp.max(jnp.abs(u - v))) < 1e-4 * max(1.0, float(jnp.max(jnp.abs(v))))
+
+
+def test_rows_past_the_plan_are_never_read():
+    """The products leave the tiles past ``n_used`` unwritten: whatever stands
+    there (NaN here, forward and backward) reaches no result and no
+    gradient."""
+    n, k, d, f, held, tm = 64, 2, 16, 24, 2, 8
+    ks = jax.random.split(jax.random.PRNGKey(2), 7)
+    x = jax.random.normal(ks[0], (n, d))
+    weights, idx = jax.lax.top_k(jax.nn.softmax(jax.random.normal(ks[1], (n, 8))), k)
+    w_gate, w_up, w_down = (jax.random.normal(ks[2], (held, d, f)),
+                            jax.random.normal(ks[3], (held, d, f)),
+                            jax.random.normal(ks[4], (held, f, d)))
+    c = jax.random.normal(ks[5], (n, d))
+    p = expert_dispatch.plan(idx, 3, held, tm)
+    past = jnp.arange(p.row_pair.shape[0])[:, None] >= p.n_used[0] * tm
+    assert 0 < int(p.n_used[0]) < p.tile_expert.shape[0] // 2
+
+    @jax.custom_vjp
+    def spoil(a):
+        return jnp.where(past, jnp.nan, a)
+
+    spoil.defvjp(lambda a: (spoil(a), None), lambda _, g: (spoil(g),))
+
+    def layer(spoil, x, weights, w_gate, w_up, w_down):
+        args = (p.tile_expert, p.n_used, tm)
+        rows, again = expert_dispatch.twice(expert_dispatch.dispatch(x, p), p)
+        gate = spoil(grouped_matmul(rows, w_gate, *args))
+        up = spoil(grouped_matmul(again, w_up, *args))
+        act = spoil(expert_dispatch.on_used_rows(mellum._swiglu, p, gate, up))
+        y = spoil(grouped_matmul(act, w_down, *args))
+        return jnp.sum(c * expert_dispatch.combine(y, weights, p))
+
+    grad = jax.value_and_grad(layer, (1, 2, 3, 4, 5))
+    want = grad(lambda a: a, x, weights, w_gate, w_up, w_down)
+    got = grad(spoil, x, weights, w_gate, w_up, w_down)
+    for u, v in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert bool(jnp.isfinite(u).all()) and float(jnp.max(jnp.abs(u - v))) == 0.0
+
+
 def test_plan_sizes_for_the_worst_case_and_drops_nothing():
     idx = jnp.zeros((40, 2), jnp.int32).at[:, 1].set(1)   # every pair held
     p = expert_dispatch.plan(idx, 0, 2, 8)

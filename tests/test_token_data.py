@@ -97,7 +97,8 @@ def test_run_training_trains_saves_and_resumes_the_decoder_lm(lm_config):
                 "loader_fetch_s", "loader_read_s", "loader_collate_s",
                 "loader_blocked_s", "loader_cache_hits", "loader_cache_misses",
                 "total_loss", "moe_pairs_held", "moe_pairs_dropped",
-                "moe_expert_tokens_max", "moe_expert_tokens_mean"} <= set(e)
+                "moe_expert_tokens_max", "moe_expert_tokens_mean",
+                "moe_tiles_used"} <= set(e)
         assert e["frames_real"] == e["frames_padded"] == 128
         assert e["moe_pairs_dropped"] == 0 and e["moe_pairs_held"] > 0
         assert e["moe_expert_tokens_max"] >= e["moe_expert_tokens_mean"] > 0
@@ -127,3 +128,50 @@ def test_cli_trains_the_decoder_lm_from_its_three_files(lm_config, capsys):
           "-t", paths["train"], "--max_steps", "2", "--data_parallel", "1"])
     assert "training finished at step 2" in capsys.readouterr().out
     assert os.path.isdir(os.path.join(lm_config.train.path.ckpt_path, "2"))
+
+
+def test_train_step_events_count_the_tiles_the_plans_used(lm_config, monkeypatch):
+    """``moe_tiles_used`` on a ``train_step`` event is the sum over layers and
+    rows of ``plan.n_used``, per step of the window, as the plans of the
+    choices those steps returned give it; the ``moe_load`` ring span holds
+    the last step's beside the worst case the buffers are sized for."""
+    import jax
+
+    from speakingstyle_tpu.models import mellum
+    from speakingstyle_tpu.ops import expert_dispatch
+    from speakingstyle_tpu.training import trainer
+
+    choices = []
+    made = trainer.make_train_step
+
+    def make(*args, **kwargs):
+        inner = made(*args, **kwargs)
+
+        def step(state, arrays, rng):
+            state, losses = inner(state, arrays, rng)
+            choices.append(losses["_choices"])
+            return state, losses
+        return step
+
+    monkeypatch.setattr(trainer, "make_train_step", make)
+    trainer.run_training(lm_config, mesh=None, registry=obs.MetricsRegistry(),
+                         max_steps=4)
+    lm = lm_config.model.decoder_lm
+    tm = mellum.tile_rows(lm, TOY_LM["seq_len"])
+
+    def tiles(step_choices):           # [layers, rows, T, k]
+        return sum(int(expert_dispatch.plan(row, lm.expert_offset, lm.n_experts_held,
+                                            tm).n_used[0])
+                   for layer in jax.device_get(step_choices) for row in layer)
+
+    used = [tiles(c) for c in choices]
+    assert len(used) == 4
+    with open(os.path.join(lm_config.train.path.log_path, "events.jsonl")) as f:
+        steps = [e for e in map(json.loads, f) if e["event"] == "train_step"]
+    assert [e["moe_tiles_used"] for e in steps] == [
+        (used[0] + used[1]) / 2, (used[2] + used[3]) / 2]
+    load = [s for s in obs.trace.get_span_ring().spans()
+            if s["name"] == "moe_load"][-1]["fields"]
+    worst = 4 * 4 * expert_dispatch.worst_tiles(32 * 2, 2, tm)
+    assert load["moe_tiles_worst"] == worst == 160
+    assert load["moe_tiles_used"] == used[-1] and 4 * 4 * 2 <= used[-1] <= worst

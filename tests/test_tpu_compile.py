@@ -68,3 +68,29 @@ def test_grouped_product_compiles_at_the_cells_shapes(one_chip, no_compile_cache
 
     compiled = jax.jit(jax.grad(loss, (0, 1))).lower(*args).compile()
     assert compiled.as_text().count("tpu_custom_call") >= 2   # dx and dw
+
+
+def test_dispatch_and_combine_compile_at_the_cells_shapes(one_chip, no_compile_cache,
+                                                          monkeypatch):
+    """Rows out and back, forward and backward, for one batch row of the
+    cell: 8,192 tokens of 2,304, 8 choices each, 16 of 64 experts held."""
+    from speakingstyle_tpu.ops import expert_dispatch
+    from speakingstyle_tpu.ops.grouped_matmul import TILE_ROWS
+
+    # the backend here is the CPU: take the chip's branch, as the chip would
+    monkeypatch.setattr(expert_dispatch, "on_tpu", lambda: True)
+    x = jax.ShapeDtypeStruct((8192, 2304), jnp.bfloat16, sharding=one_chip)
+    weights = jax.ShapeDtypeStruct((8192, 8), jnp.float32, sharding=one_chip)
+    idx = jax.ShapeDtypeStruct((8192, 8), jnp.int32, sharding=one_chip)
+
+    def loss(x, weights, idx):
+        p = expert_dispatch.plan(idx, 0, 16, TILE_ROWS)
+        rows = expert_dispatch.on_used_rows(jax.nn.silu, p,
+                                            expert_dispatch.dispatch(x, p))
+        return jnp.sum(expert_dispatch.combine(rows, weights, p).astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, (0, 1))).lower(x, weights, idx).compile()
+    # every pass over the sorted rows is a loop as long as the plan says,
+    # into a buffer that nothing fills
+    text = compiled.as_text()
+    assert text.count(" while(") >= 4 and text.count("tpu_custom_call") >= 3
