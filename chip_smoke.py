@@ -103,7 +103,8 @@ class Size:
     conv_shape: Tuple[int, int, int, int, int]
 
 
-# The flagship: LJSpeech_paper widths, the BENCH_r04 training geometry
+# The flagship: LJSpeech_paper widths, training at B=48, 100 phonemes and
+# 600 frames
 # (scripts/train_descent.py: 97-104 phones x 5-7 frames => every batch in
 # ONE (src 128, mel 640) bucket, ~29k mel frames per step of 48), and the
 # serve lattice cut in count, not width: the mel-1000 bucket pads to the

@@ -119,8 +119,7 @@ def main(argv=None) -> int:
     ap.add_argument(
         "paths", nargs="*",
         help="files/directories to lint (default: the repo's "
-             "speakingstyle_tpu/, scripts/, tests/, bench.py, "
-             "chip_smoke.py)",
+             "speakingstyle_tpu/, scripts/, tests/, chip_smoke.py)",
     )
     ap.add_argument(
         "--check", action="store_true",

@@ -53,8 +53,7 @@ def default_lockorder_path() -> str:
 def default_lint_paths() -> List[str]:
     root = repo_root()
     out = []
-    for rel in ("speakingstyle_tpu", "scripts", "tests", "bench.py",
-                "chip_smoke.py"):
+    for rel in ("speakingstyle_tpu", "scripts", "tests", "chip_smoke.py"):
         p = os.path.join(root, rel)
         if os.path.exists(p):
             out.append(p)

@@ -73,9 +73,9 @@ JL018  XLA compilation outside the program registry: any reference to
        jax.jit/jax.pjit (call, decorator, functools.partial argument,
        bare attribute), a ``from jax import jit/pjit`` import, or a
        .lower().compile() AOT chain anywhere under speakingstyle_tpu/
-       (plus bench.py) except parallel/registry.py — the registry is
-       the one guarded compile entry point (ProgramRegistry.compile
-       for AOT, jit_program for jit-on-call wrappers), which is what
+       except parallel/registry.py — the registry is the one guarded
+       compile entry point (ProgramRegistry.compile for AOT,
+       jit_program for jit-on-call wrappers), which is what
        makes the zero-steady-state-compiles invariant structural;
        precompile/warmup fixtures are exempt. Tree baseline: zero.
 JL019  full-utterance accumulation in serving code: a list that is
@@ -114,7 +114,7 @@ JL023  unsupervised thread: ``threading.Thread(...)`` without a
        ``name=`` (invisible to the watchdog/supervision machinery), or
        a thread-creating class with no close()/stop() path that joins
        the thread or sets a stop Event. Scoped to speakingstyle_tpu/
-       (bench/test harness threads are deliberately ad hoc).
+       (test harness threads are deliberately ad hoc).
        Tree baseline: zero.
 JL024  unbounded wire call in serving code: an HTTP/socket client
        construct — http.client.HTTPConnection/HTTPSConnection,
@@ -2070,13 +2070,11 @@ _REGISTRY_PATH_MARKER = "parallel/registry.py"
 
 
 def _jl018_in_scope(path: str) -> bool:
-    """The enforced tree: the package itself plus bench.py. Tests,
+    """The enforced tree: the package, except the registry. Tests,
     scripts/, and anything outside the package may spell jax.jit (their
     compiles are fixtures, not production programs)."""
     p = path.replace("\\", "/")
-    if _REGISTRY_PATH_MARKER in p:
-        return False
-    return "speakingstyle_tpu/" in p or os.path.basename(p) == "bench.py"
+    return "speakingstyle_tpu/" in p and _REGISTRY_PATH_MARKER not in p
 
 
 def rule_jl018(mod: ModuleInfo) -> Iterator[Finding]:
@@ -2084,7 +2082,7 @@ def rule_jl018(mod: ModuleInfo) -> Iterator[Finding]:
     reference to ``jax.jit``/``jax.pjit`` (call, decorator,
     ``functools.partial`` argument, or bare attribute), a
     ``from jax import jit``-style import, or a ``.lower(...).compile()``
-    AOT chain, anywhere under ``speakingstyle_tpu/`` or in ``bench.py``.
+    AOT chain, anywhere under ``speakingstyle_tpu/``.
 
     The ProgramRegistry (parallel/registry.py) is the ONE guarded entry
     point where XLA programs are built: it owns the cache-key semantics
@@ -2226,8 +2224,8 @@ def rule_jl019(mod: ModuleInfo) -> Iterator[Finding]:
 
 
 def _concurrency_in_scope(mod: ModuleInfo) -> bool:
-    """Package code only: bench.py and tests/ create deliberately ad-hoc
-    threads and toy locks that would drown the signal."""
+    """Package code only: tests/ create deliberately ad-hoc threads and
+    toy locks that would drown the signal."""
     p = mod.path.replace("\\", "/")
     return "speakingstyle_tpu/" in p and "tests/" not in p
 

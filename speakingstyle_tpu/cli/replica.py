@@ -11,7 +11,7 @@ single-process tier only in who routes to them), then registers with a
                    bounded cache instead of re-running the lattice)
   GET  /healthz    ready flag + compile/dispatch counters (the router's
                    adoption probe, and the zero-steady-state-compile
-                   check for the cluster bench)
+                   check of tests/test_cluster.py)
   POST /drain      stop admitting, finish in-flight, report not-ready
 
 Liveness is a heartbeat lease: the process beats every
@@ -26,8 +26,7 @@ multi-host mesh slice), pass ``--coordinator_address`` (+
 distributed runtime before any device work — each *replica* is then a
 whole jax process group, and the control plane above it is unchanged.
 
-Usually spawned by ``serve --cluster`` or ``bench.py --cluster`` rather
-than by hand.
+Usually spawned by ``serve --cluster`` rather than by hand.
 """
 
 import argparse

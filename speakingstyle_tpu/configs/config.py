@@ -289,7 +289,7 @@ class ModelConfig:
     max_seq_len: int = 1000
     vocoder: VocoderConfig = field(default_factory=VocoderConfig)
     # postnet topology (reference hardcodes 512/5/5 — model/modules.py);
-    # exposed so scaled-down configs (tests, the CPU serve bench) shrink
+    # exposed so scaled-down configs (tests, the distilled student) shrink
     # the whole model, not all-but-the-postnet
     postnet_embedding_dim: int = 512
     postnet_kernel_size: int = 5
@@ -300,19 +300,22 @@ class ModelConfig:
     # "xla" = lax.conv emitter, "unfold" = im2col GEMM (one large MXU
     # matmul per conv), "pallas" = fused conv+bias+ReLU(+LN) kernel
     # (ops/pallas_conv.py). Param trees are identical — switchable on a
-    # restored checkpoint. Default set by the r4 on-chip A/B (PERF.md):
-    # the XLA conv emitter measured fastest end-to-end on v5e (325k
-    # frames/s vs unfold's 265k — the im2col operand's extra HBM traffic
-    # costs more than the cleaner GEMM tiling saves on these shapes).
+    # restored checkpoint. The default is what the benchmark runs
+    # (train_ljspeech_b200; the decoder_lm family has no such site).
+    # It was chosen over "unfold" and "pallas" by a comparison read on
+    # an earlier installation; no reading in PERF_LEDGER.jsonl (ROADMAP
+    # C2).
     conv_impl: str = "xla"
     # softmax accumulation dtype in attention: "float32" (reference-parity
     # default) or "bfloat16" (A/B candidate; attention is <1% of step
     # FLOPs so this mostly saves VPU/memory traffic)
     attention_softmax_dtype: str = "float32"
     use_reference_encoder: bool = True
-    # attention lowering for the dense path: "fused" (default —
-    # ops/pallas_attention.py: one VMEM pass per (batch, head), f32
-    # softmax in-register; measured ~1.7x faster fwd+bwd at paper shapes)
+    # attention lowering for the dense path: "fused" (default, what the
+    # benchmark runs — ops/pallas_attention.py: one VMEM pass per
+    # (batch, head), f32 softmax in-register; its lead over "einsum" was
+    # read on an earlier installation; no reading in PERF_LEDGER.jsonl
+    # (ROADMAP C2))
     # or "einsum" (XLA, materializes [B, H, L, L] scores in HBM — the
     # literal transcription of the reference math). "fused" compiles the
     # kernel on a TPU backend and takes the einsum path on any other (CPU
@@ -326,7 +329,7 @@ class ModelConfig:
     # 8-device-mesh HLO
     # (tests/test_parallel.py::test_fused_attention_batch_partitioned_*),
     # loss parity with einsum under the data-sharded train step, and
-    # hardware execution on the 1-chip mesh (PERF.md).
+    # hardware execution on the 1-chip mesh (chip_smoke.py, both cells).
     attention_kernel: str = "fused"
     # "dense" or "ring": ring engages sequence-parallel exact attention
     # (parallel/ring_attention.py) in the encoder/decoder FFT stacks for
@@ -334,14 +337,14 @@ class ModelConfig:
     # (models/factory.build_model(..., seq_mesh=...)); sequence lengths
     # must divide by the mesh's seq axis.
     attention_impl: str = "dense"
-    # dropout mask generation (ops/dropout.py): "hash" (default — salted
-    # murmur3 counter hash, pure elementwise so XLA fuses it into the
-    # consumer; zero RNG-bit HBM traffic; measured -71..-286 us/site vs
-    # bernoulli on v5e, scripts/exp_dropout_r5.py), "bernoulli"
-    # (jax.random, what nn.Dropout does — the reference-parity RNG
-    # stream), or "bits16" (raw 16-bit threshold compare; measured worse
-    # than bernoulli — the bitcast defeats fusion; kept as the recorded
-    # negative). Mask distribution is identical across impls (inverted
+    # dropout mask generation (ops/dropout.py): "hash" (default, what
+    # the benchmark runs — salted murmur3 counter hash, pure
+    # elementwise so XLA fuses it into the consumer; zero RNG-bit HBM
+    # traffic), "bernoulli" (jax.random, what nn.Dropout does — the
+    # reference-parity RNG stream), or "bits16" (raw 16-bit threshold
+    # compare). The order hash < bernoulli < bits16 in cost was read on
+    # an earlier installation; no reading in PERF_LEDGER.jsonl (ROADMAP
+    # C2). Mask distribution is identical across impls (inverted
     # dropout, P(keep)=1-rate); only the PRNG stream differs, so this is
     # switchable on a restored checkpoint.
     dropout_impl: str = "hash"
@@ -611,21 +614,22 @@ class TrainConfig:
     ignore_layers: List[str] = field(default_factory=list)
     seed: int = 1234
     # Use XLA's native RBG bit generator for dropout masks instead of
-    # threefry: measured 15% step-time win on v5e (dropout masks over
-    # [B,600,1024] tensors dominate threefry's generation cost). No
-    # reference counterpart (torch RNG is cuRAND); disable for bit-stable
-    # dropout streams across hardware.
+    # threefry (dropout masks over [B,600,1024] tensors dominate
+    # threefry's generation cost). True is what both benchmark cells
+    # run; its gain was read on an earlier installation; no reading in
+    # PERF_LEDGER.jsonl (ROADMAP C2). No reference counterpart (torch
+    # RNG is cuRAND); disable for bit-stable dropout streams across
+    # hardware.
     fast_prng: bool = True
     # Run clip+Adam+LR as one fused pass over a single raveled parameter
     # vector (training/optim.py make_fused_optimizer) instead of the
     # per-leaf optax chain: mathematically identical update (parity test
     # in tests/test_training.py), different opt_state layout (flat mu/nu),
     # so checkpoints are not interchangeable with the unfused optimizer.
-    # A recorded NEGATIVE result on v5e at 35M params: the ravel/unravel
-    # copies cost more than the chain overhead they remove (422.6k vs
-    # 442.8k frames/s — see PERF.md), so this stays off by default and is
-    # kept as an honest A/B knob.
-    # r5 adds "leaf" (training/optim.make_leaf_fused_optimizer): the whole
+    # False (the optax chain) is what both benchmark cells run; "flat"
+    # was slower than the chain when read on an earlier installation; no
+    # reading in PERF_LEDGER.jsonl (ROADMAP C2).
+    # "leaf" (training/optim.make_leaf_fused_optimizer): the whole
     # clip+L2+Adam+lr chain as ONE fused expression per param leaf — no
     # ravel copies, no per-stage intermediate trees. True == "flat" for
     # back-compat. All three impls produce bit-identical updates (parity

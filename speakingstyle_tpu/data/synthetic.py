@@ -38,7 +38,7 @@ def generate_corpus(
     """Write a synthetic preprocessed corpus; returns ``out_dir``.
 
     Default geometry: ~100 phones x ~6 frames ≈ 600 mel frames/utterance —
-    the paper-config shape used for the descent artifact and bench.
+    the paper-config shape used for the descent artifact and chip_smoke.
     """
     rng = np.random.default_rng(seed)
     sig_rng = np.random.default_rng(1234)  # phone signatures: corpus-stable

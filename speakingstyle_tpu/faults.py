@@ -80,8 +80,8 @@ The plan is plain Python state constructed per run (``FaultPlan.from_env``)
 and threaded explicitly into the sites — no module globals, so tests can
 run many faulted loops in one process.  ``fire`` is thread-safe (serving
 sites race from replica workers) and ``arm`` appends entries to a live
-plan, which is how ``bench.py --chaos`` kills a replica mid-load at a
-deterministic dispatch count.
+plan, which is how the chaos tests (tests/test_chaos.py) kill a replica
+mid-load at a deterministic dispatch count.
 """
 
 import dataclasses
@@ -147,7 +147,7 @@ class FaultPlan:
         return bool(self._faults)
 
     def arm(self, kind: str, at: int) -> None:
-        """Append one entry to a live plan (bench.py --chaos arms the
+        """Append one entry to a live plan (the chaos tests arm the
         replica kill between load phases, at a dispatch count that has
         not happened yet)."""
         if kind not in KINDS:

@@ -40,9 +40,8 @@ def reference_encoder_from_config(
     cfg: Config, n_position: Optional[int] = None, name: Optional[str] = None
 ):
     """The one place ReferenceEncoder kwargs are derived from config —
-    shared by the model (fastspeech2.py), the analyze CLI, and the bench
-    breakdown, so a constructor change can't silently diverge between
-    them."""
+    shared by the model (fastspeech2.py) and the analyze CLI, so a
+    constructor change can't silently diverge between them."""
     from speakingstyle_tpu.models.reference_encoder import ReferenceEncoder
 
     m = cfg.model

@@ -1,7 +1,7 @@
 """Build/runtime identity + process gauges: *what* is this process?
 
 Every scrape and every training run should identify the code and stack
-that produced it — a BENCH number or a /metrics snapshot without a git
+that produced it — a benchmark line or a /metrics snapshot without a git
 SHA and a jax version is unattributable a week later. ``build_info()``
 collects the identity once (git SHA when the tree is a checkout, jax /
 jaxlib versions, backend platform + device count/kind, python); the

@@ -18,8 +18,7 @@ dataclass the telemetry layer can export:
     compile, emits a one-time ``program_card`` JSONL event, and folds
     achieved FLOP/s + a device-memory watermark into the per-step
     telemetry;
-  * ``bench.py --flops`` and the ``obs.cli programs`` subcommand are
-    thin consumers.
+  * the ``obs.cli programs`` subcommand is a thin consumer.
 
 Backends disagree wildly about these APIs: ``cost_analysis()`` may
 return a dict, a list-wrapped dict, ``None``, or raise; analysis keys
@@ -30,7 +29,7 @@ whatever it cannot extract stays ``None``, the failure is recorded in
 ``errors``, and the partial card remains usable — a flaky backend must
 not be able to crash engine precompile or trainer startup.
 
-Known blind spot (PERF.md "FLOP-count caveat"): XLA's cost analysis
+Known blind spot: XLA's cost analysis
 cannot see inside pallas/custom calls, so cards for programs using the
 fused-attention kernel UNDER-count by the attention math the kernel
 still executes. Compare against an einsum-config card for roofline

@@ -16,14 +16,13 @@ subscribes to them:
     distinguishes a warm start (hits ≈ requests) from a cold one
     (hits ≈ 0; misses are requests − hits);
   * ``CompileMonitor`` — a scoped counting window (``with monitor:``),
-    used by the serve smoke test and ``bench.py --serve`` to assert the
+    used by the serving tests (tests/test_serving.py) to assert the
     count is zero across a traffic window.
 
 ``enable_compilation_cache()`` is the one place that decides where jax's
 persistent compile cache lives: where ``JAX_COMPILATION_CACHE_DIR``
 points, else ``<checkout>/.jax_cache``. Every entry point calls it before
-its first compile (``__main__``, ``bench.py``, ``chip_smoke.py``'s
-children) and every ``ProgramRegistry`` (``parallel/registry.py``) calls
+its first compile (``__main__``, ``chip_smoke.py``'s children) and every ``ProgramRegistry`` (``parallel/registry.py``) calls
 it again with the ``train.obs.compilation_cache_dir`` override, so
 repeated runs skip the compiles the cache already holds.
 

@@ -15,8 +15,7 @@ call on their finished int16 samples:
   * the longform stitcher (``Stitcher.feed``/``finish``).
 
 Checks (all numpy over the emitted samples; one rFFT over a bounded
-prefix is the most expensive — see PERF.md for the measured paired
-overhead, gated at <= 2% of TTFA p50 by ``bench.py --quality``):
+prefix is the most expensive):
 
   ``non_finite``   any NaN/Inf in the float wav *before* the int16
                    conversion clipped it away (callers pass the
@@ -211,8 +210,7 @@ class QualityGate:
     wavs pin their traces exactly like latency incidents do.
 
     ``check`` cost is a few numpy passes over the emitted samples plus
-    one bounded rFFT; ``bench.py --quality`` gates the paired overhead
-    at <= 2% of TTFA p50.
+    one bounded rFFT.
     """
 
     def __init__(

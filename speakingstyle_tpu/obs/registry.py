@@ -20,7 +20,7 @@ with the same identity returns the same object, so call sites never need
 to coordinate creation. Export surfaces:
 
   * ``registry.snapshot()`` — one nested plain dict; ``/healthz`` and
-    ``bench.py --serve`` both consume this, so there is exactly one
+    the tier-1 tests both consume this, so there is exactly one
     bookkeeping path.
   * ``registry.prometheus_text()`` — Prometheus exposition format,
     served by ``GET /metrics`` on the synthesis server.
@@ -313,8 +313,8 @@ class MetricsRegistry:
         return default if m is None else m.value
 
     def snapshot(self) -> Dict:
-        """One nested plain dict of everything: the single source both
-        ``/healthz`` and ``bench.py`` consume. Labeled metrics key as
+        """One nested plain dict of everything: the single source
+        ``/healthz`` and the tests consume. Labeled metrics key as
         ``name{k="v"}``."""
         out: Dict[str, Dict] = {"counters": {}, "gauges": {}, "histograms": {}}
         for (name, labels), m in self._items():

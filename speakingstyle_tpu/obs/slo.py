@@ -253,7 +253,7 @@ class SloEngine:
 
     def quality_alerting(self) -> Dict[str, bool]:
         """Per-class alerting state of the quality stream (the tests'
-        and bench drill's direct read)."""
+        direct read)."""
         return dict(self._q_alerting)
 
     def quality_status(self) -> Dict:

@@ -172,7 +172,7 @@ def ambient(ctx: Optional[TraceContext]) -> _AmbientContext:
 
 # process-wide tracing arm switch: context propagation is always on
 # (it is just three strings riding the request), but span *recording*
-# into the ring can be disarmed for the bench overhead ablation
+# into the ring can be disarmed (``serve.trace.enabled``)
 _tracing_enabled = True
 
 

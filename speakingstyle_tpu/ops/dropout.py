@@ -3,9 +3,9 @@
 The reference applies standard inverted dropout everywhere (reference:
 transformer/SubLayers.py:55-57, model/modules.py:383-384); the math here
 is identical — ``where(keep_mask, x / keep_prob, 0)`` with
-``P(keep) = 1 - rate`` — but mask *generation* is the knob. The r4
-breakdown measured the train-step's dropout cost at 5.0 ms (PERF.md), most
-of it RNG-bit materialization traffic, so:
+``P(keep) = 1 - rate`` — but mask *generation* is the knob. Most of the
+train step's dropout cost is RNG-bit materialization traffic (read on an
+earlier installation; no reading in PERF_LEDGER.jsonl, ROADMAP C2), so:
 
 * ``"bernoulli"`` — ``jax.random.bernoulli`` (what ``nn.Dropout`` does):
   32 random bits per element, converted to f32 uniforms, compared.

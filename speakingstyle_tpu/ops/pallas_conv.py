@@ -30,8 +30,7 @@ the conv emitter):
   ReLU mask) — all elementwise/reduction work XLA fuses;
 * dx/dw/db come from ``jax.vjp`` of the *linear* ``lax.conv`` — conv is
   linear in (x, w), so this stores nothing and recomputes nothing; XLA
-  lowers the transposed convs with the same emitter the "xla" impl uses
-  (93–140 TF/s measured, PERF.md).
+  lowers the transposed convs with the same emitter the "xla" impl uses.
 
 Gradient parity vs the composed reference:
 tests/test_ops.py::test_conv1d_impl_parity,

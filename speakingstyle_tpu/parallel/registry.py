@@ -6,8 +6,8 @@ under a donation-warning filter, a compile counter bump, a ProgramCard
 mint, per-program gauges, and (sometimes) persistent-compile-cache
 wiring: the mesh-sharded train step (training/trainer.py), the serve
 lattice (serving/engine.py), the style lattice (serving/style.py), and
-bench.py. ``ProgramRegistry`` extracts that ritual behind one guarded
-entry point:
+the benchmark script of the time. ``ProgramRegistry`` extracts that
+ritual behind one guarded entry point:
 
     (callable, mesh/sharding spec, shape bucket, donation spec)
         -> compiled executable + ProgramCard + compile governance
@@ -29,7 +29,7 @@ Governance the registry provides uniformly:
     directory: ``JAX_COMPILATION_CACHE_DIR``, else ``cache_dir`` — the
     ``train.obs.compilation_cache_dir`` override — else
     ``<checkout>/.jax_cache``) before its first compile, so every
-    consumer — serve replicas, style, bench, the trainer — restarts
+    consumer — serve replicas, style, the trainer — restarts
     warm. Hits/requests land per-registry as
     ``jax_persistent_cache_{hits,requests}_total`` in the registry's
     metrics (the ``watch_compiles`` bus bridge).
@@ -45,8 +45,7 @@ Governance the registry provides uniformly:
     readback (serving/engine.py).
 
 ``jit_program`` is the sanctioned constructor for jit-on-first-call
-wrappers (the trainer's step functions, bench micro-timers, the audio
-DSP decorators): a thin alias of ``jax.jit`` that exists so JL018 can
+wrappers (the trainer's step functions, the audio DSP decorators): a thin alias of ``jax.jit`` that exists so JL018 can
 insist the spelling ``jax.jit`` appears nowhere else in the tree.
 
 Precision is a registry concern too: ``cast_params``/``dequant_params``
@@ -244,7 +243,7 @@ def _mesh_of(sh: Any) -> Optional[str]:
 
 class ProgramRegistry:
     """Compile governance for one consumer (an engine, a style service,
-    a trainer run, a bench process).
+    a trainer run).
 
     Each registry owns: its program + card tables, a compile counter in
     the consumer's ``MetricsRegistry`` (``counter_name`` keeps the

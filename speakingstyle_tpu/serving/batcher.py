@@ -154,7 +154,7 @@ class ContinuousBatcher:
         self._closed_lock = make_lock("ContinuousBatcher._closed_lock")
         self._terminal_sent = False
         # observability: everything lives in the registry (obs/), which
-        # /metrics, /healthz, and bench.py all read from one snapshot —
+        # /metrics and /healthz both read from one snapshot —
         # occupancy/dispatched/rejected below are VIEWS of it, not
         # parallel counters
         # engines are duck-typed in tests; fall back to a private registry

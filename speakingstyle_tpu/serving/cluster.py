@@ -41,7 +41,7 @@ over HTTP (ARCHITECTURE.md "Distributed control plane"):
         already-executed batch returns the cached response instead of
         re-running the lattice), ``/healthz``, ``/drain``, and the
         heartbeat loop.  ``cli/replica.py`` wraps it around a full
-        ``SynthesisEngine``; tests and the bench wrap duck engines.
+        ``SynthesisEngine``; tests wrap duck engines.
 
 Exactly-once, across the wire: the router's claim handshake is still
 the client-facing guarantee (a stolen batch's late results are
@@ -201,7 +201,7 @@ def decode_request(d: Dict) -> SynthesisRequest:
 
 
 def encode_result(r) -> Dict:
-    """Duck-typed on purpose: test/bench engines return plain objects
+    """Duck-typed on purpose: test engines return plain objects
     with a subset of the SynthesisResult fields."""
     bucket = getattr(r, "bucket", None)
     return {

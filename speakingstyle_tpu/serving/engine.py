@@ -47,14 +47,13 @@ metrics registry (``speakingstyle_tpu/obs``):
     ``/jax/core/compile/backend_compile_duration`` event, which catches
     compiles the engine *didn't* perform (a stray ``jnp`` call on a
     novel shape in the dispatch path, say). ``CompileMonitor`` (same
-    module; re-exported here) scopes a counting window — the serve
-    smoke test and ``bench.py --serve`` assert it reads zero after
-    warmup.
+    module; re-exported here) scopes a counting window — the serving
+    tests (tests/test_serving.py) assert it reads zero after warmup.
 
 Every engine owns its own ``MetricsRegistry`` (pass one to share): the
 dispatch path records per-bucket latency histograms
-(``serve_dispatch_seconds{bucket=...}``) that ``GET /metrics``,
-``/healthz``, and ``bench.py --serve`` all read from the same snapshot.
+(``serve_dispatch_seconds{bucket=...}``) that ``GET /metrics`` and
+``/healthz`` read from the same snapshot.
 
 Every compile also mints a ``ProgramCard`` (obs/cost.py): XLA's own
 cost/memory analysis of the executable, published as per-bucket
@@ -155,7 +154,7 @@ class SynthesisRequest:
     # node in the distributed trace — None for untraced callers
     trace: Optional[TraceContext] = None
     # run this request's wav through the quality choke point
-    # (obs/quality.py); benches toggle it to measure the paired cost
+    # (obs/quality.py); False is the unchecked arm (no verdict, no counter)
     quality_check: bool = True
 
 
@@ -385,7 +384,7 @@ class SynthesisEngine:
         # compile itself runs OFF the lock (see ``_ensure_program``), so
         # a multi-second compile never parks dispatches for other
         # buckets, lease heartbeats, or anything else that brushes the
-        # engine lock (the 8.6 s p999 hold BENCH_r16 sanctioned is gone)
+        # engine lock
         self._lock = make_lock("SynthesisEngine._lock", kind="condition")
         self._compiling: set = set()
         self.fault_plan = fault_plan
@@ -404,7 +403,7 @@ class SynthesisEngine:
         # pipeline" — the allocation-free-steady-state claim)
         self.pool = BufferPool(registry=self.registry)
         # per-stage latency histograms for the pipelined hot path
-        # (bench.py --latency reads these for its stage breakdown)
+        # (/metrics exports them as the stage breakdown)
         self._acoustic_hist = self.registry.histogram(
             "serve_acoustic_seconds",
             help="stage: acoustic dispatch incl. staging, transfer, and "
@@ -1063,8 +1062,8 @@ class SynthesisEngine:
                 acoustic_done_wall = time.time()
                 wav_dev = self._vocoder_exe[(bucket.b, t)](params, mel_out)
                 # one vectorized int16 conversion for the whole batch
-                # (the per-item numpy work is what bounds coalesced
-                # throughput on the CPU bench); the finite verdict is
+                # (per-item numpy work bounds coalesced throughput on
+                # a CPU host); the finite verdict is
                 # taken on the float batch first — np.clip erases the
                 # NaN/Inf evidence the quality gate needs
                 wav_f = np.asarray(wav_dev)

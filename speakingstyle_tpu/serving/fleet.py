@@ -165,7 +165,7 @@ class FleetRouter:
         # cache, one encoder lattice — a style uploaded once is warm
         # fleet-wide. None = replicas own private services (tests).
         fault_plan: Optional[FaultPlan] = None,  # SPEAKINGSTYLE_FAULTS
-        # plan threaded in by cli/serve.py / bench --chaos; consumes the
+        # plan threaded in by cli/serve.py or a chaos test; consumes the
         # replica_raise@N / replica_hang@N kinds (N = router-global
         # dispatch counter, 1-based). None = no injection.
         tier: Optional[str] = None,  # quality-tier name when this router
@@ -733,7 +733,7 @@ class FleetRouter:
     def dispatch_total(self) -> int:
         """Router-global dispatch count so far — the counter the
         ``replica_raise@N``/``replica_hang@N`` fault kinds index
-        (``bench.py --chaos`` reads this to arm a kill that has not
+        (the chaos tests read this to arm a kill that has not
         happened yet)."""
         with self._cond:
             return self._dispatch_total

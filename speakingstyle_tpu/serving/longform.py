@@ -195,8 +195,8 @@ class Stitcher:
     equal-power sin/cos ramp (constant perceived energy through the
     join).  ``seam_rms`` records, per seam, the RMS of the
     sample-to-sample first difference across the stitched join window
-    (normalized to [-1, 1]) — the click detector the bench records and
-    gates as ``longform_seam_rms_max``.
+    (normalized to [-1, 1]) — the click detector the long-form tests
+    (tests/test_longform.py) read.
     """
 
     def __init__(self, fade_samples: int, quality_check=None):

@@ -43,7 +43,7 @@ events — one line per transition, not per round.
 
 The prober is a stop-aware background thread (``Event.wait`` as the
 timer, JL016); construct with ``start=False`` and drive ``probe_once()``
-directly from tests and the bench drill.
+directly from tests.
 """
 
 import io
@@ -350,7 +350,7 @@ class GoldenProber:
 
     def probe_once(self) -> Dict:
         """One probe round over every tier: submit, compare, publish.
-        Returns the round's summary (the bench drill reads it)."""
+        Returns the round's summary (the tests read it)."""
         self.ensure_anchors()
         qcfg = self.qcfg
         summary: Dict = {"tiers": {}, "style_drift": None}

@@ -483,7 +483,7 @@ class SynthesisServer:
         # long-form chapters (POST /synthesize/longform): the chunked
         # tier needs only the frontend + backend already in hand, so the
         # service is built by default; a ring tier rides in only when the
-        # caller wires one explicitly (cli/serve.py, bench) via the
+        # caller wires one explicitly (cli/serve.py) via the
         # ``longform`` ctor arg — it needs its own seq-mesh programs
         if longform is None and frontend is not None:
             from speakingstyle_tpu.serving.longform import LongformService

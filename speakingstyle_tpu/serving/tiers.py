@@ -8,7 +8,7 @@ precision lattice's cheaper programs over the same weights, and
 (training/distill.py) registered as a second model version. Each tier
 is a full ``FleetRouter`` (or ``ClusterRouter``) whose engines compile
 the lattice at the tier's precision; the ``TierRouter`` facade in front
-of them is what the HTTP server and bench talk to, so "mixed-tier fleet
+of them is what the HTTP server talks to, so "mixed-tier fleet
 behind one router" is literally one object with the router surface.
 
 The quality door is the PR-13 canary discipline re-aimed: before a tier
@@ -24,8 +24,7 @@ degrades in quality budget, never in availability.
 Metrics: ``serve_tier_dispatch_total{tier=}`` counts routed submits per
 tier, ``serve_tier_canary_total{tier=,outcome=}`` counts gate verdicts,
 and ``serve_tier_mel_l2{tier=}`` gauges each shipped tier's measured
-golden-set distance — the numbers ``bench.py --tiers`` turns into the
-quality-vs-speed frontier artifact.
+golden-set distance — the two axes of the quality-vs-speed frontier.
 """
 
 import time

@@ -15,9 +15,9 @@ by inhomogeneous-Poisson thinning from a single seeded generator. The
 model is DETERMINISTIC: the same constructor arguments produce the
 identical schedule, every time, on every host — so a capacity artifact
 recorded from seed 0 is reproducible, and a regression in shed/scale
-behavior cannot hide behind workload noise. ``bench.py --traffic``
-replays a schedule against a live autoscaled fleet; the tests replay it
-against the clock-free policy surface.
+behavior cannot hide behind workload noise. The tests
+(tests/test_traffic.py) replay a schedule against the clock-free policy
+surface.
 
 Host-only by design (numpy for the RNG, no jax): building a schedule
 must never touch a device or compile anything.

@@ -12,11 +12,10 @@ eps=1e-9) -> schedule; grad accumulation via optax.MultiSteps.
 ``train.fused_optimizer`` swaps in ``make_fused_optimizer``: the same math
 as one fused pass over a single raveled gradient vector. The hypothesis
 was that the optax chain's ~200 leaves x 4 stages of per-leaf fusions
-(5.4 ms/step at 35M params on v5e, ~1.5 ms of it intrinsic HBM traffic)
-could be collapsed — but the measured end-to-end result is NEGATIVE: the
-ravel/unravel copies cost more than the chain overhead they remove
-(422.6k vs 442.8k frames/s, PERF.md). Kept as an honest A/B knob, off by
-default. Update parity with the chain is pinned by
+could be collapsed — but the end-to-end result was NEGATIVE: the
+ravel/unravel copies cost more than the chain overhead they remove (read
+on an earlier installation; no reading in PERF_LEDGER.jsonl, ROADMAP
+C2). Off by default. Update parity with the chain is pinned by
 tests/test_training.py::test_fused_optimizer_matches_chain.
 """
 
@@ -127,7 +126,7 @@ def make_leaf_fused_optimizer(train_cfg: TrainConfig) -> optax.GradientTransform
     materialized intermediate update trees.
 
     This is the middle ground the r4 "flat" variant missed: no
-    ravel/unravel copies (the flat impl's downfall, PERF.md), but also no
+    ravel/unravel copies (the flat impl's downfall), but also no
     per-stage HBM round trips. Update math is identical to the chain —
     pinned by tests/test_training.py::test_fused_optimizer_matches_chain —
     and the state layout (count + mu/nu trees) mirrors scale_by_adam's, so

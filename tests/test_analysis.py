@@ -1063,12 +1063,6 @@ def test_jl018_positive_from_import_and_aot_chain():
         def build(fn, args):
             return fn.lower(*args).compile()
     """, path="speakingstyle_tpu/training/fake.py")
-    assert "JL018" in _codes("""
-        import jax
-
-        def build(fn):
-            return jax.jit(fn)
-    """, path="bench.py")
 
 
 def test_jl018_negative_registry_and_out_of_scope():
@@ -1082,9 +1076,11 @@ def test_jl018_negative_registry_and_out_of_scope():
     assert "JL018" not in _codes(
         src, path="speakingstyle_tpu/parallel/registry.py"
     )
-    # tests/scripts are fixtures, not production programs
+    # tests/scripts are fixtures, not production programs; a root-level
+    # script is no more enforced than they are
     assert "JL018" not in _codes(src, path="tests/fake.py")
     assert "JL018" not in _codes(src, path="scripts/fake.py")
+    assert "JL018" not in _codes(src, path="tool.py")
 
 
 def test_jl018_negative_precompile_exempt():
@@ -1667,8 +1663,11 @@ def test_every_rule_is_non_vacuous():
     # spellings in the tree (the rule exists to keep every future cast
     # inside that choke point), and test_jl025_tree_baseline_is_zero
     # pins the out-of-band count at zero.
+    # JL008 is absent since the three micro-benchmark scripts that
+    # compiled inside their sweep loops were deleted: nothing in the
+    # tree builds a program per loop iteration any more.
     for code in ("JL001", "JL002", "JL003", "JL004", "JL005", "JL006",
-                 "JL007", "JL008"):
+                 "JL007"):
         assert code in fired, f"{code} never fires on the real tree"
 
 

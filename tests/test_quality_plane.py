@@ -665,7 +665,7 @@ def test_engine_run_choke_point_attaches_the_verdict(tiny_engine):
     assert reg.value("serve_quality_checks_total",
                      {"class": "interactive", "tier": "default",
                       "source": "engine"}) >= 1
-    # quality_check=False is the bench's unchecked arm: no verdict,
+    # quality_check=False is the unchecked arm: no verdict,
     # no counter motion
     before = reg.value("serve_quality_class_total",
                        {"class": "interactive"})
