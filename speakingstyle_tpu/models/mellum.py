@@ -21,7 +21,13 @@ and the loss are over that slice.
 **Memory.** Parameters are float32, compute is ``compute_dtype``; softmax,
 router, norms' statistics and the loss are float32. Each
 layer keeps two residual-stream arrays for the backward pass and recomputes
-its two halves: attention as one block, the experts one batch row at a time
+its two halves: attention as one block but for the core's two results, the
+output ``[B, H, T, D]`` and the rows' log-sum-exp ``[B, H, 1, T]`` float32,
+which are kept by name (``KEEP_CORE``), so the backward never runs the
+forward kernel again (norm, projections, rotary and transposes are
+recomputed: ``q``, ``k``, ``v`` are not kept; off a TPU the einsum reference
+has no such names and everything is recomputed); the experts one batch row
+at a time
 (a row's worst case, every pair held here, sizes the dispatch buffers'
 shapes; the work over them follows the tiles the row's plan uses:
 ``ops/expert_dispatch.py``). The logits stand ``LOSS_CHUNK`` positions at a
@@ -43,7 +49,8 @@ import numpy as np
 
 from speakingstyle_tpu.configs.config import DecoderLMConfig, RopeConfig
 from speakingstyle_tpu.ops import expert_dispatch
-from speakingstyle_tpu.ops.blocked_attention import blocked_attention
+from speakingstyle_tpu.ops.blocked_attention import (
+    LSE_NAME, OUT_NAME, blocked_attention)
 from speakingstyle_tpu.ops.grouped_matmul import TILE_ROWS, grouped_matmul
 
 # positions whose float32 logits stand at once (fewer where a batch has fewer)
@@ -248,6 +255,9 @@ class SparseMoE(nn.Module):
                      used, jnp.asarray(worst, jnp.int32), idx)
 
 
+KEEP_CORE = jax.checkpoint_policies.save_only_these_names(OUT_NAME, LSE_NAME)
+
+
 class DecoderLayer(nn.Module):
     cfg: DecoderLMConfig
     window: int
@@ -255,8 +265,11 @@ class DecoderLayer(nn.Module):
 
     @nn.compact
     def __call__(self, x, cos, sin):
-        h = x + nn.remat(SelfAttention)(
-            self.cfg, self.window, self.dtype, name="self_attn")(x, cos, sin)
+        # recomputed backward but for the core's output and log-sum-exp,
+        # which are kept: the forward kernel runs once a step
+        attn = nn.remat(SelfAttention, policy=KEEP_CORE)
+        h = x + attn(self.cfg, self.window, self.dtype, name="self_attn")(
+            x, cos, sin)
         out, aux = SparseMoE(self.cfg, name="moe")(h)
         return h + out, aux
 

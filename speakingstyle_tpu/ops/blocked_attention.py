@@ -27,7 +27,10 @@ Only the mask-partial blocks (the diagonal, the window's far edge) pay for
 the mask.
 
 Off a TPU the plain ``einsum`` reference below runs instead
-(``interpret=True`` emulates the kernels: the parity tests).
+(``interpret=True`` emulates the kernels: the parity tests). The reference
+carries no names and keeps nothing across a rematerialisation: it is
+differentiated by JAX, and a policy that names ``OUT_NAME`` and ``LSE_NAME``
+finds neither there.
 """
 
 import functools
@@ -36,6 +39,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -47,6 +51,12 @@ BLOCK = 512
 # the diagonal block comes last and wipes what such a row gathered
 NEG = -0.7 * float(jnp.finfo(jnp.float32).max)
 _NT = (((1,), (1,)), ((), ()))  # a @ b.T
+# what the forward kernel hands the backward, by name: a caller that
+# rematerialises around the kernel keeps the two with
+# ``jax.checkpoint_policies.save_only_these_names(OUT_NAME, LSE_NAME)`` and
+# its backward runs the forward kernel no second time (models/mellum.py)
+OUT_NAME = "blocked_attention_out"
+LSE_NAME = "blocked_attention_lse"
 
 
 def reference_attention(q, k, v, window: Optional[int], sm_scale: float):
@@ -319,6 +329,10 @@ def _blocked(q, k, v, window, sm_scale, blk, interpret):
 
 def _blocked_fwd(q, k, v, window, sm_scale, blk, interpret):
     o, lse = _forward(q, k, v, window, sm_scale, blk, interpret)
+    # named before they enter the residuals: what the backward reads are the
+    # named values (identities outside a ``jax.checkpoint`` whose policy
+    # names them)
+    o, lse = checkpoint_name(o, OUT_NAME), checkpoint_name(lse, LSE_NAME)
     return o, (q, k, v, o, lse)
 
 

@@ -247,6 +247,95 @@ def test_blocked_attention_is_einsum_attention(length, window):
         assert float(jnp.max(jnp.abs(x - y))) < 1e-4
 
 
+def _toy_layer(block, window, monkeypatch, interpret, keep):
+    """A ``DecoderLayer`` at toy size under the model's own ``nn.remat`` call
+    (grouped queries 4 to 2, 40 positions in a block of 128: a length that is
+    no multiple of the block) -> (its loss over params and input, the two).
+    ``keep`` false leaves the policy out (plain ``nn.remat``). Every call
+    makes new function objects: ``jax.checkpoint`` caches a traced function
+    by its identity."""
+    real = blocked_attention
+    monkeypatch.setattr(mellum, "blocked_attention",
+                        lambda *a, **kw: real(*a, interpret=interpret, **kw))
+    if not keep:
+        monkeypatch.setattr(mellum, "KEEP_CORE", None)
+    cfg = _build(DecoderLMConfig, block)
+    layer = mellum.DecoderLayer(cfg, window, jnp.float32)
+    cos, sin = mellum.rope_tables(cfg.rope_parameters.sliding_attention,
+                                  cfg.head_dim, 40)
+    x = jnp.asarray(np.random.default_rng(window).standard_normal(
+        (2, 40, cfg.hidden_size)), jnp.float32)
+    params = layer.init(jax.random.PRNGKey(0), x, cos, sin)
+
+    def loss(p, x):
+        y, _ = layer.apply(p, x, cos, sin)
+        return jnp.sum(jnp.square(y))
+
+    return loss, params, x
+
+
+def _count(jaxpr, name, rematerialised=False):
+    """(equations of primitive ``name`` in ``jaxpr`` and all it holds, those
+    of them inside a rematerialised region)."""
+    total = inside = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == name:
+            total += 1
+            inside += rematerialised
+        below = rematerialised or eqn.primitive.name == "remat2"
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    n, m = _count(sub, name, below)
+                    total, inside = total + n, inside + m
+    return total, inside
+
+
+def _live_jaxpr(fn, *args):
+    from jax.interpreters import partial_eval as pe
+
+    closed = jax.make_jaxpr(fn)(*args)
+    return pe.dce_jaxpr(closed.jaxpr, [True] * len(closed.jaxpr.outvars))[0]
+
+
+@pytest.mark.parametrize("keep,calls,again", [(True, 3, 2), (False, 4, 3)],
+                         ids=["core_kept", "plain_remat"])
+@pytest.mark.parametrize("window", [0, 8], ids=["full", "window_8"])
+def test_a_layers_backward_runs_the_forward_kernel_once(toy, monkeypatch, window,
+                                                        keep, calls, again):
+    """With the core's output and log-sum-exp kept across the remat boundary
+    a layer's loss and gradient launch three kernels (forward, ``dq``,
+    ``dk``/``dv``) and the rematerialised region holds the two backward ones
+    alone; with the policy left out (the control: the count can tell) the
+    forward stands there a second time."""
+    loss, params, x = _toy_layer(toy[0], window, monkeypatch, True, keep)
+    live = _live_jaxpr(jax.value_and_grad(loss, (0, 1)), params, x)
+    assert _count(live, "pallas_call") == (calls, again)
+    # kept, the two names stand beside the first forward and nowhere else
+    assert (_count(live, "name") == (2, 0)) == keep
+
+
+@pytest.mark.parametrize("interpret", [True, None], ids=["interpreted", "jnp"])
+@pytest.mark.parametrize("window", [0, 8], ids=["full", "window_8"])
+def test_keeping_the_core_changes_no_gradient(toy, monkeypatch, window, interpret):
+    """The kept values are what the second forward would have written, bit
+    for bit: loss and every gradient with the policy equal those under plain
+    ``nn.remat`` exactly. Off a TPU (``interpret=None`` here: the einsum
+    reference) nothing carries a name, nothing is kept, and the same holds."""
+    loss, params, x = _toy_layer(toy[0], window, monkeypatch, interpret, True)
+    kept = jax.value_and_grad(loss, (0, 1))(params, x)
+    if interpret is None:
+        live = _live_jaxpr(jax.value_and_grad(loss, (0, 1)), params, x)
+        assert _count(live, "name") == _count(live, "pallas_call") == (0, 0)
+    loss, params, x = _toy_layer(toy[0], window, monkeypatch, interpret, False)
+    plain = jax.value_and_grad(loss, (0, 1))(params, x)
+    for a, b in zip(jax.tree_util.tree_leaves(kept), jax.tree_util.tree_leaves(plain)):
+        np.testing.assert_array_equal(a, b)
+    assert all(float(jnp.max(jnp.abs(g))) > 0
+               for g in jax.tree_util.tree_leaves(kept[1]))
+
+
 @pytest.mark.parametrize("interpret", [None, True], ids=["jnp", "interpreted"])
 @pytest.mark.parametrize("lo", [0, 2, 5])
 def test_grouped_product_is_a_loop_over_the_held_experts(interpret, lo):

@@ -51,6 +51,43 @@ def test_blocked_attention_compiles_at_the_cells_shapes(one_chip, no_compile_cac
     assert compiled.as_text().count("tpu_custom_call") >= 3   # fwd, dq, dk/dv
 
 
+@pytest.mark.parametrize("window", [0, 1024], ids=["full", "window_1024"])
+def test_attention_half_keeps_the_core_at_the_cells_shapes(one_chip, no_compile_cache,
+                                                           monkeypatch, window):
+    """The cell's attention half (norm, projections, rotary, core, ``o_proj``)
+    at ``[4, 8192, 2304]`` under the model's rematerialisation policy, forward
+    and backward: three kernels in the compiled step, not four (the forward
+    kernel's two results are kept, so it is not launched again)."""
+    import flax.linen as nn
+
+    from speakingstyle_tpu.configs.config import load_config
+    from speakingstyle_tpu.models import mellum
+
+    real = mellum.blocked_attention
+    # the backend here is the CPU: compile the kernels, as the chip would
+    monkeypatch.setattr(mellum, "blocked_attention",
+                        lambda *a, **kw: real(*a, interpret=False, **kw))
+    cfg = load_config(preset="Mellum2-12B-A2.5B").model.decoder_lm
+    half = nn.remat(mellum.SelfAttention, policy=mellum.KEEP_CORE)(
+        cfg, window, jnp.bfloat16)
+    cos, sin = mellum.rope_tables(cfg.rope_parameters.sliding_attention,
+                                  cfg.head_dim, 8192)
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree)
+
+    x = jax.ShapeDtypeStruct((4, 8192, cfg.hidden_size), jnp.bfloat16)
+    params = jax.eval_shape(half.init, jax.random.PRNGKey(0), x, cos, sin)
+
+    def loss(p, x, cos, sin):
+        return jnp.sum(half.apply(p, x, cos, sin).astype(jnp.float32))
+
+    compiled = jax.jit(jax.value_and_grad(loss, (0, 1))).lower(
+        *on_chip((params, x, cos, sin))).compile()
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 3
+
+
 @pytest.mark.parametrize("k,n", [(2304, 896), (896, 2304)], ids=["gate_up", "down"])
 def test_grouped_product_compiles_at_the_cells_shapes(one_chip, no_compile_cache, k, n):
     from speakingstyle_tpu.ops.grouped_matmul import TILE_ROWS, grouped_matmul
