@@ -199,8 +199,11 @@ class DecoderLMConfig:
     """The ``decoder_lm`` family (models/mellum.py): a pre-norm decoder of
     RMSNorm, grouped-query rotary attention (a window or the full causal
     triangle, by layer) and a sparse-expert feed-forward, trained on
-    next-token cross-entropy. The keys a published ``config.json`` has keep
-    its names and defaults (Mellum2-12B-A2.5B); the rest say what this chip
+    next-token cross-entropy or (``objective: block_diffusion``) to fill in
+    a noised block of ``block_length`` tokens given the clean blocks before
+    it. The keys a published ``config.json`` has keep
+    its names and defaults (Mellum2-12B-A2.5B); the rest say what the
+    objective is, what this chip
     holds of an expert-parallel deployment and what a row of the data is.
     ``layer_types`` may be longer than ``num_hidden_layers`` (a depth cut
     keeps the published list whole and runs its head)."""
@@ -231,6 +234,16 @@ class DecoderLMConfig:
     tie_word_embeddings: bool = False
     rope_parameters: RopeParametersConfig = field(
         default_factory=RopeParametersConfig)
+    # -- the objective -------------------------------------------------------
+    # "next_token", or "block_diffusion": every row runs as a noised copy and
+    # the clean copy side by side (2 * seq_len positions) under one
+    # block-structured mask, and the loss is the weighted cross-entropy of
+    # the masked positions of the noised half (models/mellum.py). The loader
+    # draws the noise: ``mask_id`` stands where a token is masked.
+    objective: str = "next_token"
+    block_length: int = 4
+    mask_id: int = 0
+    qk_norm: bool = False           # RMSNorm over each head's query and key
     # -- what this chip holds (0: everything) --------------------------------
     expert_offset: int = 0          # the first expert held here
     experts_held: int = 0           # how many, from expert_offset on
@@ -264,6 +277,21 @@ class DecoderLMConfig:
                 f"are not among the router's {self.num_experts}")
         if not 0 <= self.vocab_held <= self.vocab_size:
             raise ValueError("vocab_held must lie within vocab_size")
+        if self.objective not in ("next_token", "block_diffusion"):
+            raise ValueError("objective must be next_token|block_diffusion, "
+                             f"got {self.objective}")
+        if self.block_diffusion:
+            if self.block_length < 1 or self.seq_len % self.block_length:
+                raise ValueError(f"rows of {self.seq_len} tokens are no whole "
+                                 f"number of blocks of {self.block_length}")
+            if not 0 <= self.mask_id < self.n_vocab_held:
+                raise ValueError("mask_id must be a held vocabulary row")
+            if any(k != "full_attention" for k in self.layer_types[:n]):
+                raise ValueError("block diffusion runs full_attention layers")
+
+    @property
+    def block_diffusion(self) -> bool:
+        return self.objective == "block_diffusion"
 
     @property
     def n_experts_held(self) -> int:

@@ -132,9 +132,10 @@ def init_variables(model: FastSpeech2, cfg: Config, rng: jax.Array):
     batch (decoder_lm: a row of a few ids; parameters only, and under jit,
     so that nothing but the initializers runs on the device)."""
     if cfg.model.family == "decoder_lm":
+        from speakingstyle_tpu.models.mellum import dummy_inputs
         from speakingstyle_tpu.parallel.registry import jit_program
 
-        return jit_program(model.init)(rng, jnp.zeros((1, 8), jnp.int32))
+        return jit_program(model.init)(rng, **dummy_inputs(cfg.model.decoder_lm))
     n_mels = cfg.preprocess.preprocessing.mel.n_mel_channels
     B, L, T = 2, 8, 16
     dummy = dict(

@@ -1,13 +1,31 @@
 """The ``decoder_lm`` family: a pre-norm decoder language model with
 grouped-query rotary attention (a window or the full causal triangle, by
 layer) and a sparse-expert feed-forward that is told which experts it holds
-(Mellum2-12B-A2.5B's block; ``configs/config.py:DecoderLMConfig``).
+(Mellum2-12B-A2.5B's block, SDAR-30B-A3B's; ``configs/config.py:DecoderLMConfig``).
 
     x_0 = E[ids]
     h   = x + Attn(RMSNorm(x))          self_attn  (ops/blocked_attention.py)
     y   = h + MoE(RMSNorm(h))           moe        (ops/grouped_matmul.py)
     loss = mean cross-entropy of id t+1 given ids <= t, over the held
            vocabulary rows, after a final RMSNorm and an untied head
+
+**The second objective, ``block_diffusion``** (Arriola et al.,
+arXiv:2503.09573, as SDAR, arXiv:2510.06303, trains): the decoder learns to
+fill in a block of ``block_length`` tokens given the clean blocks before it.
+A row of ``L`` tokens goes through the layers twice at once, as
+``ids = [noised ; tokens]`` of ``2L`` positions: the loader's noised copy
+(``mask_id`` where a token is masked) and the clean copy, a token's two
+copies at one rotary position, under one mask in which a noised block sees
+itself and the clean blocks before it and a clean block the clean blocks up
+to itself (``blocked_attention``'s ``BlockDiffusion``). Every layer runs
+over ``2L`` positions; the head runs on the noised half only, with no shift:
+
+    loss = 1 / (B L) sum_{b,i} weight_{b,i} CE(logits_{b,i}, tokens_{b,i})
+
+``weight`` is the loader's: ``1 / t`` where position ``i`` is masked (``t``
+its block's masking probability), else 0. ``qk_norm`` puts an RMSNorm with
+one learned scale of ``head_dim`` on each head's query and key before the
+rotation.
 
 **Expert-parallel share.** The router scores all ``num_experts`` and takes
 the ``num_experts_per_tok`` largest; the layer computes the part of the sum
@@ -50,7 +68,7 @@ import numpy as np
 from speakingstyle_tpu.configs.config import DecoderLMConfig, RopeConfig
 from speakingstyle_tpu.ops import expert_dispatch
 from speakingstyle_tpu.ops.blocked_attention import (
-    LSE_NAME, OUT_NAME, blocked_attention)
+    LSE_NAME, OUT_NAME, BlockDiffusion, blocked_attention)
 from speakingstyle_tpu.ops.grouped_matmul import TILE_ROWS, grouped_matmul
 
 # positions whose float32 logits stand at once (fewer where a batch has fewer)
@@ -147,6 +165,9 @@ class SelfAttention(nn.Module):
         q = _dense(H * D, "q_proj", self.dtype)(u).reshape(B, T, H, D)
         k = _dense(Hkv * D, "k_proj", self.dtype)(u).reshape(B, T, Hkv, D)
         v = _dense(Hkv * D, "v_proj", self.dtype)(u).reshape(B, T, Hkv, D)
+        if c.qk_norm:
+            q = RMSNorm(c.rms_norm_eps, name="q_norm")(q)
+            k = RMSNorm(c.rms_norm_eps, name="k_norm")(k)
         q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
         # the attention core, under a name of its own (the per-module
         # readers hold on to ``self_attn/core``, whatever implements it)
@@ -154,7 +175,9 @@ class SelfAttention(nn.Module):
             o = blocked_attention(
                 q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
                 v.transpose(0, 2, 1, 3), window=self.window or None,
-                sm_scale=1.0 / math.sqrt(D))
+                sm_scale=1.0 / math.sqrt(D),
+                **({"mask": BlockDiffusion(c.block_length)}
+                   if c.block_diffusion else {}))
             o = o.transpose(0, 2, 1, 3).reshape(B, T, H * D)
         return _dense(c.hidden_size, "o_proj", self.dtype, writes_std(c))(o)
 
@@ -285,6 +308,25 @@ def next_token_loss(hidden, head, tokens, chunk: int = LOSS_CHUNK):
     weight = jnp.concatenate(
         [jnp.ones((B, T - 1), jnp.float32), jnp.zeros((B, 1), jnp.float32)],
         axis=1).reshape(n)
+    return weighted_cross_entropy(hidden, head, targets, weight, chunk) \
+        / (B * (T - 1))
+
+
+def block_diffusion_loss(hidden, head, tokens, weight, chunk: int = LOSS_CHUNK):
+    """``1 / (B L)`` times the sum over the noised half's positions of
+    ``weight`` times the cross-entropy of that position's own token (no
+    shift) from ``hidden`` ``[B, L, d]``."""
+    B, L, _ = hidden.shape
+    return weighted_cross_entropy(
+        hidden, head, tokens.reshape(-1),
+        weight.astype(jnp.float32).reshape(-1), chunk) / (B * L)
+
+
+def weighted_cross_entropy(hidden, head, targets, weight, chunk: int):
+    """The sum over the ``n = B T`` positions of ``weight`` times
+    ``logsumexp(hidden W) - (hidden W)[target]``, in chunks."""
+    B, T, d = hidden.shape
+    n = B * T
     chunk = min(chunk, n)
     pad = -n % chunk
     flat = jnp.pad(hidden.reshape(n, d), ((0, pad), (0, 0)))
@@ -302,48 +344,89 @@ def next_token_loss(hidden, head, tokens, chunk: int = LOSS_CHUNK):
     sums = jax.lax.map(part, (flat.reshape(-1, chunk, d),
                               targets.reshape(-1, chunk),
                               weight.reshape(-1, chunk)))
-    return jnp.sum(sums) / (B * (T - 1))
+    return jnp.sum(sums)
 
 
 class LMHead(nn.Module):
     vocab: int
 
     @nn.compact
-    def __call__(self, hidden, tokens):
+    def __call__(self, hidden, tokens, weight=None):
         head = self.param("kernel", nn.initializers.normal(INIT_STD),
                           (hidden.shape[-1], self.vocab), jnp.float32)
-        return next_token_loss(hidden, head, tokens)
+        if weight is None:
+            return next_token_loss(hidden, head, tokens)
+        return block_diffusion_loss(hidden, head, tokens, weight)
+
+
+def noised_half(x):
+    """What the final norm and the head see of the ``[B, 2L, d]`` stream
+    under block diffusion: the head never runs on the clean half."""
+    return x[:, :x.shape[1] // 2]
+
+
+def batch_inputs(arrays) -> dict:
+    """What ``DecoderLM`` takes of a batch's arrays, by name: the one place
+    the training step and the validation loss read a ``TokenBatch``."""
+    return {k: arrays[k] for k in ("tokens", "noised", "weight") if k in arrays}
+
+
+def dummy_inputs(cfg: DecoderLMConfig) -> dict:
+    """A row of a few ids: enough to run the initializers (under block
+    diffusion one lane tile of whole blocks: the kernels pad no stream)."""
+    if not cfg.block_diffusion:
+        return {"tokens": jnp.zeros((1, 8), jnp.int32)}
+    tokens = jnp.zeros((1, math.lcm(128, cfg.block_length)), jnp.int32)
+    return {"tokens": tokens, "noised": tokens,
+            "weight": jnp.ones(tokens.shape, jnp.float32)}
 
 
 class DecoderLM(nn.Module):
-    """tokens ``[B, T]`` int32 -> (loss, aux). ``aux``: ``expert_counts``
+    """tokens ``[B, T]`` int32 (under ``block_diffusion`` also ``noised``
+    ``[B, T]`` int32 and ``weight`` ``[B, T]`` float32, the loader's) ->
+    (loss, aux). ``aux``: ``expert_counts``
     ``[layers, experts_held]``, ``pairs_routed``, ``pairs_placed``,
     ``tiles_used`` and ``tiles_worst`` ``[layers]``, and the router's
-    ``choices`` ``[layers, B, T, k]``."""
+    ``choices`` ``[layers, B, T, k]`` (``2T`` positions under
+    ``block_diffusion``, which adds ``tokens_masked`` and ``loss_weight``:
+    the batch's masked positions and the sum of their weights)."""
 
     cfg: DecoderLMConfig
     dtype: jnp.dtype = jnp.bfloat16
 
     @nn.compact
-    def __call__(self, tokens):
+    def __call__(self, tokens, noised=None, weight=None):
         c = self.cfg
         T = tokens.shape[1]
+        if c.block_diffusion and (noised is None or weight is None):
+            raise ValueError("block_diffusion needs the loader's noised and weight")
+        ids = jnp.concatenate([noised, tokens], axis=1) if c.block_diffusion \
+            else tokens
         x = nn.Embed(c.n_vocab_held, c.hidden_size, dtype=self.dtype,
                      param_dtype=jnp.float32, name="embed",
-                     embedding_init=nn.initializers.normal(1.0))(tokens)
+                     embedding_init=nn.initializers.normal(1.0))(ids)
         tables = {kind: rope_tables(getattr(c.rope_parameters, kind),
                                     c.head_dim, T)
                   for kind in set(c.layer_types[:c.num_hidden_layers])}
+        if c.block_diffusion:  # a token's two copies carry one position
+            tables = {kind: tuple(jnp.tile(t, (2, 1)) for t in pair)
+                      for kind, pair in tables.items()}
         aux = []
         for i, kind in enumerate(c.layer_types[:c.num_hidden_layers]):
             window = c.sliding_window if kind == "sliding_attention" else 0
             x, a = DecoderLayer(c, window, self.dtype, name=f"layers_{i}")(
                 x, *tables[kind])
             aux.append(a)
-        hidden = RMSNorm(c.rms_norm_eps, name="final_norm")(x)
-        loss = LMHead(c.n_vocab_held, name="lm_head")(hidden, tokens)
         counts, routed, placed, used, worst, choices = (
             jnp.stack(v) for v in zip(*aux))
-        return loss, {"expert_counts": counts, "pairs_routed": routed,
-                      "pairs_placed": placed, "tiles_used": used,
-                      "tiles_worst": worst, "choices": choices}
+        out = {"expert_counts": counts, "pairs_routed": routed,
+               "pairs_placed": placed, "tiles_used": used,
+               "tiles_worst": worst, "choices": choices}
+        if c.block_diffusion:
+            x = noised_half(x)
+            out["tokens_masked"] = jnp.sum(weight > 0)
+            out["loss_weight"] = jnp.sum(weight.astype(jnp.float32))
+        hidden = RMSNorm(c.rms_norm_eps, name="final_norm")(x)
+        loss = LMHead(c.n_vocab_held, name="lm_head")(
+            hidden, tokens, weight if c.block_diffusion else None)
+        return loss, out

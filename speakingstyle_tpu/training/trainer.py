@@ -24,6 +24,7 @@ from speakingstyle_tpu import obs
 from speakingstyle_tpu.analysis import contracts
 from speakingstyle_tpu.configs.config import Config
 from speakingstyle_tpu.models.loss import fastspeech2_loss
+from speakingstyle_tpu.models.mellum import batch_inputs as lm_inputs
 from speakingstyle_tpu.obs.trace import new_context
 from speakingstyle_tpu.parallel.registry import ProgramRegistry, jit_program
 from speakingstyle_tpu.training import faults, resilience
@@ -135,12 +136,13 @@ def make_train_step(model, tx, cfg: Config, mesh=None, state_shardings=None):
         return losses["total_loss"], (losses, updates["batch_stats"])
 
     def lm_loss(params, state, arrays, rng):
-        # next-token cross-entropy over the held vocabulary rows; the
-        # routing's counts ride back beside it (``_moe``: read by the host
+        # the family's loss over the held vocabulary rows (next-token
+        # cross-entropy, or the masked-diffusion loss on the loader's noise);
+        # the routing's counts ride back beside it (``_moe``: read by the host
         # at the log boundary only, as the sentinel is) and so do the
         # router's choices (``_choices``: the loop never reads them)
         contracts.assert_rank(arrays["tokens"], 2, "train_step.tokens")
-        loss, aux = model.apply({"params": params}, arrays["tokens"])
+        loss, aux = model.apply({"params": params}, **lm_inputs(arrays))
         choices = aux.pop("choices")
         return loss, ({"total_loss": loss, "_moe": aux, "_choices": choices},
                       state.batch_stats)
@@ -197,7 +199,7 @@ def make_eval_step(model, cfg: Config, mesh=None, state_shardings=None):
 
     def eval_fn(state: TrainState, arrays: Dict) -> Dict:
         if cfg.model.family == "decoder_lm":
-            loss, _ = model.apply({"params": state.params}, arrays["tokens"])
+            loss, _ = model.apply({"params": state.params}, **lm_inputs(arrays))
             return {"total_loss": loss}
         out = model.apply(
             {"params": state.params, "batch_stats": state.batch_stats},
@@ -305,12 +307,22 @@ _MOE_WINDOW_COUNTERS = {
 }
 
 
-def _window_totals(registry, moe: bool = False) -> Dict[str, float]:
+# the block-diffusion objective's, fed beside them: the positions the
+# loader masked and the sum of their loss weights
+_DIFFUSION_WINDOW_COUNTERS = {
+    "tokens_masked": "train_tokens_masked_total",
+    "loss_weight": "train_loss_weight_total",
+}
+
+
+def _window_totals(registry, moe: bool = False,
+                   diffusion: bool = False) -> Dict[str, float]:
     """The sums behind a ``train_step`` event's window fields, as they
     stand now; a window's share is the difference of two readings."""
     out = {field: registry.histogram(name).sum
            for field, name in _WINDOW_HISTOGRAMS.items()}
-    counters = {**_WINDOW_COUNTERS, **(_MOE_WINDOW_COUNTERS if moe else {})}
+    counters = {**_WINDOW_COUNTERS, **(_MOE_WINDOW_COUNTERS if moe else {}),
+                **(_DIFFUSION_WINDOW_COUNTERS if diffusion else {})}
     out.update((field, registry.value(name)) for field, name in counters.items())
     return out
 
@@ -350,6 +362,15 @@ def _count_routing(registry, pending: list, parent) -> None:
     most.inc(counts.max(axis=2).sum().item())
     mean.inc(counts.mean(axis=2).sum().item())
     tiles.inc(sum(aux["tiles_used"].sum().item() for aux in fetched))
+    if "tokens_masked" in fetched[0]:  # the block-diffusion objective's two
+        registry.counter(
+            "train_tokens_masked_total",
+            help="positions of the noised stream the loader masked",
+        ).inc(sum(aux["tokens_masked"].item() for aux in fetched))
+        registry.counter(
+            "train_loss_weight_total",
+            help="the sum of the masked positions' loss weights (1 / t)",
+        ).inc(sum(aux["loss_weight"].item() for aux in fetched))
     obs.Span.record(
         "moe_load", time.time(), 0.0, parent=parent,
         tokens_max=counts[-1].max(axis=1).tolist(),
@@ -588,14 +609,16 @@ def run_training(
     # ids) and how samples become batches (bucket padding, packing); cache,
     # budget, fetch spans, quarantine and prefetcher are the same
     lm = cfg.model.family == "decoder_lm"
+    diffusion = lm and cfg.model.decoder_lm.block_diffusion
     dataset_cls = TokenDataset if lm else SpeechDataset
 
     def make_batcher(ds, seed: int, reg, quarantine=None):
         if lm:
+            m = cfg.model.decoder_lm
             return PackedBatcher(
-                ds, cfg.model.decoder_lm.seq_len, cfg.model.decoder_lm.eod_id,
-                seed=seed, quarantine=quarantine, registry=reg,
-                trace_parent=run_ctx)
+                ds, m.seq_len, m.eod_id, seed=seed, quarantine=quarantine,
+                registry=reg, trace_parent=run_ctx,
+                noise=(m.block_length, m.mask_id) if m.block_diffusion else None)
         return BucketedBatcher(
             ds, max_src=max_src, max_mel=max_mel,
             batch_pad_multiple=pad_mult, seed=seed, quarantine=quarantine,
@@ -725,7 +748,7 @@ def run_training(
     # the four main-thread spans inside it are disjoint, so their sum can
     # not pass the window's wall time
     window_t0, window_step0 = time.monotonic(), step
-    window_totals = _window_totals(registry, lm)
+    window_totals = _window_totals(registry, lm, diffusion)
     moe_pending = []  # each step's routing counts, read at the log boundary
     trace_active = False
     shutdown = resilience.GracefulShutdown()
@@ -826,7 +849,7 @@ def run_training(
                         step = int(state.step)  # jaxlint: disable=JL004
                         prefetch = make_stream(guard.count)
                         window_t0, window_step0 = time.monotonic(), step
-                        window_totals = _window_totals(registry, lm)
+                        window_totals = _window_totals(registry, lm, diffusion)
                         continue
                     t_log = time.monotonic()
                     with obs.Span("train_log", registry=registry):
@@ -853,7 +876,7 @@ def run_training(
                             dt = t_log - window_t0
                             timing = None
                             if n_window > 0:
-                                totals = _window_totals(registry, lm)
+                                totals = _window_totals(registry, lm, diffusion)
                                 timing = {
                                     k: (totals[k] - window_totals[k]) / n_window
                                     for k in totals

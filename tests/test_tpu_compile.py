@@ -35,17 +35,22 @@ def no_compile_cache():
     compilation_cache.reset_cache()
 
 
-@pytest.mark.parametrize("window", [None, 1024], ids=["full", "window_1024"])
+@pytest.mark.parametrize("window,mask", [(None, None), (1024, None), (None, 4)],
+                         ids=["full", "window_1024", "block_diffusion_4"])
 def test_blocked_attention_compiles_at_the_cells_shapes(one_chip, no_compile_cache,
-                                                        window):
-    from speakingstyle_tpu.ops.blocked_attention import blocked_attention
+                                                        window, mask):
+    """8,192 positions: a row of ``train_mellum2_8k_ep4share``, and the noised
+    and the clean stream of a 4,096-token row of ``train_sdar_4k_bd4_ep8share``."""
+    from speakingstyle_tpu.ops.blocked_attention import (
+        BlockDiffusion, blocked_attention)
 
     q = jax.ShapeDtypeStruct((1, 32, 8192, 128), jnp.bfloat16, sharding=one_chip)
     kv = jax.ShapeDtypeStruct((1, 4, 8192, 128), jnp.bfloat16, sharding=one_chip)
 
     def loss(q, k, v):
-        return jnp.sum(blocked_attention(q, k, v, window=window,
-                                         interpret=False).astype(jnp.float32))
+        return jnp.sum(blocked_attention(
+            q, k, v, window=window, interpret=False,
+            mask=mask and BlockDiffusion(mask)).astype(jnp.float32))
 
     compiled = jax.jit(jax.grad(loss, (0, 1, 2))).lower(q, kv, kv).compile()
     assert compiled.as_text().count("tpu_custom_call") >= 3   # fwd, dq, dk/dv
