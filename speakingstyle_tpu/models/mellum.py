@@ -25,7 +25,7 @@ over ``2L`` positions; the head runs on the noised half only, with no shift:
 ``weight`` is the loader's: ``1 / t`` where position ``i`` is masked (``t``
 its block's masking probability), else 0. ``qk_norm`` puts an RMSNorm with
 one learned scale of ``head_dim`` on each head's query and key before the
-rotation.
+rotation (a property of the model: ``qk_prepare`` takes the scale or none).
 
 **Expert-parallel share.** The router scores all ``num_experts`` and takes
 the ``num_experts_per_tok`` largest; the layer computes the part of the sum
@@ -42,10 +42,17 @@ layer keeps two residual-stream arrays for the backward pass and recomputes
 its two halves: attention as one block but for the core's two results, the
 output ``[B, H, T, D]`` and the rows' log-sum-exp ``[B, H, 1, T]`` float32,
 which are kept by name (``KEEP_CORE``), so the backward never runs the
-forward kernel again (norm, projections, rotary and transposes are
-recomputed: ``q``, ``k``, ``v`` are not kept; off a TPU the einsum reference
-has no such names and everything is recomputed); the experts one batch row
-at a time
+forward kernel again (norm, projections and what lies between a projection
+and the core are recomputed: ``q``, ``k``, ``v`` are not kept. For ``q`` and
+``k`` that is one pass each way, ``ops/qk_prepare.py``: the head-wise norm
+under ``qk_norm``, the rotation and the core's ``[B, H, T, D]`` layout in
+one kernel forward, run again in the backward, and one kernel backward,
+from the projections' float32 accumulators (``dot_wide``) with one rounding,
+as XLA ran the passes by parts; ``v`` and the core's output are transposed
+by XLA. Off a TPU the einsum
+reference has no such names and everything is recomputed, and
+``heads_by_parts`` stands in ``qk_prepare``'s place); the experts one batch
+row at a time
 (a row's worst case, every pair held here, sizes the dispatch buffers'
 shapes; the work over them follows the tiles the row's plan uses:
 ``ops/expert_dispatch.py``). The logits stand ``LOSS_CHUNK`` positions at a
@@ -57,6 +64,7 @@ got a row (equal, or something was dropped), and the row tiles the plans
 used beside the worst case the buffers are sized for.
 """
 
+import functools
 import math
 from typing import Tuple
 
@@ -70,6 +78,7 @@ from speakingstyle_tpu.ops import expert_dispatch
 from speakingstyle_tpu.ops.blocked_attention import (
     LSE_NAME, OUT_NAME, BlockDiffusion, blocked_attention)
 from speakingstyle_tpu.ops.grouped_matmul import TILE_ROWS, grouped_matmul
+from speakingstyle_tpu.ops.qk_prepare import qk_prepare
 
 # positions whose float32 logits stand at once (fewer where a batch has fewer)
 LOSS_CHUNK = 4096
@@ -128,6 +137,29 @@ class RMSNorm(nn.Module):
         return rms_norm(x, scale, self.eps)
 
 
+class HeadScale(nn.Module):
+    """The learned ``scale`` of a head-wise RMSNorm (``q_norm``, ``k_norm``):
+    the norm itself is part of ``qk_prepare``'s pass."""
+
+    @nn.compact
+    def __call__(self, head_dim):
+        return self.param("scale", nn.initializers.ones, (head_dim,), jnp.float32)
+
+
+def heads_by_parts(x, cos, sin, scale, heads, eps, dtype):
+    """What ``qk_prepare`` does in one pass, a pass each: the projection's
+    own rounding to ``dtype``, the head-wise norm (under ``scale``), the
+    rotation, the transpose into ``[B, H, T, D]``. Where the kernel does not
+    run (off a TPU, a head size or a length its tiles do not divide) this
+    does, with this module's ``rms_norm`` and ``apply_rope`` as they stand
+    when it is called."""
+    B, T, _ = x.shape
+    x = x.astype(dtype).reshape(B, T, heads, -1)
+    if scale is not None:
+        x = rms_norm(x, scale, eps)
+    return apply_rope(x, cos, sin).transpose(0, 2, 1, 3)
+
+
 INIT_STD = 0.02
 
 
@@ -143,10 +175,41 @@ def writes_std(cfg: DecoderLMConfig) -> float:
     return INIT_STD / math.sqrt(2 * len(cfg.layer_types))
 
 
-def _dense(features, name, dtype, std=INIT_STD):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _dot_wide(lhs, rhs, dimension_numbers):
+    """A product that hands on its float32 accumulators, not yet rounded to
+    the operands' dtype; backward as the narrow product's: the cotangent is
+    rounded to that dtype first (never a float32 operand on the MXU)."""
+    return jax.lax.dot_general(lhs, rhs, dimension_numbers,
+                               preferred_element_type=jnp.float32)
+
+
+def _dot_wide_fwd(lhs, rhs, dimension_numbers):
+    return _dot_wide(lhs, rhs, dimension_numbers), (lhs, rhs)
+
+
+def _dot_wide_bwd(dimension_numbers, operands, ct):
+    narrow = lambda l, r: jax.lax.dot_general(l, r, dimension_numbers)
+    return jax.vjp(narrow, *operands)[1](ct.astype(operands[0].dtype))
+
+
+_dot_wide.defvjp(_dot_wide_fwd, _dot_wide_bwd)
+
+
+def dot_wide(lhs, rhs, dimension_numbers, precision=None,
+             preferred_element_type=None):
+    """``_dot_wide`` under ``jax.lax.dot_general``'s signature (``nn.Dense``'s
+    ``dot_general``)."""
+    return _dot_wide(lhs, rhs, dimension_numbers)
+
+
+def _dense(features, name, dtype, std=INIT_STD, wide=False):
+    """``wide``: the output is the product's float32 accumulators (for
+    ``qk_prepare``, which rounds once behind the norm and the rotation)."""
     return nn.Dense(features, use_bias=False, dtype=dtype,
                     param_dtype=jnp.float32, name=name,
-                    kernel_init=nn.initializers.normal(std))
+                    kernel_init=nn.initializers.normal(std),
+                    dot_general=dot_wide if wide else None)
 
 
 class SelfAttention(nn.Module):
@@ -162,19 +225,23 @@ class SelfAttention(nn.Module):
         B, T, _ = x.shape
         H, Hkv, D = c.num_attention_heads, c.num_key_value_heads, c.head_dim
         u = RMSNorm(c.rms_norm_eps, name="input_norm")(x)
-        q = _dense(H * D, "q_proj", self.dtype)(u).reshape(B, T, H, D)
-        k = _dense(Hkv * D, "k_proj", self.dtype)(u).reshape(B, T, Hkv, D)
+        q = _dense(H * D, "q_proj", self.dtype, wide=True)(u)
+        k = _dense(Hkv * D, "k_proj", self.dtype, wide=True)(u)
         v = _dense(Hkv * D, "v_proj", self.dtype)(u).reshape(B, T, Hkv, D)
-        if c.qk_norm:
-            q = RMSNorm(c.rms_norm_eps, name="q_norm")(q)
-            k = RMSNorm(c.rms_norm_eps, name="k_norm")(k)
-        q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
+        q_scale = HeadScale(name="q_norm")(D) if c.qk_norm else None
+        k_scale = HeadScale(name="k_norm")(D) if c.qk_norm else None
+        # head-wise norm, rotation and the core's ``[B, H, T, D]`` layout: one
+        # pass each way on a TPU, ``heads_by_parts`` elsewhere
+        with jax.named_scope("qk_prepare"):
+            q = qk_prepare(q, cos, sin, q_scale, heads=H, eps=c.rms_norm_eps,
+                           dtype=self.dtype, otherwise=heads_by_parts)
+            k = qk_prepare(k, cos, sin, k_scale, heads=Hkv, eps=c.rms_norm_eps,
+                           dtype=self.dtype, otherwise=heads_by_parts)
         # the attention core, under a name of its own (the per-module
         # readers hold on to ``self_attn/core``, whatever implements it)
         with jax.named_scope("core"):
             o = blocked_attention(
-                q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-                v.transpose(0, 2, 1, 3), window=self.window or None,
+                q, k, v.transpose(0, 2, 1, 3), window=self.window or None,
                 sm_scale=1.0 / math.sqrt(D),
                 **({"mask": BlockDiffusion(c.block_length)}
                    if c.block_diffusion else {}))

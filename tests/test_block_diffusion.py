@@ -29,6 +29,7 @@ from speakingstyle_tpu.data import CacheBudget, PackedBatcher, TokenDataset  # n
 from speakingstyle_tpu.data.token_dataset import T_MIN, block_noise  # noqa: E402
 from speakingstyle_tpu.models import mellum  # noqa: E402
 from speakingstyle_tpu.ops import blocked_attention as ba  # noqa: E402
+from speakingstyle_tpu.ops import qk_prepare  # noqa: E402
 
 CONFIG = "benchmark/configs/sdar_30b_ep8share.json"
 
@@ -222,6 +223,28 @@ def test_program_is_the_reference_in_float32(toy):
     assert int((aux["pairs_routed"] - aux["pairs_placed"]).sum()) == 0
     assert int(aux["tokens_masked"]) == int((batch["weight"] > 0).sum())
     assert float(aux["loss_weight"]) == pytest.approx(float(batch["weight"].sum()))
+
+
+def test_program_with_qk_prepare_emulated_is_the_program_by_parts(toy, monkeypatch):
+    """At a head of 128 lanes the interpreted ``qk_prepare`` (norm variant)
+    stands where norm, rotation and transpose stand by parts: same loss, same
+    gradients (the two norms' scales among them) to the tolerance this file
+    holds the reference to, and 3 layers x (q, k) x (forward, recomputed,
+    backward) launches in the step."""
+    block, batch = {**toy[0], "head_dim": 128}, toy[3]
+    params = ref.init_params(ref.hyper({**toy_block(), "decoder_lm": block}), 7)
+    loss, grads, _ = program_grads(block, params, batch, jnp.float32)
+    real = mellum.qk_prepare
+    monkeypatch.setattr(mellum, "qk_prepare",
+                        lambda *a, **kw: real(*a, interpret=True, **kw))
+    fused_loss, fused, _ = program_grads(block, params, batch, jnp.float32)
+    assert abs(fused_loss - loss) < 1e-5 * loss
+    assert set(fused) == set(grads)
+    assert max(rel(fused[k], grads[k]) for k in grads) < 2e-5
+    model = mellum.DecoderLM(_build(DecoderLMConfig, block), dtype=jnp.float32)
+    step = jax.make_jaxpr(jax.grad(lambda p: model.apply(
+        {"params": p}, **mellum.batch_inputs(batch))[0]))(params)
+    assert qk_prepare.launches(step) == {"norm": 18, "plain": 0}
 
 
 def test_program_in_bfloat16_stays_near_the_reference(toy):
@@ -468,6 +491,29 @@ def test_run_training_counts_the_masked_tokens_and_their_weights(corpus_config):
     val = [e for e in events if e.get("event") == "val"] or [
         e for e in events if "val" in str(e.get("event"))]
     assert val, [e.get("event") for e in events]        # the validation loss ran on the arrays
+
+
+@pytest.mark.parametrize("head_dim,launches", [(16, 0), (128, 18)],
+                         ids=["by_parts", "emulated"])
+def test_program_card_span_counts_qk_prepares_launches(corpus_config, monkeypatch,
+                                                       head_dim, launches):
+    """``train_program_card`` says how often ``qk_prepare``'s kernels stand
+    in the step, by variant: 0 where the parts ran (a head of 16 lanes), and
+    where they engage 3 layers x (q, k) x (forward, recomputed, backward)."""
+    import dataclasses
+
+    from speakingstyle_tpu.training.trainer import run_training
+
+    real = mellum.qk_prepare
+    monkeypatch.setattr(mellum, "qk_prepare",
+                        lambda *a, **kw: real(*a, interpret=True, **kw))
+    model = corpus_config.model
+    cfg = dataclasses.replace(corpus_config, model=dataclasses.replace(
+        model, decoder_lm=dataclasses.replace(model.decoder_lm, head_dim=head_dim)))
+    run_training(cfg, mesh=None, max_steps=2, registry=obs.MetricsRegistry(), log=True)
+    card = [s for s in obs.trace.get_span_ring().spans()
+            if s["name"] == "train_program_card"][-1]
+    assert card["fields"]["qk_prepare_launches"] == {"norm": launches, "plain": 0}
 
 
 def test_preset_is_the_published_configuration_uncut():

@@ -66,6 +66,30 @@ def test_program_is_the_reference_in_float32(toy):
     assert int((aux["pairs_routed"] - aux["pairs_placed"]).sum()) == 0
 
 
+def test_program_with_qk_prepare_emulated_is_the_program_by_parts(toy, monkeypatch):
+    """At a head of 128 lanes the interpreted ``qk_prepare`` (no head norm in
+    this model: the plain variant) stands where rotation and transpose stand
+    by parts: same loss and gradients to the tolerance this file holds the
+    reference to, and 4 layers x (q, k) x (forward, recomputed, backward)
+    launches in the step."""
+    from speakingstyle_tpu.ops import qk_prepare
+
+    block = {**toy[0], "head_dim": 128}
+    tokens = toy[3]
+    params = ref.init_params(ref.hyper({"decoder_lm": block}), 7)
+    loss, grads, _ = program_grads(block, params, tokens, jnp.float32)
+    real = mellum.qk_prepare
+    monkeypatch.setattr(mellum, "qk_prepare",
+                        lambda *a, **kw: real(*a, interpret=True, **kw))
+    fused_loss, fused, _ = program_grads(block, params, tokens, jnp.float32)
+    assert abs(fused_loss - loss) < 1e-5 * loss
+    assert max(rel(fused[k], grads[k]) for k in grads) < 2e-5
+    model = mellum.DecoderLM(_build(DecoderLMConfig, block), dtype=jnp.float32)
+    step = jax.make_jaxpr(jax.grad(
+        lambda p: model.apply({"params": p}, tokens)[0]))(params)
+    assert qk_prepare.launches(step) == {"norm": 0, "plain": 24}
+
+
 def test_program_in_bfloat16_stays_within_the_cells_tolerances(toy):
     """bfloat16 compute against the float32 reference: the loss within a
     percent; every gradient leaf's norm within a tenth of the larger of its

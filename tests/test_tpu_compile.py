@@ -56,26 +56,76 @@ def test_blocked_attention_compiles_at_the_cells_shapes(one_chip, no_compile_cac
     assert compiled.as_text().count("tpu_custom_call") >= 3   # fwd, dq, dk/dv
 
 
-@pytest.mark.parametrize("window", [0, 1024], ids=["full", "window_1024"])
+@pytest.mark.parametrize("heads", [32, 4], ids=["q_32_heads", "k_4_heads"])
+@pytest.mark.parametrize("normed", [True, False], ids=["norm", "plain"])
+def test_qk_prepare_compiles_at_the_cells_shapes(one_chip, no_compile_cache, heads,
+                                                 normed):
+    """Both variants, forward and backward, at a step's ``q`` and ``k`` of
+    both decoder cells, ``[4, 8192, 32 * 128]`` and ``[4, 8192, 4 * 128]``,
+    from the projection's float32 to the core's bfloat16."""
+    from speakingstyle_tpu.ops.qk_prepare import qk_prepare
+
+    x = jax.ShapeDtypeStruct((4, 8192, heads * 128), jnp.float32, sharding=one_chip)
+    table = jax.ShapeDtypeStruct((8192, 128), jnp.float32, sharding=one_chip)
+    scale = jax.ShapeDtypeStruct((128,), jnp.float32, sharding=one_chip)
+
+    def loss(x, cos, sin, scale):
+        return jnp.sum(qk_prepare(x, cos, sin, scale if normed else None, heads=heads,
+                                  eps=1e-6, dtype=jnp.bfloat16,
+                                  interpret=False).astype(jnp.float32))
+
+    compiled = jax.jit(jax.value_and_grad(loss, (0, 3) if normed else (0,))).lower(
+        x, table, table, scale).compile()
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 2
+
+
+def _transposes(jaxpr, sizes, found=None):
+    """{size: ``transpose`` equations over an array of that many elements},
+    in ``jaxpr`` and all it holds."""
+    found = dict.fromkeys(sizes, 0) if found is None else found
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "transpose" and eqn.invars[0].aval.size in found:
+            found[eqn.invars[0].aval.size] += 1
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    _transposes(sub, sizes, found)
+    return found
+
+
+@pytest.mark.parametrize("preset,window", [
+    ("Mellum2-12B-A2.5B", 0), ("Mellum2-12B-A2.5B", 1024), ("SDAR-30B-A3B", 0)],
+    ids=["full", "window_1024", "block_diffusion_qk_norm"])
 def test_attention_half_keeps_the_core_at_the_cells_shapes(one_chip, no_compile_cache,
-                                                           monkeypatch, window):
-    """The cell's attention half (norm, projections, rotary, core, ``o_proj``)
-    at ``[4, 8192, 2304]`` under the model's rematerialisation policy, forward
-    and backward: three kernels in the compiled step, not four (the forward
-    kernel's two results are kept, so it is not launched again)."""
+                                                           monkeypatch, capsys, preset,
+                                                           window):
+    """A cell's attention half (norm, projections, ``qk_prepare``, core,
+    ``o_proj``) at ``[4, 8192, hidden]`` under the model's rematerialisation
+    policy, forward and backward. Nine kernels in the compiled step: the
+    core's three (its forward's two results are kept, so it is not launched
+    again: ten otherwise) and ``qk_prepare``'s for ``q`` and for ``k``,
+    forward, forward again in the backward, backward. What crosses from the
+    forward to the backward beside the half's arguments is the core's output
+    and log-sum-exp alone. No ``q`` or ``k`` is transposed any more: of their
+    sizes only ``o`` and ``v`` still are (forward, recomputed, cotangent)."""
     import flax.linen as nn
+    from jax.interpreters import partial_eval as pe
 
     from speakingstyle_tpu.configs.config import load_config
     from speakingstyle_tpu.models import mellum
 
-    real = mellum.blocked_attention
     # the backend here is the CPU: compile the kernels, as the chip would
+    core, prepare = mellum.blocked_attention, mellum.qk_prepare
     monkeypatch.setattr(mellum, "blocked_attention",
-                        lambda *a, **kw: real(*a, interpret=False, **kw))
-    cfg = load_config(preset="Mellum2-12B-A2.5B").model.decoder_lm
+                        lambda *a, **kw: core(*a, interpret=False, **kw))
+    monkeypatch.setattr(mellum, "qk_prepare",
+                        lambda *a, **kw: prepare(*a, interpret=False, **kw))
+    cfg = load_config(preset=preset).model.decoder_lm
     half = nn.remat(mellum.SelfAttention, policy=mellum.KEEP_CORE)(
         cfg, window, jnp.bfloat16)
-    cos, sin = mellum.rope_tables(cfg.rope_parameters.sliding_attention,
+    kind = "sliding_attention" if window else "full_attention"
+    cos, sin = mellum.rope_tables(getattr(cfg.rope_parameters, kind),
                                   cfg.head_dim, 8192)
 
     def on_chip(tree):
@@ -88,9 +138,21 @@ def test_attention_half_keeps_the_core_at_the_cells_shapes(one_chip, no_compile_
     def loss(p, x, cos, sin):
         return jnp.sum(half.apply(p, x, cos, sin).astype(jnp.float32))
 
-    compiled = jax.jit(jax.value_and_grad(loss, (0, 1))).lower(
-        *on_chip((params, x, cos, sin))).compile()
-    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 3
+    args = on_chip((params, x, cos, sin))
+    step = jax.value_and_grad(loss, (0, 1))
+    compiled = jax.jit(step).lower(*args).compile()
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 9
+    capsys.readouterr()
+    jax.ad_checkpoint.print_saved_residuals(loss, *args)
+    kept = [line.split()[0] for line in capsys.readouterr().out.splitlines()
+            if " from the argument " not in line]
+    H, D = cfg.num_attention_heads, cfg.head_dim
+    assert sorted(kept) == [f"bf16[4,{H},8192,{D}]", f"f32[4,{H},1,8192]"]
+    closed = jax.make_jaxpr(step)(*args)
+    live = pe.dce_jaxpr(closed.jaxpr, [True] * len(closed.jaxpr.outvars))[0]
+    q_size, k_size = 4 * 8192 * H * D, 4 * 8192 * cfg.num_key_value_heads * D
+    assert _transposes(live, (q_size, k_size)) == {q_size: 3, k_size: 3}
+    assert mellum.qk_prepare.__module__ == __name__     # the patch was in place
 
 
 @pytest.mark.parametrize("k,n", [(2304, 896), (896, 2304)], ids=["gate_up", "down"])
