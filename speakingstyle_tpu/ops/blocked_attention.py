@@ -24,15 +24,23 @@ are never visited:
   ``2L`` has 136, at ``L`` 4,096);
 - **grouped queries**: ``H`` query heads share ``H_kv`` key-value heads,
   ``G = H / H_kv`` to one; the index maps send query head ``h`` to key-value
-  head ``h // G``, and the key/value gradient kernel sums over the group.
+  head ``h // G``, and the backward sums the key/value gradients over the
+  group.
 
 Layout ``[B, H, T, D]`` (``D`` on lanes: 128 is one lane tile), scores and
 softmax in float32, probabilities cast to the operands' dtype for the second
-product. The backward is two kernels, as usual for this algorithm: one per
-query block for ``dq`` (the forward's loop again), one per key block for
-``dk`` and ``dv``, which works on transposed scores ``[keys, queries]`` so
-that the per-query log-sum-exp and ``delta = rowsum(dO * O)`` ride as rows.
-Only the mask-partial blocks (the diagonal, the window's far edge, a
+product. The backward is one kernel: it walks the query blocks as the
+forward does, forms each visited tile's scores once, transposed
+``[keys, queries]`` so that the per-query log-sum-exp and
+``delta = rowsum(dO * O)`` ride as rows, and writes ``dq``, ``dk`` and
+``dv`` from them (5 block products and one ``exp`` a tile). ``dq`` of a
+query block gathers in a block of float32 scratch; ``dk`` and ``dv`` of a
+key-value head gather over its group and every query block in float32
+scratch as long as the sequence (8 MiB for both at 8,192 x 128). Where that
+does not fit the chip's fast memory the backward is two kernels, each
+forming the scores again: one per query block for ``dq``, one per key block
+for ``dk`` and ``dv`` (the split path: past 44,032 positions at a head of
+128 in bfloat16). Only the mask-partial blocks (the diagonal, the window's far edge, a
 stream's own tile and the clean tile beside a noised one) pay for the mask.
 Which tiles meet is a *geometry* (``_Causal``, ``_BlockDiffusion`` below):
 the kernels and the index maps ask it from the query side (``key``) and
@@ -55,6 +63,7 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from speakingstyle_tpu import ops
 from speakingstyle_tpu.ops import on_tpu
 
 LANE = 128
@@ -69,6 +78,9 @@ _NT = (((1,), (1,)), ((), ()))  # a @ b.T
 # its backward runs the forward kernel no second time (models/mellum.py)
 OUT_NAME = "blocked_attention_out"
 LSE_NAME = "blocked_attention_lse"
+# the backward's kernels in a program, by path: ``launches`` counts them
+NAMES = {"fused": ("blocked_attention_bwd",),
+         "split": ("blocked_attention_dq", "blocked_attention_dkv")}
 
 
 class BlockDiffusion(NamedTuple):
@@ -351,9 +363,74 @@ def _forward(q, k, v, geom, sm_scale, interpret):
 
 
 # -- backward ----------------------------------------------------------------
+# One kernel forms each visited tile's scores once and writes all three
+# gradients from them (``_bwd_kernel``). Its grid walks the query tiles as
+# the forward does, ``(B, H_kv, G, query tile, step)``: ``dq`` of a tile
+# gathers over its steps in ``[blk, D]`` float32 and leaves once; ``dk`` and
+# ``dv`` of the key-value head stand whole-length in float32 scratch
+# (``[T, D]`` each, 4 MiB at 8,192 x 128), gather over the group and every
+# query tile, and leave at the grid's last step of that head. Each gradient
+# is summed in the order the two kernels of the split path sum it. Where the
+# whole-length scratch does not fit the chip's fast memory (``_fuses``), the
+# split path runs: ``_dq_kernel`` per query tile and ``_dkv_kernel`` per key
+# tile, each forming the scores again; at lengths past the cells' only.
+
+_TN = (((0,), (0,)), ((), ()))  # a.T @ b
+
+
+def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dk_ref,
+                dv_ref, dq_sc, dk_sc, dv_sc, *, sm_scale, geom):
+    """Scores transposed, ``[keys, queries]``, so that the log-sum-exp and
+    ``delta`` ride as rows and only ``dq``'s product takes a transposed
+    operand."""
+    g, i, j = pl.program_id(2), pl.program_id(3), pl.program_id(4)
+    kb = geom.key(i, j)
+    last = geom.q_steps - 1
+
+    @pl.when(j == 0)
+    def _():
+        dq_sc[...] = jnp.zeros(dq_sc.shape, jnp.float32)
+
+    @pl.when((g == 0) & (i == 0) & (j == 0))
+    def _():
+        dk_sc[...] = jnp.zeros(dk_sc.shape, jnp.float32)
+        dv_sc[...] = jnp.zeros(dv_sc.shape, jnp.float32)
+
+    def step(masked):
+        q, k, v, do = q_ref[0, 0], k_ref[0, 0], v_ref[0, 0], do_ref[0, 0]
+        lse, delta = lse_ref[0, 0], delta_ref[0, 0]      # [1, blk] rows
+        st = jax.lax.dot_general(k, q, _NT,
+                                 preferred_element_type=jnp.float32) * sm_scale
+        if masked:
+            st = jnp.where(geom.mask(i, kb, True), st, NEG)
+        pt = jnp.exp(st - lse)                           # [keys, queries]
+        rows = pl.ds(pl.multiple_of(kb * geom.blk, geom.blk), geom.blk)
+        dv_sc[rows, :] += jnp.dot(pt.astype(do.dtype), do,
+                                  preferred_element_type=jnp.float32)
+        dpt = jax.lax.dot_general(v, do, _NT, preferred_element_type=jnp.float32)
+        dst = (pt * (dpt - delta) * sm_scale).astype(q.dtype)
+        dk_sc[rows, :] += jnp.dot(dst, q, preferred_element_type=jnp.float32)
+        dq_sc[...] += jax.lax.dot_general(dst, k, _TN,
+                                          preferred_element_type=jnp.float32)
+
+    @pl.when(geom.key_seen(i, j, kb))
+    def _():
+        _both(geom.partial(i, kb), step)
+
+    @pl.when(j == last)
+    def _():
+        dq_ref[0, 0] = dq_sc[...].astype(dq_ref.dtype)
+
+    @pl.when((g == pl.num_programs(2) - 1) & (i == pl.num_programs(3) - 1)
+             & (j == last))
+    def _():
+        dk_ref[0, 0] = dk_sc[...].astype(dk_ref.dtype)
+        dv_ref[0, 0] = dv_sc[...].astype(dv_ref.dtype)
+
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, acc_sc,
                *, sm_scale, geom):
+    """The split path's ``dq``: the forward's loop again."""
     i, j = pl.program_id(2), pl.program_id(3)
     kb = geom.key(i, j)
 
@@ -385,6 +462,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, acc_sc,
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
                 dk_sc, dv_sc, *, sm_scale, geom, group):
+    """The split path's ``dk`` and ``dv``: one key tile's rows at a time."""
     kb, g, t = pl.program_id(2), pl.program_id(3), pl.program_id(4)
     i = geom.query(kb, t)  # the query block
 
@@ -417,6 +495,27 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
         dv_ref[0, 0] = dv_sc[...].astype(dv_ref.dtype)
 
 
+# one v5e TensorCore's fast memory (``pltpu.get_tpu_info()`` on the chip
+# reads 134,217,728), for a program compiled or emulated with no TPU attached
+V5E_VMEM = 128 * 2**20
+
+
+def _fused_vmem(T: int, D: int, blk: int, itemsize: int) -> int:
+    """Bytes of fast memory the fused kernel asks for: ``dk``, ``dv``
+    whole-length and ``dq`` a tile in float32, their output blocks and the
+    input tiles double-buffered, and a tile's float32 temporaries."""
+    acc = (2 * T + blk) * D * 4
+    blocks = 2 * ((2 * T + blk) * D + 4 * blk * D) * itemsize + 2 * 2 * 8 * blk * 4
+    return acc + blocks + 8 * blk * blk * 4
+
+
+def _fuses(T: int, D: int, blk: int, itemsize: int) -> bool:
+    """Whether the backward runs as one kernel at this shape: whether what
+    it asks for fits three quarters of the chip's fast memory."""
+    vmem = pltpu.get_tpu_info().vmem_capacity_bytes if on_tpu() else V5E_VMEM
+    return _fused_vmem(T, D, blk, itemsize) <= vmem * 3 // 4
+
+
 def _backward(q, k, v, o, lse, do, geom, sm_scale, interpret):
     B, H, T, D = q.shape
     Hkv = k.shape[1]
@@ -425,6 +524,39 @@ def _backward(q, k, v, o, lse, do, geom, sm_scale, interpret):
     nq = T // blk
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
                     axis=-1)[:, :, None, :]              # [B, H, 1, T]
+    itemsize = jnp.dtype(q.dtype).itemsize
+    if _fuses(T, D, blk, itemsize):
+        def tile(b, hk, g, i, j):
+            return (b, hk * G + g, i, 0)
+
+        def key_tile(b, hk, g, i, j):
+            return (b, hk, geom.fetch_key(i, j), 0)
+
+        def row(b, hk, g, i, j):
+            return (b, hk * G + g, 0, i)
+
+        q_spec = pl.BlockSpec((1, 1, blk, D), tile)
+        kv_spec = pl.BlockSpec((1, 1, blk, D), key_tile)
+        row_spec = pl.BlockSpec((1, 1, 1, blk), row)
+        whole = pl.BlockSpec((1, 1, T, D), lambda b, hk, g, i, j: (b, hk, 0, 0))
+        return tuple(pl.pallas_call(
+            functools.partial(_bwd_kernel, sm_scale=sm_scale, geom=geom),
+            grid=(B, Hkv, G, nq, geom.q_steps),
+            in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
+            out_specs=[q_spec, whole, whole],
+            out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+                       jax.ShapeDtypeStruct(k.shape, k.dtype),
+                       jax.ShapeDtypeStruct(v.shape, v.dtype)],
+            scratch_shapes=[pltpu.VMEM((blk, D), jnp.float32),
+                            pltpu.VMEM((T, D), jnp.float32),
+                            pltpu.VMEM((T, D), jnp.float32)],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary",
+                                     "arbitrary", "arbitrary"),
+                vmem_limit_bytes=_fused_vmem(T, D, blk, itemsize)),
+            interpret=interpret,
+            name=NAMES["fused"][0],
+        )(q, k, v, do, lse, delta))
 
     def kv_map(b, h, i, j):
         return (b, h // G, geom.fetch_key(i, j), 0)
@@ -443,6 +575,7 @@ def _backward(q, k, v, o, lse, do, geom, sm_scale, interpret):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name=NAMES["split"][0],
     )(q, k, v, do, lse, delta)
 
     def q_of(b, hk, kb, g, t):
@@ -468,6 +601,7 @@ def _backward(q, k, v, o, lse, do, geom, sm_scale, interpret):
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary", "arbitrary")),
         interpret=interpret,
+        name=NAMES["split"][1],
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
 
@@ -536,3 +670,9 @@ def blocked_attention(q, k, v, window: Optional[int] = None,
     out = _blocked(q, k, v, _Causal(window, blk, (T + pad) // blk),
                    float(sm_scale), not compiled)
     return out[:, :, :T] if pad else out
+
+
+def launches(closed_jaxpr) -> dict:
+    """The backward's kernel launches in a program by path (``fused``: one a
+    call; ``split``: two a call): 0 and 0 where the einsum reference ran."""
+    return ops.launches(closed_jaxpr, NAMES)

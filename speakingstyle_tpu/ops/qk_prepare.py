@@ -48,6 +48,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from speakingstyle_tpu import ops
 from speakingstyle_tpu.ops import on_tpu
 
 LANE = 128
@@ -224,27 +225,6 @@ def qk_prepare(x, cos, sin, scale=None, *, heads: int, eps: float = 0.0,
 
 def launches(closed_jaxpr) -> dict:
     """The kernels' launches in a program by variant (``norm``, ``plain``),
-    forward and backward together: 0 and 0 where ``otherwise`` ran. What is
-    dead goes first: a rematerialised region lists its whole forward until
-    what its backward never reads is taken out."""
-    from jax.interpreters import partial_eval as pe
-
-    jaxpr = closed_jaxpr.jaxpr
-    live = pe.dce_jaxpr(jaxpr, [True] * len(jaxpr.outvars))[0]
-    found = dict.fromkeys(NAMES, 0)
-
-    def walk(j):
-        for eqn in j.eqns:
-            if eqn.primitive.name == "pallas_call":
-                name = eqn.params.get("name")
-                for variant, stem in NAMES.items():
-                    found[variant] += name in (stem, stem + "_bwd")
-                continue
-            for value in eqn.params.values():
-                for sub in value if isinstance(value, (list, tuple)) else [value]:
-                    sub = getattr(sub, "jaxpr", sub)
-                    if hasattr(sub, "eqns"):
-                        walk(sub)
-
-    walk(live)
-    return found
+    forward and backward together: 0 and 0 where ``otherwise`` ran."""
+    return ops.launches(closed_jaxpr, {variant: (stem, stem + "_bwd")
+                                       for variant, stem in NAMES.items()})

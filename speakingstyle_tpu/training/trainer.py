@@ -26,7 +26,7 @@ from speakingstyle_tpu.configs.config import Config
 from speakingstyle_tpu.models.loss import fastspeech2_loss
 from speakingstyle_tpu.models.mellum import batch_inputs as lm_inputs
 from speakingstyle_tpu.obs.trace import new_context
-from speakingstyle_tpu.ops import qk_prepare
+from speakingstyle_tpu.ops import blocked_attention, qk_prepare
 from speakingstyle_tpu.parallel.registry import ProgramRegistry, jit_program
 from speakingstyle_tpu.training import faults, resilience
 from speakingstyle_tpu.training.state import TrainState
@@ -68,16 +68,20 @@ def build_train_step_card(train_step, state, arrays, rng,
     return obs.ProgramCard.from_compiled(compiled, name="train_step")
 
 
-def step_qk_prepare_launches(train_step, state, arrays, rng) -> Optional[Dict]:
-    """``ops/qk_prepare``'s kernel launches in the jitted train step by
-    variant (``norm``, ``plain``), from the step's jaxpr (traced already: no
-    compile); all 0 where its ``otherwise`` ran, as off a TPU. Telemetry:
-    None with a warning where the step cannot be read."""
+def step_kernel_launches(train_step, state, arrays, rng) -> Dict:
+    """Launches of the ``decoder_lm`` family's kernels in the jitted train
+    step, from the step's jaxpr (traced already: no compile):
+    ``qk_prepare_launches`` by variant (``norm``, ``plain``) and
+    ``attn_bwd_launches``, the attention core's backward by path (``fused``,
+    ``split``); all 0 where the model's plain ``jax.numpy`` ran, as off a
+    TPU. Telemetry: each None with a warning where the step cannot be read."""
     try:
-        return qk_prepare.launches(train_step.trace(state, arrays, rng).jaxpr)
+        jaxpr = train_step.trace(state, arrays, rng).jaxpr
     except Exception as e:
-        print(f"warning: qk_prepare launches unavailable ({type(e).__name__}: {e})")
-        return None
+        print(f"warning: kernel launches unavailable ({type(e).__name__}: {e})")
+        return dict.fromkeys(("qk_prepare_launches", "attn_bwd_launches"))
+    return {"qk_prepare_launches": qk_prepare.launches(jaxpr),
+            "attn_bwd_launches": blocked_attention.launches(jaxpr)}
 
 
 def _model_kwargs(arrays: Dict, teacher_forced: bool) -> Dict:
@@ -730,7 +734,8 @@ def run_training(
     # the train-step ProgramCard is built once, after the first step has
     # compiled (train.obs.program_card); card_pending makes it one
     # attempt, success or not. The decoder_lm family's span also says how
-    # often ``ops/qk_prepare``'s kernels stand in the step, card or no card
+    # often its kernels (``ops/qk_prepare``'s, the attention core's
+    # backward) stand in the step, card or no card
     program_card, card_pending = None, cfg.train.obs.program_card or lm
 
     # template for rollback restores: stays valid after donation consumes
@@ -807,9 +812,8 @@ def run_training(
                     with obs.Span("train_program_card", registry=registry,
                                   parent=run_ctx) as card_span:
                         if lm:
-                            card_span.note(
-                                qk_prepare_launches=step_qk_prepare_launches(
-                                    train_step, state, arrays, step_rng))
+                            card_span.note(**step_kernel_launches(
+                                train_step, state, arrays, step_rng))
                         if cfg.train.obs.program_card:
                             program_card = build_train_step_card(
                                 train_step, state, arrays, step_rng,

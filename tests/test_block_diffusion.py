@@ -47,14 +47,27 @@ def dense_mask(L, c):
     return ok
 
 
-@pytest.mark.parametrize("L,c", [(256, 4), (384, 32), (256, 64)],
-                         ids=["2_tiles_c4", "3_tiles_c32", "2_tiles_c64"])
-def test_block_mask_is_the_dense_mask_forward_and_backward(L, c):
+@pytest.mark.parametrize("L,c,kv_heads,path", [
+    pytest.param(256, 4, 2, "fused", id="2_tiles_c4"),
+    pytest.param(384, 32, 2, "fused", id="3_tiles_c32"),
+    pytest.param(256, 64, 2, "fused", id="2_tiles_c64"),
+    pytest.param(384, 4, 4, "fused", id="3_tiles_c4_one_query_head_a_kv_head"),
+    pytest.param(384, 4, 2, "split", id="3_tiles_c4_split")])
+def test_block_mask_is_the_dense_mask_forward_and_backward(monkeypatch, L, c, kv_heads,
+                                                           path):
+    """Loss and ``dq``, ``dk``, ``dv`` of the kernels in the interpreter
+    against the einsum reference and a dense mask pair by pair: grouped
+    queries (four over two) and a query head to a key-value head through
+    the fused backward, and the split path's two kernels where the fast
+    memory the fused one may ask for is cut to nothing."""
+    if path == "split":
+        monkeypatch.setattr(ba, "V5E_VMEM", 0)
     T = 2 * L
+    G = 4 // kv_heads
     ks = jax.random.split(jax.random.PRNGKey(L + c), 4)
     q = jax.random.normal(ks[0], (2, 4, T, 32))
-    k = jax.random.normal(ks[1], (2, 2, T, 32))
-    v = jax.random.normal(ks[2], (2, 2, T, 32))
+    k = jax.random.normal(ks[1], (2, kv_heads, T, 32))
+    v = jax.random.normal(ks[2], (2, kv_heads, T, 32))
     w = jax.random.normal(ks[3], (2, 4, T, 32))
     mask, scale = ba.BlockDiffusion(c), 1 / math.sqrt(32)
     ok = dense_mask(L, c)
@@ -69,11 +82,14 @@ def test_block_mask_is_the_dense_mask_forward_and_backward(L, c):
         return jnp.sum(w * ba.reference_attention(q, k, v, None, scale, mask))
 
     def dense(q, k, v):
-        s = jnp.einsum("bkgtd,bksd->bkgts", q.reshape(2, 2, 2, T, 32), k) * scale
+        s = jnp.einsum("bkgtd,bksd->bkgts", q.reshape(2, kv_heads, G, T, 32), k) * scale
         p = jax.nn.softmax(jnp.where(ok, s, -1e30), axis=-1)
         return jnp.sum(w * jnp.einsum("bkgts,bksd->bkgtd", p, v).reshape(2, 4, T, 32))
 
-    a, ga = jax.value_and_grad(kernel, (0, 1, 2))(q, k, v)
+    grad = jax.value_and_grad(kernel, (0, 1, 2))
+    assert ba.launches(jax.make_jaxpr(grad)(q, k, v)) == (
+        {"fused": 1, "split": 0} if path == "fused" else {"fused": 0, "split": 2})
+    a, ga = grad(q, k, v)
     for other in (einsum, dense):
         b, gb = jax.value_and_grad(other, (0, 1, 2))(q, k, v)
         assert abs(float(a - b)) < 1e-3
@@ -136,10 +152,13 @@ def _primitives(jaxpr, out):
 
 @pytest.mark.parametrize("window,T", [(None, 512), (200, 640)], ids=["causal", "window"])
 def test_causal_and_window_calls_trace_to_what_they_traced_to(window, T):
-    """The new argument's default changes nothing: the same jaxpr with and
-    without it, and the three kernels' equations counted as the tree before
-    the mask had them (forward, ``dq``, ``dk``/``dv``: read from the parent
-    commit's trace of the same call)."""
+    """The mask argument's default changes nothing: the same jaxpr with and
+    without it. The forward kernel's equations are the parent's, counted
+    from the parent commit's trace of the same call; the backward is one
+    kernel where the parent had two (``dq``, ``dk``/``dv``), and forms each
+    visited tile's scores once: two block products and one ``exp`` fewer in
+    each of its two branches (the tiles with masked pairs and the whole
+    ones), 5 products and 1 ``exp`` where the two kernels had 7 and 2."""
     q = jnp.zeros((1, 4, T, 128), jnp.bfloat16)
     k = jnp.zeros((1, 2, T, 128), jnp.bfloat16)
 
@@ -151,13 +170,21 @@ def test_causal_and_window_calls_trace_to_what_they_traced_to(window, T):
     named = jax.make_jaxpr(jax.grad(loss(mask=None), (0, 1, 2)))(q, k, k)
     assert str(plain) == str(named)
     counts = _primitives(plain.jaxpr, collections.Counter())
-    assert counts["pallas_call"] == 3
-    want = PARENT_COUNTS["window" if window else "causal"]
-    assert {p: counts[p] for p in want} == want
+    parent = PARENT_COUNTS["window" if window else "causal"]
+    assert counts["pallas_call"] == parent["grad"]["pallas_call"] - 1 == 2
+    assert ba.launches(plain) == {"fused": 1, "split": 0}
+    # the forward: the kernel whose results hold the float32 log-sum-exp
+    forward, = [e for e in plain.jaxpr.eqns if e.primitive.name == "pallas_call"
+                and any(o.aval.dtype == jnp.float32 for o in e.outvars)]
+    assert dict(_primitives(forward.params["jaxpr"], collections.Counter(
+        pallas_call=1))) == parent["forward"]
+    assert counts["dot_general"] == parent["grad"]["dot_general"] - 2 * 2
+    assert counts["exp"] == parent["grad"]["exp"] - 2 * 1
 
 
-# equations inside grad(blocked_attention) at the parent commit (9f723f6), by
+# equations inside grad(blocked_attention) at the parent commit, by
 # primitive: the kernels' bodies, their index maps and the XLA around them
+# (``grad``), and the forward kernel's alone (``forward``)
 PARENT_COUNTS = json.load(open(os.path.join(
     ROOT, "tests", "data", "blocked_attention_parent_counts.json")))
 
@@ -514,6 +541,31 @@ def test_program_card_span_counts_qk_prepares_launches(corpus_config, monkeypatc
     card = [s for s in obs.trace.get_span_ring().spans()
             if s["name"] == "train_program_card"][-1]
     assert card["fields"]["qk_prepare_launches"] == {"norm": launches, "plain": 0}
+    # the core ran as the einsum reference: no backward kernel
+    assert card["fields"]["attn_bwd_launches"] == {"fused": 0, "split": 0}
+
+
+def test_program_card_span_counts_the_cores_backward_launches(corpus_config,
+                                                              monkeypatch):
+    """``train_program_card`` says how often the attention core's backward
+    stands in the step, by path: with the kernels emulated, one fused kernel
+    a layer. Rows of 128 tokens, so that a stream is a whole lane tile."""
+    import dataclasses
+
+    from speakingstyle_tpu.training.trainer import run_training
+
+    real = mellum.blocked_attention
+    monkeypatch.setattr(mellum, "blocked_attention",
+                        lambda *a, **kw: real(*a, interpret=True, **kw))
+    model = corpus_config.model
+    block = dataclasses.replace(model.decoder_lm, seq_len=128)
+    cfg = dataclasses.replace(corpus_config, model=dataclasses.replace(
+        model, decoder_lm=block))
+    run_training(cfg, mesh=None, max_steps=2, registry=obs.MetricsRegistry(), log=True)
+    card = [s for s in obs.trace.get_span_ring().spans()
+            if s["name"] == "train_program_card"][-1]
+    assert card["fields"]["attn_bwd_launches"] == {
+        "fused": block.num_hidden_layers, "split": 0}
 
 
 def test_preset_is_the_published_configuration_uncut():

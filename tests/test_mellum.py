@@ -23,6 +23,7 @@ from speakingstyle_tpu.configs.config import (  # noqa: E402
     DecoderLMConfig, RopeConfig, _build, load_config)
 from speakingstyle_tpu.models import mellum  # noqa: E402
 from speakingstyle_tpu.ops import expert_dispatch  # noqa: E402
+from speakingstyle_tpu.ops import blocked_attention as ba  # noqa: E402
 from speakingstyle_tpu.ops.blocked_attention import (  # noqa: E402
     blocked_attention, reference_attention)
 from speakingstyle_tpu.ops.grouped_matmul import grouped_matmul  # noqa: E402
@@ -245,15 +246,29 @@ def test_yarn_frequencies_and_factor_by_hand():
     assert f2 == factor
 
 
-@pytest.mark.parametrize("length,window", [(200, None), (300, 100), (384, 128),
-                                           (130, 129), (640, 257)])
-def test_blocked_attention_is_einsum_attention(length, window):
+@pytest.mark.parametrize("length,window,kv_heads,path", [
+    pytest.param(200, None, 2, "fused", id="200-None"),
+    pytest.param(300, 100, 2, "fused", id="300-100"),
+    pytest.param(384, 128, 2, "fused", id="384-128"),
+    pytest.param(130, 129, 2, "fused", id="130-129"),
+    pytest.param(640, 257, 2, "fused", id="640-257"),
+    pytest.param(300, None, 4, "fused", id="300-None-one_query_head_a_kv_head"),
+    pytest.param(640, 257, 4, "fused", id="640-257-one_query_head_a_kv_head"),
+    pytest.param(300, None, 2, "split", id="300-None-split"),
+    pytest.param(640, 257, 2, "split", id="640-257-split")])
+def test_blocked_attention_is_einsum_attention(monkeypatch, length, window, kv_heads,
+                                               path):
     """The kernels in the interpreter against the plain einsum, forward and
-    backward, grouped queries, at lengths that are no multiple of the block."""
+    backward (``dq``, ``dk``, ``dv``), grouped queries (four over two) and a
+    query head to a key-value head, at lengths that are no multiple of the
+    block. The backward is the one fused kernel; with the fast memory it may
+    ask for cut to nothing, the split path's two."""
+    if path == "split":
+        monkeypatch.setattr(ba, "V5E_VMEM", 0)
     ks = jax.random.split(jax.random.PRNGKey(length), 4)
     q = jax.random.normal(ks[0], (2, 4, length, 32))
-    k = jax.random.normal(ks[1], (2, 2, length, 32))
-    v = jax.random.normal(ks[2], (2, 2, length, 32))
+    k = jax.random.normal(ks[1], (2, kv_heads, length, 32))
+    v = jax.random.normal(ks[2], (2, kv_heads, length, 32))
     w = jax.random.normal(ks[3], (2, 4, length, 32))
     scale = 1 / math.sqrt(32)
 
@@ -264,7 +279,10 @@ def test_blocked_attention_is_einsum_attention(length, window):
     def plain(q, k, v):
         return jnp.sum(w * reference_attention(q, k, v, window, scale))
 
-    a, ga = jax.value_and_grad(kernel, (0, 1, 2))(q, k, v)
+    grad = jax.value_and_grad(kernel, (0, 1, 2))
+    assert ba.launches(jax.make_jaxpr(grad)(q, k, v)) == (
+        {"fused": 1, "split": 0} if path == "fused" else {"fused": 0, "split": 2})
+    a, ga = grad(q, k, v)
     b, gb = jax.value_and_grad(plain, (0, 1, 2))(q, k, v)
     assert abs(float(a - b)) < 1e-3
     for x, y in zip(ga, gb):
@@ -323,14 +341,14 @@ def _live_jaxpr(fn, *args):
     return pe.dce_jaxpr(closed.jaxpr, [True] * len(closed.jaxpr.outvars))[0]
 
 
-@pytest.mark.parametrize("keep,calls,again", [(True, 3, 2), (False, 4, 3)],
+@pytest.mark.parametrize("keep,calls,again", [(True, 2, 1), (False, 3, 2)],
                          ids=["core_kept", "plain_remat"])
 @pytest.mark.parametrize("window", [0, 8], ids=["full", "window_8"])
 def test_a_layers_backward_runs_the_forward_kernel_once(toy, monkeypatch, window,
                                                         keep, calls, again):
     """With the core's output and log-sum-exp kept across the remat boundary
-    a layer's loss and gradient launch three kernels (forward, ``dq``,
-    ``dk``/``dv``) and the rematerialised region holds the two backward ones
+    a layer's loss and gradient launch two kernels (forward; ``dq``, ``dk``
+    and ``dv`` in one) and the rematerialised region holds the backward one
     alone; with the policy left out (the control: the count can tell) the
     forward stands there a second time."""
     loss, params, x = _toy_layer(toy[0], window, monkeypatch, True, keep)

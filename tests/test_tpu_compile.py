@@ -53,7 +53,28 @@ def test_blocked_attention_compiles_at_the_cells_shapes(one_chip, no_compile_cac
             mask=mask and BlockDiffusion(mask)).astype(jnp.float32))
 
     compiled = jax.jit(jax.grad(loss, (0, 1, 2))).lower(q, kv, kv).compile()
-    assert compiled.as_text().count("tpu_custom_call") >= 3   # fwd, dq, dk/dv
+    # the forward; dq, dk and dv in one
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 2
+
+
+@pytest.mark.parametrize("T,kernels", [(44032, 2), (44544, 3)],
+                         ids=["longest_fused", "shortest_split"])
+def test_blocked_attention_backward_fits_fast_memory_at_the_longest_length(
+        one_chip, no_compile_cache, T, kernels):
+    """The fused backward at the longest length it is chosen for (a head of
+    128 in bfloat16: the whole-length float32 scratch and its blocks ask for
+    95.6 MiB, of the 96 it may) compiles inside the fast memory it asks for;
+    one tile longer the split path's two kernels take over."""
+    from speakingstyle_tpu.ops.blocked_attention import blocked_attention
+
+    q = jax.ShapeDtypeStruct((1, 2, T, 128), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, 1, T, 128), jnp.bfloat16, sharding=one_chip)
+
+    def loss(q, k, v):
+        return jnp.sum(blocked_attention(q, k, v, interpret=False).astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, (0, 1, 2))).lower(q, kv, kv).compile()
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == kernels
 
 
 @pytest.mark.parametrize("heads", [32, 4], ids=["q_32_heads", "k_4_heads"])
@@ -102,9 +123,10 @@ def test_attention_half_keeps_the_core_at_the_cells_shapes(one_chip, no_compile_
                                                            window):
     """A cell's attention half (norm, projections, ``qk_prepare``, core,
     ``o_proj``) at ``[4, 8192, hidden]`` under the model's rematerialisation
-    policy, forward and backward. Nine kernels in the compiled step: the
-    core's three (its forward's two results are kept, so it is not launched
-    again: ten otherwise) and ``qk_prepare``'s for ``q`` and for ``k``,
+    policy, forward and backward. Eight kernels in the compiled step: the
+    core's two (forward; ``dq``, ``dk`` and ``dv`` in one; its forward's two
+    results are kept, so it is not launched again: nine otherwise) and
+    ``qk_prepare``'s for ``q`` and for ``k``,
     forward, forward again in the backward, backward. What crosses from the
     forward to the backward beside the half's arguments is the core's output
     and log-sum-exp alone. No ``q`` or ``k`` is transposed any more: of their
@@ -141,7 +163,7 @@ def test_attention_half_keeps_the_core_at_the_cells_shapes(one_chip, no_compile_
     args = on_chip((params, x, cos, sin))
     step = jax.value_and_grad(loss, (0, 1))
     compiled = jax.jit(step).lower(*args).compile()
-    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 9
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 8
     capsys.readouterr()
     jax.ad_checkpoint.print_saved_residuals(loss, *args)
     kept = [line.split()[0] for line in capsys.readouterr().out.splitlines()
