@@ -37,10 +37,18 @@ consumer can rely on:
     ``checkpoint_save`` (step), ``rollback`` (step, ``rollback_n``,
     ``restore_step``), ``fault_fire`` (kind, step), ``preempt_flush``
     (signal, step), ``quarantine`` (sample ids), ``note`` (msg),
+    ``compile`` (a backend compile in the loop past its first step:
+    ``program``, ``outcome`` — ``hit``/``miss``/``uncached`` by the
+    persistent cache —, ``step``, ``duration_s``, ``trace_s`` and
+    ``lower_s`` of the phases before it on its thread, and on a miss the
+    cache ``key`` and its components' ``digests``; obs/jaxmon.py),
     ``train_end`` (one per run that returns: step, ``cache_dir``,
     ``compiles``, ``compile_seconds``, ``cache_hits``,
-    ``cache_requests`` — the jax.monitoring totals, so a log directory
-    says whether the run started warm);
+    ``cache_requests``, ``cache_misses``, ``missed_compile_seconds``
+    (backend-compile seconds the cache did not serve),
+    ``trace_seconds``, ``lower_seconds`` — the jax.monitoring totals, so
+    a log directory says whether the run started warm and where its
+    compile time went);
   * serving (opt-in, ``serve.log_events``): ``serve_dispatch``
     (``req_ids``, bucket, rows, ``duration_s``) and ``http_request``
     (``req_id``, path, status, ``duration_s``) — ``req_id`` joins the

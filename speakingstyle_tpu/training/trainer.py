@@ -453,10 +453,15 @@ def run_training(
     (``loader_cache_hits`` / ``loader_cache_misses``). Set-up is
     four spans under one trace id in the process's span ring
     (``setup_model_init``, ``setup_restore``, ``setup_build_steps``,
-    ``setup_datasets``), joined there by each batch shape's first
-    ``train_dispatch`` (compile or cache load) and the program card's
-    build. A ``train_start`` event records the build identity (git SHA,
-    jax versions, backend, device count), the set-up spans' durations
+    ``setup_datasets``) and ``run_start`` (from there to the loop's
+    first data wait), joined there by the run's first ``train_data_wait``,
+    each batch shape's first ``train_dispatch``, the program card's build,
+    and one ``jax_compile`` record for every compile of the run, under the
+    span it happened in (obs/jaxmon.py: program, cache outcome, its trace
+    and lowering seconds, the loop's step; a compile past the loop's first
+    step is also a ``compile`` event). A ``train_start`` event records
+    the build identity (git SHA, jax versions, backend, device count),
+    the set-up spans' durations
     and the bytes of host memory the datasets may keep samples in
     (``loader_cache_budget_bytes``, data/dataset.CacheBudget); after
     the first step compiles, a one-time ``program_card`` event records XLA's own cost/memory accounting of
@@ -502,6 +507,10 @@ def run_training(
     # into the span ring under it; per-step spans carry no trace id and
     # stay out (the ring holds thousands of records, a run millions of steps)
     run_ctx = new_context(f"train-{os.getpid():x}-{time.time():.0f}")
+    # every compile of the run is a ``jax_compile`` ring record under the
+    # span open on its thread, else under the run (obs/jaxmon.py); in the
+    # loop it carries the step, and past the first step it is an event too
+    compiles = obs.jaxmon.watch_run(run_ctx)
     setup_spans = []
 
     def setup_span(name: str) -> obs.Span:
@@ -689,92 +698,100 @@ def run_training(
         val_registry = obs.MetricsRegistry()
         val_batcher = make_batcher(val_ds, 0, val_registry)
 
-    logger = None
-    if log:
-        events = (
-            obs.JsonlEventLog(
-                cfg.train.path.log_path,
-                max_bytes=cfg.train.obs.events_max_bytes,
-                keep=cfg.train.obs.events_keep,
+    # what stands between set-up and the loop's first data wait (the logger,
+    # the build identity, the step's key, the rollback template) is the
+    # run's ``run_start`` ring span; ``train_start.setup_s`` holds the four
+    # set-up phases, finished before it is written
+    with obs.Span("run_start", registry=registry, parent=run_ctx):
+        logger = None
+        if log:
+            events = (
+                obs.JsonlEventLog(
+                    cfg.train.path.log_path,
+                    max_bytes=cfg.train.obs.events_max_bytes,
+                    keep=cfg.train.obs.events_keep,
+                )
+                if cfg.train.obs.events else None
             )
-            if cfg.train.obs.events else None
+            logger = TrainLogger(
+                cfg.train.path.log_path, registry=registry, events=events
+            )
+        # per-chip observability: gauge labels name each mesh device; on the
+        # single-chip path the one label is the default device
+        mesh_devices = (
+            list(mesh.devices.flat) if mesh is not None else jax.devices()[:1]
         )
-        logger = TrainLogger(
-            cfg.train.path.log_path, registry=registry, events=events
-        )
-    # per-chip observability: gauge labels name each mesh device; on the
-    # single-chip path the one label is the default device
-    mesh_devices = (
-        list(mesh.devices.flat) if mesh is not None else jax.devices()[:1]
-    )
-    if logger:
-        # one identity record per run: build + runtime stack + mesh shape,
-        # so a log directory is attributable without the shell that
-        # launched it; and where the start's seconds went, phase by phase
-        # (``total``: entry of run_training to here, imports included)
-        setup_s = {sp.name.removeprefix("setup_"): sp.duration_s
-                   for sp in setup_spans}
-        setup_s["total"] = time.monotonic() - t_entry
-        logger.event(
-            "train_start", step=step, total_step=total_step,
-            mesh_shape=(dict(mesh.shape) if mesh is not None
-                        else {"data": 1, "model": 1}),
-            mesh_devices=len(mesh_devices),
-            checkpoint_step=ckpt.last_restored_step,
-            weights_digest=ckpt.last_weights_digest,
-            setup_s=setup_s,
-            loader_cache_budget_bytes=sample_cache.limit,
-            **obs.build_info(),
-        )
-    if lm:
-        synth_callback = None  # nothing to render: no mel, no vocoder
-    if synth_callback == "default":
-        synth_callback = default_synth_callback(cfg, logger, vocoder=vocoder)
-    step_rng = jax.random.PRNGKey(cfg.train.seed + 1)
-    # the train-step ProgramCard is built once, after the first step has
-    # compiled (train.obs.program_card); card_pending makes it one
-    # attempt, success or not. The decoder_lm family's span also says how
-    # often its kernels (``ops/qk_prepare``'s, the attention core's
-    # backward) stand in the step, card or no card
-    program_card, card_pending = None, cfg.train.obs.program_card or lm
+        if logger:
+            # one identity record per run: build + runtime stack + mesh shape,
+            # so a log directory is attributable without the shell that
+            # launched it; and where the start's seconds went, phase by phase
+            # (``total``: entry of run_training to here, imports included)
+            setup_s = {sp.name.removeprefix("setup_"): sp.duration_s
+                       for sp in setup_spans}
+            setup_s["total"] = time.monotonic() - t_entry
+            logger.event(
+                "train_start", step=step, total_step=total_step,
+                mesh_shape=(dict(mesh.shape) if mesh is not None
+                            else {"data": 1, "model": 1}),
+                mesh_devices=len(mesh_devices),
+                checkpoint_step=ckpt.last_restored_step,
+                weights_digest=ckpt.last_weights_digest,
+                setup_s=setup_s,
+                loader_cache_budget_bytes=sample_cache.limit,
+                **obs.build_info(),
+            )
+        if lm:
+            synth_callback = None  # nothing to render: no mel, no vocoder
+        if synth_callback == "default":
+            synth_callback = default_synth_callback(cfg, logger, vocoder=vocoder)
+        step_rng = jax.random.PRNGKey(cfg.train.seed + 1)
+        # the train-step ProgramCard is built once, after the first step has
+        # compiled (train.obs.program_card); card_pending makes it one
+        # attempt, success or not. The decoder_lm family's span also says how
+        # often its kernels (``ops/qk_prepare``'s, the attention core's
+        # backward) stand in the step, card or no card
+        program_card, card_pending = None, cfg.train.obs.program_card or lm
 
-    # template for rollback restores: stays valid after donation consumes
-    # the live buffers (see TrainState.abstract)
-    abstract_template = state.abstract()
-    guard = resilience.RollbackGuard(res.max_rollbacks)
-    last_val: Optional[float] = None
-    last_saved: Optional[int] = None
-    events_log = logger.events if logger else None
-    seen_shapes = set()
+        # template for rollback restores: stays valid after donation consumes
+        # the live buffers (see TrainState.abstract)
+        abstract_template = state.abstract()
+        guard = resilience.RollbackGuard(res.max_rollbacks)
+        last_val: Optional[float] = None
+        last_saved: Optional[int] = None
+        events_log = logger.events if logger else None
+        compiles.events, compiles.first_step = events_log, start_step
+        seen_shapes = set()
 
-    @contextlib.contextmanager
-    def dispatch_span(shape: tuple):
-        """``train_dispatch`` around the jitted call. A batch shape's first
-        call (its compile, or its load from the persistent cache) also goes
-        into the ring, with the shape and what the compile counters grew by."""
-        first = shape not in seen_shapes
-        seen_shapes.add(shape)
-        before = obs.jaxmon.compile_totals(registry) if first else None
-        ring = {"parent": run_ctx, "shape": list(shape)} if first else {}
-        with obs.Span("train_dispatch", registry=registry,
-                      histogram="train_step_seconds", **ring) as sp:
-            yield
-            if first:
-                after = obs.jaxmon.compile_totals(registry)
-                sp.note(**{k: after[k] - before[k] for k in after})
+        @contextlib.contextmanager
+        def dispatch_span(shape: tuple):
+            """``train_dispatch`` around the jitted call. A batch shape's first
+            call also goes into the ring, with the shape; its trace, lowering
+            and compile (or load from the persistent cache) are its children
+            there, the ``jax_compile`` records."""
+            first = shape not in seen_shapes
+            seen_shapes.add(shape)
+            ring = {"parent": run_ctx, "shape": list(shape)} if first else {}
+            with obs.Span("train_dispatch", registry=registry,
+                          histogram="train_step_seconds", **ring):
+                yield
 
-    # a window runs from one log boundary's train_log span to the next's:
-    # the four main-thread spans inside it are disjoint, so their sum can
-    # not pass the window's wall time
-    window_t0, window_step0 = time.monotonic(), step
-    window_totals = _window_totals(registry, lm, diffusion)
-    moe_pending = []  # each step's routing counts, read at the log boundary
-    trace_active = False
-    shutdown = resilience.GracefulShutdown()
+        # a window runs from one log boundary's train_log span to the next's:
+        # the four main-thread spans inside it are disjoint, so their sum can
+        # not pass the window's wall time
+        window_t0, window_step0 = time.monotonic(), step
+        window_totals = _window_totals(registry, lm, diffusion)
+        moe_pending = []  # each step's routing counts, read at the log boundary
+        trace_active = False
+        shutdown = resilience.GracefulShutdown()
     try:
         with shutdown:
             while step < total_step and not shutdown.requested:
-                with obs.Span("train_data_wait", registry=registry):
+                compiles.step = step
+                # the run's first wait (before any call: the first
+                # super-batch's loads) goes into the ring as well
+                first_wait = {} if seen_shapes else {"parent": run_ctx}
+                with obs.Span("train_data_wait", registry=registry,
+                              **first_wait):
                     item = next(prefetch, None)
                 if item is None:
                     break
@@ -974,6 +991,7 @@ def run_training(
                     **obs.jaxmon.compile_totals(registry),
                 )
     finally:
+        compiles.close()
         if trace_active:
             jax.profiler.stop_trace()  # run ended inside the profile window
         prefetch.stop()

@@ -778,13 +778,29 @@ def test_train_smoke_populates_metrics_and_event_log(
     ring = get_span_ring().spans()
     run = [s for s in ring if s["name"] == "setup_model_init"][-1]["trace_id"]
     mine = [s for s in ring if s["trace_id"] == run]
-    names = [s["name"] for s in mine]
-    assert names[:4] == ["setup_model_init", "setup_restore",
-                         "setup_build_steps", "setup_datasets"]
+    # a compile's record finishes inside the span it happened in, so it
+    # enters the ring before it: the spans alone, in the order they closed
+    names = [s["name"] for s in mine if s["name"] != "jax_compile"]
+    assert names[:6] == ["setup_model_init", "setup_restore",
+                         "setup_build_steps", "setup_datasets", "run_start",
+                         "train_data_wait"]
     firsts = [s for s in mine if s["name"] == "train_dispatch"]
     assert 1 <= len(firsts) <= 3 and "train_program_card" in names
     assert len({tuple(s["fields"]["shape"]) for s in firsts}) == len(firsts)
-    assert all(s["fields"]["compiles"] >= 0 for s in firsts)
+    # each first call's trace, lowering and compile (or load) are its child
+    # records, at the loop's step; set-up's are its spans', at no step
+    compiles = [s for s in mine if s["name"] == "jax_compile"]
+    for first in firsts:
+        kids = [c["fields"] for c in compiles
+                if c["parent_span_id"] == first["span_id"]]
+        assert kids and all(k["step"] is not None for k in kids)
+        assert {k["outcome"] for k in kids} <= {"hit", "miss", "uncached"}
+        assert sum(k["trace_s"] + k["lower_s"] for k in kids) > 0
+    init = [s for s in mine if s["name"] == "setup_model_init"][-1]
+    assert [c["fields"]["step"] for c in compiles
+            if c["parent_span_id"] == init["span_id"]] != []
+    assert all(c["fields"]["step"] is None for c in compiles
+               if c["parent_span_id"] == init["span_id"])
     # one program_card event: XLA's own accounting of the step program
     (card,) = read_events(log_dir, event="program_card")
     assert card["name"] == "train_step"
