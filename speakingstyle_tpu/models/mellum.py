@@ -56,7 +56,8 @@ row at a time
 (a row's worst case, every pair held here, sizes the dispatch buffers'
 shapes; the work over them follows the tiles the row's plan uses:
 ``ops/expert_dispatch.py``). The logits stand ``LOSS_CHUNK`` positions at a
-time.
+time, and the head's gradient is formed while they stand
+(``weighted_cross_entropy``): the backward never forms them again.
 
 The step's counters come back with the loss (``aux``): per layer, the pairs
 each held expert drew, the pairs routed to held experts, the pairs that
@@ -66,6 +67,7 @@ used beside the worst case the buffers are sized for.
 
 import functools
 import math
+import re
 from typing import Tuple
 
 import flax.linen as nn
@@ -73,6 +75,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from speakingstyle_tpu import ops
 from speakingstyle_tpu.configs.config import DecoderLMConfig, RopeConfig
 from speakingstyle_tpu.ops import expert_dispatch
 from speakingstyle_tpu.ops.blocked_attention import (
@@ -82,6 +85,8 @@ from speakingstyle_tpu.ops.qk_prepare import qk_prepare
 
 # positions whose float32 logits stand at once (fewer where a batch has fewer)
 LOSS_CHUNK = 4096
+# the loss head's module name, the last component of its module path
+HEAD = "lm_head"
 
 
 def rope_inv_freq(rope: RopeConfig, head_dim: int) -> Tuple[np.ndarray, float]:
@@ -367,7 +372,7 @@ class DecoderLayer(nn.Module):
 def next_token_loss(hidden, head, tokens, chunk: int = LOSS_CHUNK):
     """Mean cross-entropy of ``tokens[:, t + 1]`` from ``hidden[:, t]``
     (``[B, T, d]``, already normed) under ``head`` ``[d, V]``, float32, the
-    logits standing ``chunk`` positions at a time and recomputed backward."""
+    logits standing ``chunk`` positions at a time."""
     B, T, d = hidden.shape
     n = B * T
     targets = jnp.concatenate(
@@ -375,8 +380,8 @@ def next_token_loss(hidden, head, tokens, chunk: int = LOSS_CHUNK):
     weight = jnp.concatenate(
         [jnp.ones((B, T - 1), jnp.float32), jnp.zeros((B, 1), jnp.float32)],
         axis=1).reshape(n)
-    return weighted_cross_entropy(hidden, head, targets, weight, chunk) \
-        / (B * (T - 1))
+    return weighted_cross_entropy(hidden, head, targets, weight, chunk,
+                                  1.0 / (B * (T - 1)))
 
 
 def block_diffusion_loss(hidden, head, tokens, weight, chunk: int = LOSS_CHUNK):
@@ -386,32 +391,102 @@ def block_diffusion_loss(hidden, head, tokens, weight, chunk: int = LOSS_CHUNK):
     B, L, _ = hidden.shape
     return weighted_cross_entropy(
         hidden, head, tokens.reshape(-1),
-        weight.astype(jnp.float32).reshape(-1), chunk) / (B * L)
+        weight.astype(jnp.float32).reshape(-1), chunk, 1.0 / (B * L))
 
 
-def weighted_cross_entropy(hidden, head, targets, weight, chunk: int):
-    """The sum over the ``n = B T`` positions of ``weight`` times
-    ``logsumexp(hidden W) - (hidden W)[target]``, in chunks."""
+def weighted_cross_entropy(hidden, head, targets, weight, chunk: int,
+                           scale: float = 1.0):
+    """``scale`` times the sum over the ``n = B T`` positions of ``weight``
+    times ``logsumexp(hidden W) - (hidden W)[target]``, the float32 logits
+    standing ``chunk`` positions at a time.
+
+    Differentiated, it forms the gradient while a chunk's logits stand, so
+    they are never formed again: ``dlogits = (softmax - onehot) weight
+    scale`` in float32, rounded once to ``hidden``'s dtype; ``dx = dlogits
+    W^T`` in that dtype and ``dW = x^T dlogits`` summed over the chunks in
+    float32. Three products a chunk; the backward only scales ``dx`` and
+    ``dW`` by the cotangent. Undifferentiated (validation) it forms the loss
+    alone."""
+    return _cross_entropy(hidden, head, targets, weight, chunk, float(scale))
+
+
+def _chunks(hidden, targets, weight, chunk):
+    """hidden ``[B, T, d]``, targets and weight ``[B T]`` -> the three in
+    chunks of ``chunk`` positions (fewer where there are fewer), the last
+    padded with weight 0."""
     B, T, d = hidden.shape
     n = B * T
     chunk = min(chunk, n)
     pad = -n % chunk
     flat = jnp.pad(hidden.reshape(n, d), ((0, pad), (0, 0)))
     targets, weight = jnp.pad(targets, (0, pad)), jnp.pad(weight, (0, pad))
+    return (flat.reshape(-1, chunk, d), targets.reshape(-1, chunk),
+            weight.reshape(-1, chunk))
+
+
+def _chunk_loss(x, w, t, m):
+    """(a chunk's weighted loss sum, its float32 logits' ``exp`` less the
+    row's max, their row sums)."""
+    logits = jnp.dot(x, w, preferred_element_type=jnp.float32)
+    top = jnp.max(logits, axis=-1, keepdims=True)
+    e = jnp.exp(logits - top)
+    s = jnp.sum(e, axis=-1)
+    lse = jnp.log(s) + top[:, 0]
+    picked = jnp.take_along_axis(logits, t[:, None], axis=-1)[:, 0]
+    return jnp.sum((lse - picked) * m), e, s
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _cross_entropy(hidden, head, targets, weight, chunk, scale):
     w = head.astype(hidden.dtype)
+    sums = jax.lax.map(lambda c: _chunk_loss(c[0], w, c[1], c[2])[0],
+                       _chunks(hidden, targets, weight, chunk))
+    return jnp.sum(sums) * scale
 
-    @jax.checkpoint
-    def part(args):
-        x, t, m = args
-        logits = jnp.dot(x, w, preferred_element_type=jnp.float32)
-        lse = jax.nn.logsumexp(logits, axis=-1)
-        picked = jnp.take_along_axis(logits, t[:, None], axis=-1)[:, 0]
-        return jnp.sum((lse - picked) * m)
 
-    sums = jax.lax.map(part, (flat.reshape(-1, chunk, d),
-                              targets.reshape(-1, chunk),
-                              weight.reshape(-1, chunk)))
-    return jnp.sum(sums)
+def _cross_entropy_fwd(hidden, head, targets, weight, chunk, scale):
+    B, T, d = hidden.shape
+    w = head.astype(hidden.dtype)
+    vocab = jnp.arange(w.shape[1])
+
+    def step(dw, c):
+        x, t, m = c
+        total, e, s = _chunk_loss(x, w, t, m)
+        by = m * scale
+        dlogits = (e * (by / s)[:, None]
+                   - jnp.where(t[:, None] == vocab, by[:, None], 0.0))
+        dlogits = dlogits.astype(x.dtype)
+        dx = jax.lax.dot_general(dlogits, w, (((1,), (1,)), ((), ())))
+        dw = dw + jax.lax.dot_general(x, dlogits, (((0,), (0,)), ((), ())),
+                                      preferred_element_type=jnp.float32)
+        return dw, (total, dx)
+
+    dw, (sums, dx) = jax.lax.scan(step, jnp.zeros(head.shape, jnp.float32),
+                                  _chunks(hidden, targets, weight, chunk))
+    dx = dx.reshape(-1, d)[:B * T].reshape(B, T, d)
+    return jnp.sum(sums) * scale, (dx, dw)
+
+
+def _cross_entropy_bwd(chunk, scale, residuals, g):
+    dx, dw = residuals
+    return (g * dx).astype(dx.dtype), g * dw, None, None
+
+
+_cross_entropy.defvjp(_cross_entropy_fwd, _cross_entropy_bwd)
+
+
+def head_products(closed_jaxpr, head_shape) -> int:
+    """The products under the head's module path (``HEAD``) in a program
+    that take or give its ``[d, V]`` (``head_shape``, either way round),
+    each counted once however often its loop runs, after what is dead is
+    taken out: 3 in a training step (forward, ``dx``, ``dW``), 1 where the
+    loss alone is formed."""
+    shapes = {tuple(head_shape), tuple(head_shape)[::-1]}
+    return sum(eqn.primitive.name == "dot_general"
+               and HEAD in re.split(r"[/()]", scope)
+               and any(tuple(v.aval.shape) in shapes
+                       for v in (*eqn.invars, *eqn.outvars))
+               for scope, eqn in ops.live_equations(closed_jaxpr))
 
 
 class LMHead(nn.Module):
@@ -472,9 +547,12 @@ class DecoderLM(nn.Module):
         x = nn.Embed(c.n_vocab_held, c.hidden_size, dtype=self.dtype,
                      param_dtype=jnp.float32, name="embed",
                      embedding_init=nn.initializers.normal(1.0))(ids)
+        # in sorted order: a set's follows the process's string hash seed, and
+        # the tables' order is part of the lowered program, so of its key in
+        # the persistent compile cache
         tables = {kind: rope_tables(getattr(c.rope_parameters, kind),
                                     c.head_dim, T)
-                  for kind in set(c.layer_types[:c.num_hidden_layers])}
+                  for kind in sorted(set(c.layer_types[:c.num_hidden_layers]))}
         if c.block_diffusion:  # a token's two copies carry one position
             tables = {kind: tuple(jnp.tile(t, (2, 1)) for t in pair)
                       for kind, pair in tables.items()}
@@ -494,6 +572,6 @@ class DecoderLM(nn.Module):
             out["tokens_masked"] = jnp.sum(weight > 0)
             out["loss_weight"] = jnp.sum(weight.astype(jnp.float32))
         hidden = RMSNorm(c.rms_norm_eps, name="final_norm")(x)
-        loss = LMHead(c.n_vocab_held, name="lm_head")(
+        loss = LMHead(c.n_vocab_held, name=HEAD)(
             hidden, tokens, weight if c.block_diffusion else None)
         return loss, out

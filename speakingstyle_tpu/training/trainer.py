@@ -23,8 +23,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from speakingstyle_tpu import obs
 from speakingstyle_tpu.analysis import contracts
 from speakingstyle_tpu.configs.config import Config
+from speakingstyle_tpu.models import mellum
 from speakingstyle_tpu.models.loss import fastspeech2_loss
-from speakingstyle_tpu.models.mellum import batch_inputs as lm_inputs
 from speakingstyle_tpu.obs.trace import new_context
 from speakingstyle_tpu.ops import blocked_attention, qk_prepare
 from speakingstyle_tpu.parallel.registry import ProgramRegistry, jit_program
@@ -74,14 +74,19 @@ def step_kernel_launches(train_step, state, arrays, rng) -> Dict:
     ``qk_prepare_launches`` by variant (``norm``, ``plain``) and
     ``attn_bwd_launches``, the attention core's backward by path (``fused``,
     ``split``); all 0 where the model's plain ``jax.numpy`` ran, as off a
-    TPU. Telemetry: each None with a warning where the step cannot be read."""
+    TPU. Beside them ``loss_head_products``, the products on the head's
+    ``[d, V]`` (``mellum.head_products``: 3 a chunk's loop body). Telemetry:
+    each None with a warning where the step cannot be read."""
     try:
         jaxpr = train_step.trace(state, arrays, rng).jaxpr
     except Exception as e:
         print(f"warning: kernel launches unavailable ({type(e).__name__}: {e})")
-        return dict.fromkeys(("qk_prepare_launches", "attn_bwd_launches"))
+        return dict.fromkeys(("qk_prepare_launches", "attn_bwd_launches",
+                              "loss_head_products"))
     return {"qk_prepare_launches": qk_prepare.launches(jaxpr),
-            "attn_bwd_launches": blocked_attention.launches(jaxpr)}
+            "attn_bwd_launches": blocked_attention.launches(jaxpr),
+            "loss_head_products": mellum.head_products(
+                jaxpr, state.params[mellum.HEAD]["kernel"].shape)}
 
 
 def _model_kwargs(arrays: Dict, teacher_forced: bool) -> Dict:
@@ -159,7 +164,7 @@ def make_train_step(model, tx, cfg: Config, mesh=None, state_shardings=None):
         # at the log boundary only, as the sentinel is) and so do the
         # router's choices (``_choices``: the loop never reads them)
         contracts.assert_rank(arrays["tokens"], 2, "train_step.tokens")
-        loss, aux = model.apply({"params": params}, **lm_inputs(arrays))
+        loss, aux = model.apply({"params": params}, **mellum.batch_inputs(arrays))
         choices = aux.pop("choices")
         return loss, ({"total_loss": loss, "_moe": aux, "_choices": choices},
                       state.batch_stats)
@@ -216,7 +221,8 @@ def make_eval_step(model, cfg: Config, mesh=None, state_shardings=None):
 
     def eval_fn(state: TrainState, arrays: Dict) -> Dict:
         if cfg.model.family == "decoder_lm":
-            loss, _ = model.apply({"params": state.params}, **lm_inputs(arrays))
+            loss, _ = model.apply({"params": state.params},
+                                  **mellum.batch_inputs(arrays))
             return {"total_loss": loss}
         out = model.apply(
             {"params": state.params, "batch_stats": state.batch_stats},

@@ -378,6 +378,193 @@ def test_keeping_the_core_changes_no_gradient(toy, monkeypatch, window, interpre
                for g in jax.tree_util.tree_leaves(kept[1]))
 
 
+def _checkpointed_cross_entropy(hidden, head, targets, weight, chunk, scale):
+    """The loss head as it stood before it formed its gradient forward:
+    ``jax.checkpoint`` over ``lax.map``, differentiated by JAX, so the
+    backward forms each chunk's logits a second time."""
+    B, T, d = hidden.shape
+    n = B * T
+    chunk = min(chunk, n)
+    pad = -n % chunk
+    flat = jnp.pad(hidden.reshape(n, d), ((0, pad), (0, 0)))
+    targets, weight = jnp.pad(targets, (0, pad)), jnp.pad(weight, (0, pad))
+    w = head.astype(hidden.dtype)
+
+    @jax.checkpoint
+    def part(args):
+        x, t, m = args
+        logits = jnp.dot(x, w, preferred_element_type=jnp.float32)
+        lse = jax.nn.logsumexp(logits, axis=-1)
+        picked = jnp.take_along_axis(logits, t[:, None], axis=-1)[:, 0]
+        return jnp.sum((lse - picked) * m)
+
+    sums = jax.lax.map(part, (flat.reshape(-1, chunk, d), targets.reshape(-1, chunk),
+                              weight.reshape(-1, chunk)))
+    return jnp.sum(sums) * scale
+
+
+def _plain_cross_entropy(hidden, head, targets, weight, chunk, scale):
+    """Unchunked, float32 at the highest precision."""
+    logits = jnp.dot(hidden.reshape(-1, hidden.shape[-1]).astype(jnp.float32), head,
+                     precision=jax.lax.Precision.HIGHEST)
+    lse = jax.nn.logsumexp(logits, axis=-1)
+    picked = jnp.take_along_axis(logits, targets[:, None], axis=-1)[:, 0]
+    return jnp.sum((lse - picked) * weight) * scale
+
+
+# (rows, length, chunk, objective, cotangent): 3 chunks of 32, a last chunk
+# padded by 16, one chunk, a cotangent other than the training step's 1
+HEAD_CASES = {
+    "next_token": (2, 48, 32, "next_token", 1.0),
+    "block_diffusion": (2, 48, 32, "block_diffusion", 1.0),
+    "padded": (2, 40, 32, "weighted", 1.0),
+    "one_chunk": (2, 40, 4096, "weighted", 1.0),
+    "cotangent": (2, 48, 32, "weighted", -2.5),
+}
+
+
+def _head_case(name, dtype):
+    """-> (the program's loss of (hidden, head), the same loss as
+    ``form(hidden, head, targets, weight, chunk, scale)`` for any ``form``,
+    hidden, head, cotangent)."""
+    B, T, chunk, objective, g = HEAD_CASES[name]
+    d, V = 16, 96
+    rng = np.random.default_rng(len(name))
+    hidden = jnp.asarray(rng.standard_normal((B, T, d)), dtype)
+    head = jnp.asarray(0.3 * rng.standard_normal((d, V)), jnp.float32)
+    tokens = jnp.asarray(rng.integers(0, V, (B, T)), jnp.int32)
+    if objective == "next_token":   # the last position of a row weighs 0
+        targets = jnp.concatenate([tokens[:, 1:], jnp.zeros((B, 1), jnp.int32)], 1)
+        weight = jnp.ones((B, T), jnp.float32).at[:, -1].set(0.0)
+        scale = 1.0 / (B * (T - 1))
+        program = lambda h, w: mellum.next_token_loss(h, w, tokens, chunk)
+    elif objective == "block_diffusion":   # 1 / t where masked, up to 1,000
+        t = rng.uniform(0.001, 1.0, (B, T))
+        weight = jnp.asarray(np.where(rng.random((B, T)) < t, 1.0 / t, 0.0), jnp.float32)
+        weight = weight.at[0, 0].set(1000.0)
+        targets, scale = tokens, 1.0 / (B * T)
+        program = lambda h, w: mellum.block_diffusion_loss(h, w, tokens, weight, chunk)
+    else:
+        targets = tokens
+        weight = jnp.asarray(rng.random((B, T)), jnp.float32)
+        scale = 1.0 / (B * T) if g != 1.0 else 1.0
+        # where ``scale`` is 1 the call leaves it to its default, as
+        # ``test_cell_sdar.py:clean_head`` calls it
+        program = lambda h, w: mellum.weighted_cross_entropy(
+            h, w, targets.reshape(-1), weight.reshape(-1), chunk,
+            **({"scale": scale} if scale != 1.0 else {}))
+    as_form = lambda form: lambda h, w: form(h, w, targets.reshape(-1),
+                                             weight.reshape(-1), chunk, scale)
+    return program, as_form, hidden, head, g
+
+
+def _loss_and_grads(fn, hidden, head, g):
+    loss, pull = jax.vjp(fn, hidden, head)
+    dh, dw = pull(jnp.asarray(g, loss.dtype))
+    return loss, dh.astype(jnp.float32), dw
+
+
+@pytest.mark.parametrize("form,dtype,tol", [
+    (_plain_cross_entropy, jnp.float32, 1e-5),
+    (_checkpointed_cross_entropy, jnp.bfloat16, 2.0 ** -8)],
+    ids=["plain_float32", "checkpointed_bfloat16"])
+@pytest.mark.parametrize("case", sorted(HEAD_CASES))
+def test_loss_heads_gradient_formed_forward_is_the_differentiated_loss(case, form,
+                                                                       dtype, tol):
+    """The loss head's own VJP against JAX's gradient of a formulation: an
+    unchunked float32 one at float32 (to float32's error), and the
+    ``checkpoint`` + ``lax.map`` form it replaced at bfloat16 (to within a
+    bfloat16 rounding: both round ``dlogits`` and ``dx`` once; ``dW`` is
+    summed in float32 where the replaced form summed it in bfloat16)."""
+    program, as_form, hidden, head, g = _head_case(case, dtype)
+    loss, dh, dw = _loss_and_grads(program, hidden, head, g)
+    ref_loss, ref_dh, ref_dw = _loss_and_grads(as_form(form), hidden, head, g)
+    assert dh.shape == hidden.shape and dw.dtype == jnp.float32
+    assert abs(float(loss) - float(ref_loss)) <= 1e-6 * abs(float(ref_loss))
+    assert rel(dh, ref_dh) < tol and rel(dw, ref_dw) < tol
+
+
+@pytest.mark.parametrize("case", ["next_token", "block_diffusion"])
+def test_loss_head_alone_forms_one_product_and_its_gradient_three(case):
+    """Traced for its value alone (validation) the head forms the forward
+    product and no other on ``[d, V]``; differentiated, 3 (forward, ``dx``,
+    ``dW``), where the replaced form read 4 (its backward formed the
+    forward's again)."""
+    program, as_form, hidden, head, g = _head_case(case, jnp.bfloat16)
+
+    def count(fn):
+        def under_the_heads_path(*args):
+            with jax.named_scope(mellum.HEAD):
+                return fn(*args)
+        return mellum.head_products(
+            jax.make_jaxpr(under_the_heads_path)(hidden, head), head.shape)
+
+    assert count(program) == 1
+    assert count(jax.value_and_grad(program, (0, 1))) == 3
+    assert count(jax.value_and_grad(as_form(_checkpointed_cross_entropy), (0, 1))) == 4
+
+
+@pytest.mark.parametrize("config", ["mellum2_12b_ep4share", "sdar_30b_ep8share"],
+                         ids=["next_token", "block_diffusion"])
+def test_step_card_counts_three_head_products(config, capsys):
+    """``train_program_card``'s ``loss_head_products`` on a toy step of each
+    objective: the head's forward, ``dx`` and ``dW``; None with a warning
+    where the step cannot be traced."""
+    import dataclasses
+
+    from speakingstyle_tpu.models.factory import build_model, init_variables
+    from speakingstyle_tpu.training import make_optimizer, trainer
+    from speakingstyle_tpu.training.state import TrainState
+
+    block = common.sized(common.load_json(f"benchmark/configs/{config}.json"),
+                         True)["model"]["decoder_lm"]
+    cfg = load_config(preset="Mellum2-12B-A2.5B")
+    cfg = dataclasses.replace(cfg, model=dataclasses.replace(
+        cfg.model, decoder_lm=_build(DecoderLMConfig, block)))
+    model, tx = build_model(cfg), make_optimizer(cfg.train)
+    init_key, step_key, none_key = jax.random.split(jax.random.PRNGKey(0), 3)
+    state = TrainState.create(init_variables(model, cfg, init_key), tx)
+    step = trainer.make_train_step(model, tx, cfg)
+    arrays = mellum.dummy_inputs(cfg.model.decoder_lm)
+    fields = trainer.step_kernel_launches(step, state, arrays, step_key)
+    assert fields["loss_head_products"] == 3
+    assert trainer.step_kernel_launches(step, state, {}, none_key) == dict.fromkeys(fields)
+    assert "warning: kernel launches unavailable" in capsys.readouterr().out
+
+
+LOWER_TOY = """
+import hashlib, sys
+sys.path.insert(0, sys.argv[1])
+import jax, jax.numpy as jnp
+from benchmark.harness import common
+from speakingstyle_tpu.configs.config import DecoderLMConfig, _build
+from speakingstyle_tpu.models import mellum
+block = common.sized(common.load_json(
+    "benchmark/configs/mellum2_12b_ep4share.json"), True)["model"]["decoder_lm"]
+model = mellum.DecoderLM(_build(DecoderLMConfig, block))
+tokens = jnp.zeros((1, 32), jnp.int32)
+params = jax.eval_shape(model.init, jax.random.PRNGKey(0), tokens)
+text = jax.jit(model.apply).lower(params, tokens).as_text()
+print(hashlib.sha256(text.encode()).hexdigest())
+"""
+
+
+def test_lowered_model_is_the_same_under_any_hash_seed():
+    """Two processes whose string hash seeds differ lower the toy model (a
+    window and a full layer kind: two rotary tables) to the same program, so
+    the persistent compile cache serves one process what another wrote."""
+    import subprocess
+
+    digests = set()
+    for seed in ("1", "2"):
+        env = {**os.environ, "PYTHONHASHSEED": seed, "JAX_PLATFORMS": "cpu"}
+        out = subprocess.run([sys.executable, "-c", LOWER_TOY, ROOT], cwd=ROOT,
+                             env=env, capture_output=True, text=True, timeout=300)
+        assert out.returncode == 0, out.stderr[-2000:]
+        digests.add(out.stdout.split()[-1])
+    assert len(digests) == 1
+
+
 @pytest.mark.parametrize("interpret", [None, True], ids=["jnp", "interpreted"])
 @pytest.mark.parametrize("lo", [0, 2, 5])
 def test_grouped_product_is_a_loop_over_the_held_experts(interpret, lo):
